@@ -1,6 +1,6 @@
 # Convenience targets (the CI-role entry points — SURVEY §3.4).
 
-.PHONY: test gate gate-fast bench bench-compile bench-import native native-test lint lint-baseline shape-lint life-lint check check-baseline obs-smoke serve-smoke tune-smoke tune chaos-smoke train-chaos-smoke cluster-chaos-smoke slo-smoke prefix-smoke spec-smoke aot-smoke locktrace-smoke shapetrace-smoke lifetrace-smoke
+.PHONY: test gate gate-fast bench native native-test lint lint-baseline shape-lint life-lint check check-baseline obs-smoke tune-smoke tune chaos-smoke train-chaos-smoke cluster-chaos-smoke slo-smoke prefix-smoke spec-smoke aot-smoke locktrace-smoke shapetrace-smoke lifetrace-smoke
 
 # graftlint: JAX-footgun static analysis (docs/LINT.md). Fails only on
 # findings NOT grandfathered in lint_baseline.json. JAX_PLATFORMS=cpu so
@@ -45,16 +45,17 @@ obs-smoke:
 # default) cache dir, and PROVE via the dispatch counters that resolve
 # honors the tuned flash_min_t (XLA below, Pallas above). ONE JSON line
 # like lint/check. The throwaway dir matters: smoke thresholds are
-# interpret-mode noise and must never clobber a real measured table in
-# ~/.cache (set DL4J_TPU_TUNING_DIR yourself to keep the smoke table).
+# interpret-mode noise and must never sit beside a real measured table
+# (set DL4J_TPU_TUNING_DIR yourself to keep the smoke table).
 tune-smoke:
 	JAX_PLATFORMS=cpu \
 	DL4J_TPU_TUNING_DIR=$${DL4J_TPU_TUNING_DIR:-$$(mktemp -d -t dl4j_tune_smoke.XXXXXX)} \
 	python tools/tune.py --smoke --json
 
 # full-ladder autotune — run ON THE TARGET CHIP; writes the measured table
-# for this device kind to DL4J_TPU_TUNING_DIR (commit a copy under
-# deeplearning4j_tpu/ops/tuning_tables/<kind>.json to ship it as default)
+# for this device kind to DL4J_TPU_TUNING_DIR, or to the git-ignored
+# .tuning/ of the checkout when that is unset. Dispatch reads it only once a
+# copy is committed as deeplearning4j_tpu/ops/tuning_tables/<kind>.json
 tune:
 	python tools/tune.py
 
@@ -153,19 +154,10 @@ spec-smoke:
 # persistent export cache off, populating, and warm — fails unless the
 # warm restart pays ZERO serving first_compile ledger events (every
 # dispatched fn arrives as cache_hit), its greedy outputs are
-# bit-identical to the cache-off leg, zero new_shape events were paid,
-# and cold-start TTFT (process boot + first token) stays within 2x the
-# cache-off leg. ONE JSON line like lint/check/obs/chaos/slo/prefix.
+# bit-identical to the cache-off leg, and zero new_shape events were paid.
+# ONE JSON line like lint/check/obs/chaos/slo/prefix.
 aot-smoke:
 	JAX_PLATFORMS=cpu python tools/aot.py --json
-
-# generative-serving smoke (docs/SERVING.md): continuous-batching
-# generation, smoke-sized, CPU-pinned — ONE JSON line with tokens/sec,
-# TTFT/inter-token percentiles and the observe generate section.
-serve-smoke:
-	JAX_PLATFORMS=cpu BENCH_MODEL=generate BENCH_RECORD=0 BENCH_QPS=5 \
-	BENCH_REQUESTS=8 BENCH_GEN_TOKENS=8 BENCH_SLOTS=4 BENCH_GPT=tiny \
-	python bench.py
 
 # DL4J_TPU_REQUIRE_NATIVE=1: a missing native lib FAILS the ctypes tests
 # instead of silently exercising the numpy fallback (SURVEY §5.3)
@@ -175,33 +167,19 @@ test: native-test
 native-test: native
 	ctest --test-dir native/build --output-on-failure
 
-# full pre-snapshot gate: pytest + on-chip consistency + bench smoke +
-# multichip dryrun (tools/gate.py). Run before any round-end commit.
+# full pre-snapshot gate (tools/gate.py): pytest + every smoke stage, all on
+# the CPU. Run before any round-end commit. The chip is reached only through
+# `python chip_smoke.py`, where a TPU is attached.
 gate:
 	python tools/gate.py
 
 gate-fast:
 	python tools/gate.py --fast
 
+# needs an attached TPU: without one it exits non-zero, naming the missing
+# device, and prints no metric
 bench:
 	python bench.py
-
-# graph-compile metric (docs/OPTIMIZER.md): trace+XLA-compile speedup from
-# the pre-trace SameDiff optimizer, CPU-pinned (pure compile-time
-# measurement — no device loop), one gate-friendly JSON line on stdout.
-# Also asserts the fusion tier: a 2-layer imported BERT must report >= 1
-# attention fusion, so a matcher regression fails this target.
-bench-compile:
-	JAX_PLATFORMS=cpu BENCH_MODEL=graph_compile BENCH_RECORD=0 python bench.py
-
-# imported-BERT forward throughput, fusion on vs off (docs/OPTIMIZER.md
-# § Fusion tier): one JSON line with tokens/sec + fused_attention_count/
-# fused_epilogue_count. Smoke-sized here; unpinned `BENCH_MODEL=bert_import
-# python bench.py` measures the real chip.
-bench-import:
-	JAX_PLATFORMS=cpu BENCH_MODEL=bert_import BENCH_RECORD=0 \
-	BENCH_ITERS=3 BENCH_IMPORT_LAYERS=2 BENCH_SEQ=16 BENCH_IMPORT_D=128 \
-	BENCH_IMPORT_HEADS=2 BENCH_IMPORT_FF=256 python bench.py
 
 native:
 	cmake -S native -B native/build && cmake --build native/build -j

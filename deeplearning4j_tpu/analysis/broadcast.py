@@ -100,8 +100,13 @@ def is_float_dtype(dt: Optional[np.dtype]) -> bool:
 def promotion_surprise(dtypes: Sequence[Optional[np.dtype]]
                        ) -> Optional[str]:
     """The GC003 predicate: mixed float widths (bf16+f32, f32+f64 — the
-    silent up/downcast class the optimizer's strip guard exists for), or a
-    promotion to a dtype wider than every input (int32+uint32→int64).
+    silent up/downcast class the optimizer's strip guard exists for), a
+    promotion to a dtype wider than every input (int8+uint8→int16), or a
+    signed result too narrow for an unsigned input. The last is what
+    int32+uint32 means as programs here run, with x64 off: jax's lattice
+    asks for int64, the installed ``promote_types`` answers int32, and
+    every uint32 value above 2^31-1 silently wraps negative. (With x64 on
+    the same pair widens to int64 and is caught by the previous clause.)
     Returns a human-readable reason, or None when unsurprising."""
     known = [dt for dt in dtypes if dt is not None]
     if len(known) < 2:
@@ -111,7 +116,12 @@ def promotion_surprise(dtypes: Sequence[Optional[np.dtype]]
         names = sorted({dt.name for dt in inexact})
         return f"mixed float widths {' vs '.join(names)}"
     promoted = promote_dtypes(known)
+    names = " + ".join(dt.name for dt in known)
     if promoted is not None and all(promoted != dt for dt in known):
-        names = " + ".join(dt.name for dt in known)
         return f"{names} promotes to {promoted.name} (wider than every input)"
+    if promoted is not None and promoted.kind == "i":
+        for dt in known:
+            if dt.kind == "u" and dt.itemsize >= promoted.itemsize:
+                return (f"{names} stays {promoted.name}: {dt.name} values "
+                        f"above {np.iinfo(promoted).max} wrap negative")
     return None

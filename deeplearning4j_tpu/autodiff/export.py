@@ -51,6 +51,8 @@ import numpy as np
 from jax import export as jexport
 
 from deeplearning4j_tpu import observe
+from deeplearning4j_tpu.environment import (
+    disable_compile_cache, enable_compile_cache)
 from deeplearning4j_tpu.ops.tuning import current_device_kind
 
 logger = logging.getLogger(__name__)
@@ -62,60 +64,13 @@ ENV_DIR = "DL4J_TPU_COMPILE_CACHE"
 # first bad load of a path logs a warning, later loads stay silent misses
 _WARNED_PATHS: set = set()
 
-# the XLA persistent compilation cache is armed at most once per process
-# (jax config is global); remembers the directory it was armed with and
-# the config values it displaced, so disarm_xla_cache can restore them
-_XLA_CACHE_ARMED: List[str] = []
-_XLA_CACHE_PRIOR: Dict[str, Any] = {}
-
 
 def reset_export_cache() -> None:
     """Test seam: forget warn-once state (mirrors tuning.reset_tables)
-    and disarm the XLA persistent cache so later compiles in the same
-    process stop paying cache serialization."""
+    and turn JAX's persistent cache back off, so later compiles in the
+    same process stop paying cache serialization."""
     _WARNED_PATHS.clear()
-    disarm_xla_cache()
-
-
-def _arm_xla_cache(root: str) -> None:
-    """Best-effort: point jax's own persistent compilation cache at a
-    subdir of ours, so the warm leg skips the XLA backend compile of the
-    deserialized StableHLO too (the jax.export payload caches the
-    *program*; this caches the *backend binary*)."""
-    if _XLA_CACHE_ARMED:
-        return
-    try:
-        xla_dir = os.path.join(root, "xla")
-        os.makedirs(xla_dir, exist_ok=True)
-        for name, val in (
-            ("jax_compilation_cache_dir", xla_dir),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                _XLA_CACHE_PRIOR.setdefault(name, getattr(jax.config, name))
-                jax.config.update(name, val)
-            except Exception as e:  # older jax: flag absent — size gating
-                logger.debug("%s unavailable: %r", name, e)  # keep default
-        _XLA_CACHE_ARMED.append(xla_dir)
-    except Exception as e:  # pragma: no cover - config surface varies
-        logger.warning("could not arm XLA persistent cache under %s: %r",
-                       root, e)
-
-
-def disarm_xla_cache() -> None:
-    """Restore jax's persistent-cache config to its pre-arm values.
-
-    The arm is global jax config: without this, every compile after the
-    first ExportCache construction — anywhere in the process — keeps
-    serializing backend binaries to disk."""
-    for name, val in _XLA_CACHE_PRIOR.items():
-        try:
-            jax.config.update(name, val)
-        except Exception as e:  # pragma: no cover - config surface varies
-            logger.debug("could not restore %s: %r", name, e)
-    _XLA_CACHE_PRIOR.clear()
-    del _XLA_CACHE_ARMED[:]
+    disable_compile_cache()
 
 
 class ExportCache:
@@ -136,7 +91,10 @@ class ExportCache:
         self._hits = m.counter("dl4j_tpu_aot_cache_hits")
         self._misses = m.counter("dl4j_tpu_aot_cache_misses")
         self._export_h = m.histogram("dl4j_tpu_aot_export_seconds")
-        _arm_xla_cache(self.root)
+        # the export payload caches the *program*; JAX's own persistent
+        # cache (placed by the one shared rule, not under ``root``) caches
+        # the *backend binary*, so a warm restore skips that compile too
+        enable_compile_cache()
 
     @classmethod
     def from_env(cls) -> Optional["ExportCache"]:

@@ -115,3 +115,50 @@ def reset_environment() -> Environment:
     global _INSTANCE
     _INSTANCE = Environment()
     return _INSTANCE
+
+
+# ---------------------------------------------------------------------------
+# JAX's persistent compilation cache — THE one place that turns it on
+# ---------------------------------------------------------------------------
+
+# what this code writes beside itself goes to fixed, git-ignored directories
+# at the root of the checkout — never the user's home, never a name made from
+# tempfile, a pid or the time (a cache directory that moves never hits)
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# where compiled programs persist when JAX_COMPILATION_CACHE_DIR is unset
+_CHECKOUT_COMPILE_CACHE = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads that
+    directory from the environment and no code sets another; where it is
+    not, the cache lives in ``.jax_cache/`` inside the checkout. Every
+    program is cached, however small or fast to compile: a cold process
+    (``chip_smoke.py``, ``bench.py``, an AOT warm boot) pays for each one
+    again otherwise."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _CHECKOUT_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not jax.config.jax_enable_compilation_cache:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    return cache_dir
+
+
+def disable_compile_cache() -> None:
+    """Stop reading and writing the persistent cache in this process
+    (tests: later compiles must not keep serializing binaries to disk)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()

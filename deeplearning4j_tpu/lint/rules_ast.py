@@ -1,7 +1,7 @@
 """graftlint AST rules — the JAX footguns this codebase actually hits.
 
 GL001  host-sync / tracer-leak calls inside jit-traced functions
-GL002  unguarded backend probes (jax.devices & co) — the round-5 driver hang
+GL002  import-time backend probes (jax.devices & co)
 GL003  Python side effects under jit (print, global/nonlocal mutation)
 GL004  PRNG key reuse without split
 GL005  mutable default arguments in public APIs
@@ -131,30 +131,20 @@ def rule_host_sync(tree, lines, path) -> List[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# GL002 — unguarded backend probes
+# GL002 — import-time backend probes
 # ---------------------------------------------------------------------------
 
 _PROBES = {"devices", "local_devices", "device_count", "local_device_count"}
 
 
-def _mentions_subprocess_or_timeout(fn: ast.AST) -> bool:
-    """Guard heuristic: the enclosing function routes the probe through a
-    subprocess or bounds it with a timeout (the gate.py has_tpu pattern)."""
-    for node in ast.walk(fn):
-        d = _dotted(node) if isinstance(node, (ast.Name, ast.Attribute)) else None
-        if d and ("subprocess" in d.split(".") or "Popen" in d.split(".")):
-            return True
-        if isinstance(node, ast.keyword) and node.arg == "timeout":
-            return True
-        if isinstance(node, ast.Call):
-            fd = _dotted(node.func)
-            if fd and fd.split(".")[-1] in ("wait_for", "alarm"):
-                return True
-    return False
-
-
-@ast_rule("GL002", "unguarded backend probe (jax.devices & co)")
+@ast_rule("GL002", "import-time backend probe (jax.devices & co)")
 def rule_backend_probe(tree, lines, path) -> List[Finding]:
+    """A probe at module or class scope runs while the module is imported:
+    it initializes the backend — and takes the chip, which has one owner —
+    in every process that imports the module, test workers and pool
+    children included. Inside a function a probe is how a program finds its
+    device, in-process; that is not flagged. (A child process started to
+    probe is no guard: it holds the chip its parent then needs.)"""
     findings: List[Finding] = []
 
     # enclosing-function map: node id -> innermost FunctionDef
@@ -180,20 +170,12 @@ def rule_backend_probe(tree, lines, path) -> List[Finding]:
         parts = d.split(".")
         if not (len(parts) >= 2 and parts[0] == "jax" and parts[-1] in _PROBES):
             continue
-        fn = enclosing.get(id(node))
-        if fn is None:
+        if enclosing.get(id(node)) is None:
             findings.append(Finding(
                 path=path, line=node.lineno, rule="GL002", severity="error",
                 message=f"jax.{parts[-1]}() at import time initializes the "
-                        f"backend and can hang on an unreachable TPU; move "
-                        f"into a function behind a subprocess/timeout guard"))
-        elif not _mentions_subprocess_or_timeout(fn):
-            name = getattr(fn, "name", "<lambda>")
-            findings.append(Finding(
-                path=path, line=node.lineno, rule="GL002", severity="warning",
-                message=f"jax.{parts[-1]}() in '{name}' has no "
-                        f"subprocess/timeout guard; an unreachable backend "
-                        f"hangs the caller (round-5 driver hang)"))
+                        f"backend in every process that imports this "
+                        f"module; move it into a function"))
     return findings
 
 

@@ -10,7 +10,7 @@ GR001  unbalanced page ownership: a refcounted-page acquisition
 GR002  double-release hazard: a second ``release`` of the same page
        reference on one path, or two release-loops draining the same
        page list
-GR003  terminal-taxonomy exactly-once: a function that completes a
+GR003  terminal-vocabulary exactly-once: a function that completes a
        request future (``set_result``/``set_exception``, including the
        deferred-lambda form) without routing the outcome through the
        ``count_terminal`` funnel (or a funnel-calling helper); plus the
@@ -663,11 +663,11 @@ def rule_double_release(tree, lines, path) -> List[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# GR003 — terminal-taxonomy exactly-once
+# GR003 — terminal-vocabulary exactly-once
 # ---------------------------------------------------------------------------
 
 
-@ast_rule("GR003", "terminal-taxonomy exactly-once: a future completed "
+@ast_rule("GR003", "terminal-vocabulary exactly-once: a future completed "
                    "(set_result/set_exception, incl. deferred lambdas) "
                    "without routing through the count_terminal funnel")
 def rule_terminal_exactly_once(tree, lines, path) -> List[Finding]:
@@ -694,7 +694,7 @@ def rule_terminal_exactly_once(tree, lines, path) -> List[Finding]:
             findings.append(Finding(path, completer_line, "GR003", "error",
                 f"{_qual(key)}() completes a request future without "
                 f"routing the outcome through the count_terminal "
-                f"funnel — the terminal taxonomy loses this exit"))
+                f"funnel — the terminal vocabulary loses this exit"))
         # double-count arm: two count_terminal calls in one suite (no
         # branch between them) count one request exit twice
         for n in [fn] + list(_walk_no_defs(fn)):

@@ -27,25 +27,22 @@ def _pix_lib() -> Optional[ctypes.CDLL]:
     if lib is None:
         return None
     if not getattr(lib, "_pixops_bound", False):
-        try:
-            lib.u8_normalize.restype = None
-            lib.u8_normalize.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-                ctypes.c_float, ctypes.c_float,
-                ctypes.POINTER(ctypes.c_float)]
-            lib.u8_standardize.restype = None
-            lib.u8_standardize.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-                ctypes.c_int64, ctypes.POINTER(ctypes.c_float),
-                ctypes.POINTER(ctypes.c_float),
-                ctypes.POINTER(ctypes.c_float)]
-            lib.murmur3_32.restype = ctypes.c_uint32
-            lib.murmur3_32.argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-                ctypes.c_uint32]
-            lib._pixops_bound = True
-        except AttributeError:
-            return None  # stale .so without pixops — fall back
+        lib.u8_normalize.restype = None
+        lib.u8_normalize.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
+            ctypes.c_float, ctypes.c_float,
+            ctypes.POINTER(ctypes.c_float)]
+        lib.u8_standardize.restype = None
+        lib.u8_standardize.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
+            ctypes.c_int64, ctypes.POINTER(ctypes.c_float),
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.POINTER(ctypes.c_float)]
+        lib.murmur3_32.restype = ctypes.c_uint32
+        lib.murmur3_32.argtypes = [
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
+            ctypes.c_uint32]
+        lib._pixops_bound = True
     return lib
 
 

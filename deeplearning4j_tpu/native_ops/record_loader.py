@@ -21,20 +21,17 @@ def _loader_lib() -> Optional[ctypes.CDLL]:
     if lib is None:
         return None
     if not getattr(lib, "_record_loader_bound", False):
-        try:
-            lib.csv_parse_floats.restype = ctypes.c_int64
-            lib.csv_parse_floats.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64, ctypes.c_char,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_float)]
-            lib.idx_parse.restype = ctypes.c_int64
-            lib.idx_parse.argtypes = [
-                ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int64, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int)]
-            lib._record_loader_bound = True
-        except AttributeError:
-            return None  # stale .so without the loader symbols
+        lib.csv_parse_floats.restype = ctypes.c_int64
+        lib.csv_parse_floats.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64, ctypes.c_char,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_float)]
+        lib.idx_parse.restype = ctypes.c_int64
+        lib.idx_parse.argtypes = [
+            ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int64, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int)]
+        lib._record_loader_bound = True
     return lib
 
 

@@ -4,7 +4,9 @@ Reference parity: the nd4j Java side calls libnd4j's encode/decode threshold
 ops over JNI; here the host-side codec is a C++ shared lib consumed via
 ctypes (SURVEY §8.1: native work = host-side codecs, not device kernels —
 the device path is XLA). Auto-builds with cmake on first use (cached under
-native/build); when no toolchain is available, numpy fallbacks in THIS module
+native/build, and rebuilt whenever a source under native/ is newer than the
+library — a stale build is never loaded); when the build fails, the reason
+is logged and numpy fallbacks in THIS module
 mirror the C ABI bit-for-bit (signed 1-based index format). These are
 distinct from ops/compression.py, whose jax ops use an in-graph
 (indices, values) format for use INSIDE compiled steps; this module is the
@@ -14,12 +16,15 @@ host-side wire format for DCN gradient exchange.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional, Tuple
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
@@ -28,19 +33,39 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
+def _stale(so: str) -> bool:
+    """The library is missing, or older than a source it is built from
+    (every regular file directly under native/; build dirs are git-ignored
+    and survive checkouts, so an old .so may sit beside new sources)."""
+    if not os.path.exists(so):
+        return True
+    built = os.path.getmtime(so)
+    return any(
+        os.path.getmtime(os.path.join(_NATIVE_DIR, name)) > built
+        for name in os.listdir(_NATIVE_DIR)
+        if os.path.isfile(os.path.join(_NATIVE_DIR, name)))
+
+
 def _build_and_load() -> Optional[ctypes.CDLL]:
     build_dir = os.path.join(_NATIVE_DIR, "build")
     so = os.path.join(build_dir, "libdl4j_tpu_native.so")
-    if not os.path.exists(so):
+    if _stale(so):
         try:
             subprocess.run(["cmake", "-S", _NATIVE_DIR, "-B", build_dir],
                            check=True, capture_output=True, timeout=120)
             subprocess.run(["cmake", "--build", build_dir, "-j"],
                            check=True, capture_output=True, timeout=300)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = (getattr(e, "stderr", None) or b"").decode(
+                errors="replace")[-500:]
+            logger.warning("native codec build failed (%r) %s — using the "
+                           "numpy path", e, stderr)
             return None
-    if not os.path.exists(so):
-        return None
+        if _stale(so):
+            logger.warning("native codec build produced no library newer "
+                           "than its sources at %s — using the numpy path",
+                           so)
+            return None
     lib = ctypes.CDLL(so)
     lib.threshold_encode.restype = ctypes.c_int64
     lib.threshold_encode.argtypes = [

@@ -219,9 +219,22 @@ class Updater:
     def init_state(self, param) -> Dict[str, jax.Array]:
         return {}
 
+    def scalars(self, lr, step) -> Tuple[jax.Array, ...]:
+        """The per-step scalars ``apply_leaf`` needs — the scheduled lr and
+        whatever depends on ``step`` alone (Adam-family bias corrections).
+        Computed once per step OUTSIDE the elementwise math, so the fused
+        Pallas kernel (ops/pallas_updater.py) receives them as inputs:
+        Mosaic has no ``powf`` and cannot trace ``beta**t`` in its body."""
+        return (lr,)
+
+    def apply_leaf(self, grad, state, *scalars):
+        """The elementwise updater math for one leaf, given
+        :meth:`scalars`. Return (update, new_state)."""
+        raise NotImplementedError
+
     def apply(self, grad, state, lr, step):
         """Return (update, new_state); params -= update downstream."""
-        raise NotImplementedError
+        return self.apply_leaf(grad, state, *self.scalars(lr, step))
 
     # -- fused step (ops/pallas_updater.py) ---------------------------------
     def _fusable(self) -> bool:
@@ -283,7 +296,7 @@ class Sgd(Updater):
 
     learning_rate: Any = 1e-1
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         return lr * grad, state
 
 
@@ -291,7 +304,7 @@ class Sgd(Updater):
 class NoOp(Updater):
     """NoOpUpdater: passes the raw gradient through (update = g)."""
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         return grad, state
 
 
@@ -304,7 +317,7 @@ class Frozen(Updater):
 
     learning_rate: Any = 0.0
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         return jnp.zeros_like(grad), state
 
 
@@ -322,7 +335,7 @@ class Nesterovs(Updater):
     def init_state(self, param):
         return {"v": jnp.zeros_like(param)}
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         mu = self.momentum
         v_prev = state["v"]
         v = mu * v_prev - lr * grad
@@ -342,7 +355,7 @@ class AdaGrad(Updater):
     def init_state(self, param):
         return {"h": jnp.full_like(param, self.epsilon)}
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         h = state["h"] + grad * grad
         update = lr * grad / (jnp.sqrt(h) + self.epsilon)
         return update, {"h": h}
@@ -359,7 +372,7 @@ class RmsProp(Updater):
     def init_state(self, param):
         return {"g2": jnp.full_like(param, self.epsilon)}
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         g2 = self.rms_decay * state["g2"] + (1 - self.rms_decay) * grad * grad
         update = grad * lr / jnp.sqrt(g2 + self.epsilon)
         return update, {"g2": g2}
@@ -375,7 +388,7 @@ class AdaDelta(Updater):
     def init_state(self, param):
         return {"msg": jnp.zeros_like(param), "msdx": jnp.zeros_like(param)}
 
-    def apply(self, grad, state, lr, step):
+    def apply_leaf(self, grad, state, lr):
         msg = self.rho * state["msg"] + (1 - self.rho) * grad * grad
         dx = (
             jnp.sqrt(state["msdx"] + self.epsilon)
@@ -383,6 +396,13 @@ class AdaDelta(Updater):
         ) * grad
         msdx = self.rho * state["msdx"] + (1 - self.rho) * dx * dx
         return dx, {"msg": msg, "msdx": msdx}
+
+
+def _adam_alpha(upd, lr, step):
+    """Bias-corrected step size shared by Adam and AmsGrad:
+    ``lr * sqrt(1-b2^t)/(1-b1^t)``."""
+    t = jnp.asarray(step, jnp.float32) + 1.0
+    return lr * jnp.sqrt(1 - upd.beta2**t) / (1 - upd.beta1**t)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -401,11 +421,12 @@ class Adam(Updater):
     def init_state(self, param):
         return {"m": jnp.zeros_like(param), "v": jnp.zeros_like(param)}
 
-    def apply(self, grad, state, lr, step):
-        t = jnp.asarray(step, jnp.float32) + 1.0
+    def scalars(self, lr, step):
+        return (_adam_alpha(self, lr, step),)
+
+    def apply_leaf(self, grad, state, alpha):
         m = self.beta1 * state["m"] + (1 - self.beta1) * grad
         v = self.beta2 * state["v"] + (1 - self.beta2) * grad * grad
-        alpha = lr * jnp.sqrt(1 - self.beta2**t) / (1 - self.beta1**t)
         update = alpha * m / (jnp.sqrt(v) + self.epsilon)
         return update, {"m": m, "v": v}
 
@@ -422,11 +443,14 @@ class AdaMax(Updater):
     def init_state(self, param):
         return {"m": jnp.zeros_like(param), "u": jnp.zeros_like(param)}
 
-    def apply(self, grad, state, lr, step):
+    def scalars(self, lr, step):
         t = jnp.asarray(step, jnp.float32) + 1.0
+        return (lr / (1 - self.beta1**t),)
+
+    def apply_leaf(self, grad, state, lr_hat):
         m = self.beta1 * state["m"] + (1 - self.beta1) * grad
         u = jnp.maximum(self.beta2 * state["u"], jnp.abs(grad))
-        update = lr / (1 - self.beta1**t) * m / (u + self.epsilon)
+        update = lr_hat * m / (u + self.epsilon)
         return update, {"m": m, "u": u}
 
 
@@ -442,15 +466,18 @@ class Nadam(Updater):
     def init_state(self, param):
         return {"m": jnp.zeros_like(param), "v": jnp.zeros_like(param)}
 
-    def apply(self, grad, state, lr, step):
+    def scalars(self, lr, step):
         t = jnp.asarray(step, jnp.float32) + 1.0
+        return (lr, 1 - self.beta1**t, 1 - self.beta2**t)
+
+    def apply_leaf(self, grad, state, lr, c1, c2):
         m = self.beta1 * state["m"] + (1 - self.beta1) * grad
         v = self.beta2 * state["v"] + (1 - self.beta2) * grad * grad
-        m_hat = m / (1 - self.beta1**t)
-        v_hat = v / (1 - self.beta2**t)
+        m_hat = m / c1
+        v_hat = v / c2
         update = (
             lr
-            * (self.beta1 * m_hat + (1 - self.beta1) * grad / (1 - self.beta1**t))
+            * (self.beta1 * m_hat + (1 - self.beta1) * grad / c1)
             / (jnp.sqrt(v_hat) + self.epsilon)
         )
         return update, {"m": m, "v": v}
@@ -472,12 +499,13 @@ class AmsGrad(Updater):
             "vhat": jnp.zeros_like(param),
         }
 
-    def apply(self, grad, state, lr, step):
-        t = jnp.asarray(step, jnp.float32) + 1.0
+    def scalars(self, lr, step):
+        return (_adam_alpha(self, lr, step),)
+
+    def apply_leaf(self, grad, state, alpha):
         m = self.beta1 * state["m"] + (1 - self.beta1) * grad
         v = self.beta2 * state["v"] + (1 - self.beta2) * grad * grad
         vhat = jnp.maximum(state["vhat"], v)
-        alpha = lr * jnp.sqrt(1 - self.beta2**t) / (1 - self.beta1**t)
         update = alpha * m / (jnp.sqrt(vhat) + self.epsilon)
         return update, {"m": m, "v": v, "vhat": vhat}
 

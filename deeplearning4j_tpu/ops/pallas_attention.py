@@ -56,6 +56,8 @@ from jax.experimental import pallas as pl
 # (VMEM scratch allocations); a build without it cannot run these kernels.
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops.registry import pallas_interpret
+
 logger = logging.getLogger(__name__)
 
 # Shortest kv length at which the Pallas kernel beats the XLA fused /
@@ -542,14 +544,6 @@ def flash_attention(q, k, v, kv_mask=None, dropout_seed=None,
                        block_q, block_k, interpret, dropout_rate)[0]
 
 
-def _resolve_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    from deeplearning4j_tpu.ops.registry import current_platform
-
-    return current_platform() != "tpu"
-
-
 def _norm_seed(dropout_seed, dropout_rate):
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("flash attention dropout_rate > 0 needs dropout_seed")
@@ -574,7 +568,7 @@ def _flash_call(q, k, v, kv_mask, dropout_seed, scale, causal, block_q,
     seed = _norm_seed(dropout_seed, dropout_rate)
     return _flash_fwd(q, k, v, kv_mask, seed, scale=scale, causal=causal,
                       block_q=block_q, block_k=block_k,
-                      interpret=_resolve_interpret(interpret),
+                      interpret=pallas_interpret(interpret),
                       dropout_rate=dropout_rate)
 
 
@@ -592,7 +586,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, dropout_rate, res, g):
     seed = _norm_seed(dropout_seed, dropout_rate)
     dq, dk, dv = _flash_bwd(q, k, v, kv_mask, seed, out, lse, g, scale=s,
                             causal=causal, block_q=block_q, block_k=block_k,
-                            interpret=_resolve_interpret(interpret),
+                            interpret=pallas_interpret(interpret),
                             dropout_rate=dropout_rate)
     return dq, dk, dv, None, None
 
@@ -728,7 +722,7 @@ def _paged_decode_call(q, k_pages, v_pages, page_table, seq_lens, *,
     page = k_pages.shape[1]
     max_pages = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    interpret = _resolve_interpret(interpret)
+    interpret = pallas_interpret(interpret)
     kernel = functools.partial(_paged_decode_kernel, page=page, scale=scale,
                                heads=h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -803,7 +797,7 @@ def _check_paged_decode_attention():
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
     got_pl = _paged_decode_call(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(pt), jnp.asarray(sl), interpret=True)
+        jnp.asarray(pt), jnp.asarray(sl))
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
 
 

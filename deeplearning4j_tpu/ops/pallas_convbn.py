@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops.registry import current_platform, pallas_interpret
+
 
 def _pick_block(size: int, candidates=(512, 384, 256, 128)) -> int:
     for c in candidates:
@@ -78,7 +80,7 @@ def _kernel(x_ref, sc_ref, sh_ref, w_ref, stat_shift_ref,
 def fused_bn_matmul_stats(x, scale, shift, w, stat_shift, *, relu: bool = True,
                           fuse_prologue: bool = True, block_m: int = 0,
                           block_n: int = 0, block_k: int = 0,
-                          interpret: bool = False):
+                          interpret=None):
     """relu(x·scale+shift) @ w with shifted-stats epilogue, one HBM pass.
 
     x: (M, K) activations (bf16; raw previous-conv output when
@@ -88,10 +90,11 @@ def fused_bn_matmul_stats(x, scale, shift, w, stat_shift, *, relu: bool = True,
     this conv's biased batch statistics, ready for the BN running-buffer
     update and normalize scale.
     """
-    m, k_dim = x.shape
-    n = w.shape[1]
     from deeplearning4j_tpu.ops import tuning
 
+    interpret = pallas_interpret(interpret)
+    m, k_dim = x.shape
+    n = w.shape[1]
     bucket = tuning.bucket_mkn(m, k_dim, n)
     bm = block_m or tuning.tuned_block("fused_bn_matmul_stats", "block_m",
                                        m, bucket, _pick_block)
@@ -148,10 +151,7 @@ def _pallas_ok(x, w) -> bool:
     policy, ragged shapes) the reference XLA chain runs — same math."""
     if os.environ.get("DL4J_TPU_DISABLE_PALLAS_CONVBN") == "1":
         return False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:  # pragma: no cover
+    if current_platform() != "tpu":
         return False
     m, k = x.shape
     n = w.shape[1]

@@ -31,7 +31,7 @@ from jax.experimental import pallas as pl
 
 from deeplearning4j_tpu.ops.nn_ops import (
     FUSED_MATMUL_ACTIVATIONS, apply_fused_activation)
-from deeplearning4j_tpu.ops.registry import op
+from deeplearning4j_tpu.ops.registry import op, pallas_interpret
 
 
 @op("fused_layer_norm")
@@ -88,10 +88,7 @@ def fused_layer_norm_pallas(x, gain, bias=None, *, eps: float = 1e-5,
 
     Leading dims fold into rows; rows must divide by the (tuned) row block
     and D by 128 — the usable() gate guarantees both on the dispatch path."""
-    if interpret is None:
-        from deeplearning4j_tpu.ops.registry import current_platform
-
-        interpret = current_platform() != "tpu"
+    interpret = pallas_interpret(interpret)
     lead = x.shape[:-1]
     d = x.shape[-1]
     rows = 1
@@ -235,7 +232,7 @@ def _check_fused_layer_norm():
                                    atol=1e-5)
         got_pl = fused_layer_norm_pallas(
             jnp.asarray(x), jnp.asarray(g), jnp.asarray(b), eps=eps,
-            activation=act, block_rows=8, interpret=True)
+            activation=act, block_rows=8)
         np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4,
                                    atol=1e-5)
 

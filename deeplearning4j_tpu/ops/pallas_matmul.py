@@ -30,6 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.nn_ops import (
     FUSED_MATMUL_ACTIVATIONS, apply_fused_activation, fused_matmul_bias_act)
+from deeplearning4j_tpu.ops.registry import pallas_interpret
 
 
 def _pick_block(size: int, candidates=(512, 256, 128)) -> int:
@@ -74,10 +75,7 @@ def fused_matmul_bias_act_pallas(x, w, b=None, *, activation: str = "none",
     Accepts 2-D or 3-D ``x`` (leading batch folded into rows); transpose
     flags are rejected by the usable() gate but handled here defensively
     by materializing the transpose before the kernel."""
-    if interpret is None:
-        from deeplearning4j_tpu.ops.registry import current_platform
-
-        interpret = current_platform() != "tpu"
+    interpret = pallas_interpret(interpret)
     if transpose_a:
         x = jnp.swapaxes(x, -1, -2)
     if transpose_b:
@@ -247,8 +245,7 @@ def _check_fused_matmul_bias_act():
         np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=1e-4, atol=1e-5)
         got_pl = fused_matmul_bias_act_pallas(
-            jnp.asarray(x), jnp.asarray(w), jnp.asarray(b), activation=act,
-            interpret=True)
+            jnp.asarray(x), jnp.asarray(w), jnp.asarray(b), activation=act)
         np.testing.assert_allclose(np.asarray(got_pl), want,
                                    rtol=1e-4, atol=1e-5)
 

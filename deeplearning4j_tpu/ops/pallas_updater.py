@@ -14,10 +14,12 @@ boundaries. ``fused_updater_step`` makes the one-HBM-pass contract explicit:
   same ``Updater.apply``), so trajectories are bit-identical to the unfused
   step everywhere — the op is safe on the default train path.
 * the **Pallas TPU helper** flattens the leaf to (rows, 128) lanes and runs
-  the identical ``apply`` math inside one kernel: param, grad and every
-  state buffer are read once, new param + state written once. All 11
+  the identical ``apply_leaf`` math inside one kernel: param, grad and
+  every state buffer are read once, new param + state written once. All 11
   updater kinds (Sgd…AmsGrad) share this one kernel — the per-kind math is
-  traced into the kernel body from the same dataclasses.
+  traced into the kernel body from the same dataclasses; the per-step
+  scalars (``Updater.scalars``: lr, Adam-family bias corrections) are
+  computed in the wrapper and ride in through SMEM.
 * dispatch consults the tuning table (``fused_updater_step.min_size``):
   below the measured crossover the generic XLA chain wins (kernel launch
   overhead), above it the fused kernel does — ``ops/tuning.py``.
@@ -35,8 +37,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.registry import op
+from deeplearning4j_tpu.ops.registry import op, pallas_interpret
 
 LANES = 128
 
@@ -81,19 +84,21 @@ def fused_updater_step(param, grad, lr, step, *state, kind: str = "Sgd",
 # ---------------------------------------------------------------------------
 
 
-def _kernel(lr_ref, step_ref, p_ref, g_ref, *refs, apply_fn, keys):
+def _kernel(scal_ref, p_ref, g_ref, *refs, apply_leaf, keys, n_scalars):
     """One (block_rows, 128) tile: the full updater chain, traced from the
-    same dataclass ``apply`` as the generic impl — the kernel cannot drift
-    from the reference math because it IS the reference math. Stores cast
-    back to the ref dtype: the f32 lr/step scalars promote the chain, and
-    an un-cast f32 store into a bf16 param ref is a Mosaic trace error."""
+    same dataclass ``apply_leaf`` as the generic impl — the kernel cannot
+    drift from the reference math because it IS the reference math. Tiles
+    widen to f32 on load and stores cast back to the ref dtype: a no-op
+    for f32 leaves (bit-identical to the generic impl), and for bf16
+    leaves the only form a v5e accepts — its transcendental unit has no
+    bf16 ops, so a bf16 ``sqrt`` in the body fails the TPU compiler."""
     n = len(keys)
     state_refs, out_refs = refs[:n], refs[n:]
-    lr = lr_ref[0, 0]
-    step = step_ref[0, 0]
-    st = {k: r[...] for k, r in zip(keys, state_refs)}
-    u, new = apply_fn(g_ref[...], st, lr, step)
-    out_refs[0][...] = (p_ref[...] - u).astype(out_refs[0].dtype)
+    scalars = [scal_ref[i] for i in range(n_scalars)]
+    f32 = jnp.float32
+    st = {k: r[...].astype(f32) for k, r in zip(keys, state_refs)}
+    u, new = apply_leaf(g_ref[...].astype(f32), st, *scalars)
+    out_refs[0][...] = (p_ref[...].astype(f32) - u).astype(out_refs[0].dtype)
     for k, r in zip(keys, out_refs[1:]):
         r[...] = new[k].astype(r.dtype)
 
@@ -112,10 +117,7 @@ def fused_updater_helper(param, grad, lr, step, *state, kind: str = "Sgd",
     cells compute garbage that is sliced off; every updater's denominators
     carry an eps, so pads cannot NaN). One grid dimension walks row
     blocks; param/grad/state stream through VMEM once."""
-    if interpret is None:
-        from deeplearning4j_tpu.ops.registry import current_platform
-
-        interpret = current_platform() != "tpu"
+    interpret = pallas_interpret(interpret)
     upd, keys = _updater_and_keys(kind, tuple(sorted(hyper.items())))
     if len(state) != len(keys):
         raise ValueError(
@@ -136,20 +138,22 @@ def fused_updater_helper(param, grad, lr, step, *state, kind: str = "Sgd",
         return flat.reshape(rows, LANES)
 
     tiles = [to_tile(a) for a in (param, grad) + tuple(state)]
-    scalar = lambda v: jnp.asarray(v, jnp.float32).reshape(1, 1)
+    scalars = jnp.stack([jnp.asarray(v, jnp.float32)
+                         for v in upd.scalars(lr, step)])
     grid = (rows // block_rows,)
     tile_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     n_out = 1 + len(keys)
     outs = pl.pallas_call(
-        functools.partial(_kernel, apply_fn=upd.apply, keys=keys),
+        functools.partial(_kernel, apply_leaf=upd.apply_leaf, keys=keys,
+                          n_scalars=scalars.shape[0]),
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), t.dtype)
                    for t in tiles[:1] + tiles[2:]],
         grid=grid,
-        in_specs=[scalar_spec, scalar_spec] + [tile_spec] * len(tiles),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [tile_spec] * len(tiles),
         out_specs=[tile_spec] * n_out,
         interpret=interpret,
-    )(scalar(lr), scalar(step), *tiles)
+    )(scalars, *tiles)
     if n_out == 1:
         outs = [outs] if not isinstance(outs, (list, tuple)) else outs
     return tuple(o.reshape(-1)[:size].reshape(shape) for o in outs)
@@ -183,7 +187,7 @@ def _usable(param, grad, lr, step, *state, **kw):
 
 def _check_fused_updater_step():
     """Validation case (ops.validation ratchet): generic vs the literal
-    nn/updater.py math, and the Pallas interpret kernel vs both, for a
+    nn/updater.py math, and the Pallas kernel vs both, for a
     stateful kind (Adam) and a stateless one (Sgd)."""
     import numpy as np
 
@@ -202,16 +206,14 @@ def _check_fused_updater_step():
     got = fused_updater_step.fn(p, g, lr, step, st["m"], st["v"],
                                 kind="Adam", beta1=0.85)
     got_pl = fused_updater_helper(p, g, lr, step, st["m"], st["v"],
-                                  kind="Adam", beta1=0.85, block_rows=8,
-                                  interpret=True)
+                                  kind="Adam", beta1=0.85, block_rows=8)
     for w, a, b in zip(want, got, got_pl):
         np.testing.assert_allclose(np.asarray(a), w, rtol=1e-6, atol=1e-7)
         np.testing.assert_allclose(np.asarray(b), w, rtol=1e-6, atol=1e-7)
 
     u, _ = Sgd(learning_rate=0.1).apply(g, {}, lr, step)
     got = fused_updater_step.fn(p, g, lr, step, kind="Sgd")
-    got_pl = fused_updater_helper(p, g, lr, step, kind="Sgd", block_rows=8,
-                                  interpret=True)
+    got_pl = fused_updater_helper(p, g, lr, step, kind="Sgd", block_rows=8)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(p - u),
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(got_pl[0]), np.asarray(p - u),

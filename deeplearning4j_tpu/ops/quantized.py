@@ -36,7 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 # shared divisibility-first block picker (one definition; drift between
 # per-module copies is how alignment fixes get lost)
 from deeplearning4j_tpu.ops.pallas_matmul import _pick_block
-from deeplearning4j_tpu.ops.registry import op
+from deeplearning4j_tpu.ops.registry import op, pallas_interpret
 
 _QMAX = 127.0
 
@@ -126,18 +126,16 @@ def _kernel(xq_ref, xs_ref, wq_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # int8 MXU tiles with an f32 VMEM accumulator: K is bounded by the
-    # f32 mantissa for exactness (|acc| <= K·127² must stay < 2^24 per
-    # block step — block_k <= 1024 guarantees it), and f32 scratch keeps
-    # the epilogue de-scale a pure in-register multiply
+    # int8 MXU tiles accumulate in int32 (exact, like the generic impl —
+    # Mosaic refuses an int8 x int8 dot that asks for a float result);
+    # the cast to f32 happens once, before the epilogue de-scale
     acc_ref[:] += jax.lax.dot_general(
         xq_ref[0], wq_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT)
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _():
-        y = acc_ref[:] * xs_ref[0] * ws_ref[0]
+        y = acc_ref[:].astype(jnp.float32) * xs_ref[0] * ws_ref[0]
         o_ref[0] = y.astype(o_ref.dtype)
 
 
@@ -145,10 +143,7 @@ def matmul_int8_pallas(x, w_q, w_scale, *, block_m: int = 0,
                        block_n: int = 0, block_k: int = 0, interpret=None):
     """Pallas forward for matmul_int8: quantize rows via XLA, then one
     blocked int8 MXU kernel with the de-scale epilogue in VMEM."""
-    if interpret is None:
-        from deeplearning4j_tpu.ops.registry import current_platform
-
-        interpret = current_platform() != "tpu"
+    interpret = pallas_interpret(interpret)
     from deeplearning4j_tpu.ops import tuning
 
     lead = x.shape[:-2] if x.ndim > 2 else ()
@@ -183,7 +178,7 @@ def matmul_int8_pallas(x, w_q, w_scale, *, block_m: int = 0,
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, j, k: (0, i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -252,7 +247,7 @@ def _check_matmul_int8():
     got = matmul_int8.fn(jnp.asarray(x), wq, ws)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
     got_pl = matmul_int8_pallas(jnp.asarray(x), wq, ws, block_m=32,
-                                block_k=128, block_n=128, interpret=True)
+                                block_k=128, block_n=128)
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-5,
                                atol=1e-6)
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import math
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -47,6 +48,44 @@ def current_platform() -> str:
             return plat
         return str(dev).split(":")[0]
     return jax.default_backend()
+
+
+def pallas_interpret(requested: Optional[bool] = None) -> bool:
+    """THE interpret-mode decision for every Pallas wrapper in ``ops/``.
+
+    ``None`` (the dispatch path) follows the target platform: Mosaic on
+    TPU, the interpreter elsewhere so CPU tests run the same kernel body.
+    An explicit ``False`` is honored anywhere (the compile tests lower for
+    a described TPU from a CPU process); an explicit ``True`` while the
+    target is a TPU raises — the chip must never run a kernel through the
+    interpreter by accident."""
+    on_tpu = current_platform() == "tpu"
+    if requested is None:
+        return not on_tpu
+    if requested and on_tpu:
+        raise ValueError("Pallas interpret mode requested while the target "
+                         "platform is tpu")
+    return bool(requested)
+
+
+def _compiler_partitions_trace() -> bool:
+    """Whether the computation being traced is one the compiler will
+    partition over several devices: a mesh context whose non-manual axes
+    span more than one device (``ParallelWrapper``'s ``with mesh:``, or
+    ``jax.set_mesh``). JAX refuses to lower a Mosaic kernel there ("cannot
+    be automatically partitioned"), so on a TPU the helper table defers to
+    the generic impl, which GSPMD partitions like any other XLA op. Inside
+    ``shard_map`` every axis is manual, each device runs its own kernel,
+    and helpers stay on. ``jit`` keys its trace cache on the context mesh,
+    so the decision cannot go stale between one-chip and mesh calls."""
+    # the legacy ``with mesh:`` context has no public reader in jax 0.9
+    from jax._src import mesh as mesh_lib
+
+    abstract = jax.sharding.get_abstract_mesh()
+    shape = dict(abstract.shape) or dict(
+        mesh_lib.thread_resources.env.physical_mesh.shape)
+    return math.prod(size for axis, size in shape.items()
+                     if axis not in abstract.manual_axes) > 1
 
 
 def _note_dispatch(op: str, impl: str, reason: str) -> None:
@@ -89,22 +128,21 @@ class OpDescriptor:
         if impl is None:
             _note_dispatch(self.name, "generic", "no_helper")
             return self.fn
+        if backend == "tpu" and _compiler_partitions_trace():
+            _note_dispatch(self.name, "generic", "partitioned")
+            return self.fn
         # the usable() gate must come from the SAME table entry as the
         # impl — looking it up under the current backend would silently
         # skip the gate for the forced-pallas fallback path
         usable = self.platform_usable.get(impl_key, lambda *a, **k: True)
-        try:
-            ok = usable(*args, **kwargs)
-            reason = "usable" if ok else "not_usable"
-        except Exception:  # pragma: no cover - defensive
-            ok = False
-            reason = "usable_error"
-        if ok:
+        # a raising gate propagates: swallowing it would quietly run the
+        # generic impl where the helper was meant to
+        if usable(*args, **kwargs):
             if env.log_helper_selection:
                 logger.info("op %s: selected %s platform helper", self.name, backend)
-            _note_dispatch(self.name, impl_key, reason)
+            _note_dispatch(self.name, impl_key, "usable")
             return impl
-        _note_dispatch(self.name, "generic", reason)
+        _note_dispatch(self.name, "generic", "not_usable")
         return self.fn
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:

@@ -8,14 +8,19 @@ over XLA at t=8192 yet 0.65–0.99× below t=4096 — one hardcoded
 
 * the **tuning table**: a JSON document keyed on device kind holding, per
   op, pallas-vs-XLA crossover thresholds and per-shape-bucket Pallas block
-  sizes. A checked-in default table (``ops/tuning_tables/default.json``)
-  keeps CPU/untuned hosts deterministic; a measured table in the cache dir
-  (``DL4J_TPU_TUNING_DIR``, default ``~/.cache/dl4j_tpu/tuning``) overlays
-  it; ``DL4J_TPU_*`` env overrides (read by the dispatch sites) still win.
+  sizes. Dispatch reads the checked-in tables (``ops/tuning_tables/``:
+  ``default.json``, then ``<device_kind>.json`` when one was committed) and
+  nothing else, so every machine with the same checkout dispatches alike.
+  Only an explicit ``DL4J_TPU_TUNING_DIR`` adds a measured overlay from
+  that directory; ``DL4J_TPU_*`` env overrides (read by the dispatch
+  sites) still win.
 * the **autotuner** (:func:`autotune`): times candidate configurations with
   AOT lowering — ``jax.jit(fn).lower(*args).compile()`` — so measurement
   runs never contaminate the process jit cache (the SNIPPETS AOT idiom),
-  and persists the winners. ``tools/tune.py`` is the CLI;
+  and persists the winners — to ``DL4J_TPU_TUNING_DIR`` when set, else to
+  the git-ignored ``.tuning/`` at the root of the checkout, from where a
+  table is committed by copying it under ``ops/tuning_tables/``.
+  ``tools/tune.py`` is the CLI;
   ``make tune-smoke`` runs a tiny-shape pass that must exit 0 anywhere.
 * the **dispatch feed**: ``flash_min_t()``, the Pallas block pickers in
   ``pallas_attention``/``pallas_matmul``/``pallas_convbn``/``quantized``,
@@ -51,6 +56,8 @@ import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from deeplearning4j_tpu.environment import CHECKOUT_ROOT
+
 logger = logging.getLogger(__name__)
 
 SCHEMA = "dl4j_tpu_tuning_v1"
@@ -58,6 +65,8 @@ ENV_DIR = "DL4J_TPU_TUNING_DIR"
 
 _PACKAGE_TABLE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                   "tuning_tables")
+# where the autotuner writes when DL4J_TPU_TUNING_DIR is unset
+_CHECKOUT_TUNING_DIR = os.path.join(CHECKOUT_ROOT, ".tuning")
 
 # memoized per-device-kind merged tables + once-only warnings for corrupt
 # files; reset_tables() is the test seam and runs after autotune() saves
@@ -86,13 +95,9 @@ def current_device_kind() -> str:
     dev = jax.config.jax_default_device
     if dev is not None and getattr(dev, "device_kind", None):
         return normalize_device_kind(dev.device_kind)
-    try:
-        # justified: tuned() runs at op-resolve time, strictly after the
-        # caller has already initialized/touched the backend — a probe that
-        # could hang would have hung the caller's own computation first
-        return normalize_device_kind(jax.devices()[0].device_kind)  # graftlint: disable=GL002
-    except Exception:  # pragma: no cover - backendless probe
-        return normalize_device_kind(jax.default_backend())
+    # a backend that cannot list its devices raises here: there is no
+    # device kind to dispatch for
+    return normalize_device_kind(jax.devices()[0].device_kind)
 
 
 def pow2_bucket(n: int) -> int:
@@ -134,6 +139,9 @@ class TuningTable:
     device_kind: str
     entries: Dict[str, Dict[str, Any]] = dataclasses.field(default_factory=dict)
     source: str = ""
+    # every file merged into this table, in merge order (active_table fills
+    # it; chip_smoke.py prints it so a run says what steered its dispatch)
+    sources: List[str] = dataclasses.field(default_factory=list)
 
     # -- reads ---------------------------------------------------------------
     def get(self, op: str, key: str, default: Any = None) -> Any:
@@ -211,16 +219,13 @@ class TuningTable:
 
 
 # ---------------------------------------------------------------------------
-# loading: checked-in default, then measured cache overlay
+# loading: checked-in tables; a measured overlay only on explicit request
 # ---------------------------------------------------------------------------
 
 
 def tuning_dir() -> str:
-    d = os.environ.get(ENV_DIR)
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache", "dl4j_tpu",
-                        "tuning")
+    """Where the autotuner and the sweep tools write measured tables."""
+    return os.environ.get(ENV_DIR) or _CHECKOUT_TUNING_DIR
 
 
 def cache_path(device_kind: Optional[str] = None) -> str:
@@ -243,6 +248,7 @@ def _load_or_warn(table: TuningTable, path: str) -> None:
         return
     try:
         table.merge(TuningTable.load(path))
+        table.sources.append(path)
     except (ValueError, TypeError, AttributeError, OSError,
             json.JSONDecodeError) as e:
         # corrupt measured table: fall back to the checked-in defaults —
@@ -253,7 +259,10 @@ def _load_or_warn(table: TuningTable, path: str) -> None:
 
 
 def active_table(device_kind: Optional[str] = None) -> TuningTable:
-    """The merged (default ⊕ measured) table for a device kind, memoized."""
+    """The table dispatch reads for a device kind, memoized: the
+    checked-in tables, plus the measured overlay from
+    ``DL4J_TPU_TUNING_DIR`` when (and only when) that variable is set —
+    dispatch never depends on a file git does not see unless asked to."""
     kind = device_kind or current_device_kind()
     cached = _ACTIVE.get(kind)
     if cached is not None:
@@ -261,7 +270,8 @@ def active_table(device_kind: Optional[str] = None) -> TuningTable:
     table = TuningTable(device_kind=kind)
     for path in default_table_paths(kind):
         _load_or_warn(table, path)
-    _load_or_warn(table, cache_path(kind))
+    if os.environ.get(ENV_DIR):
+        _load_or_warn(table, cache_path(kind))
     _ACTIVE[kind] = table
     return table
 
@@ -635,14 +645,13 @@ def _tune_convbn(table: TuningTable, smoke: bool) -> int:
             sh = jnp.asarray(r.randn(k).astype(np.float32) * 0.1)
             w = jnp.asarray((r.randn(k, nn_) * k ** -0.5).astype(np.float32))
             ss = jnp.asarray(r.randn(nn_).astype(np.float32) * 0.1)
-            interpret = current_device_kind().find("tpu") < 0
             best = None
             for bm in cands:
                 if m % bm:
                     continue
                 sec = aot_time(
                     lambda *a, _bm=bm: fused_bn_matmul_stats(
-                        *a, block_m=_bm, interpret=interpret),
+                        *a, block_m=_bm),
                     (x, sc, sh, w, ss))
                 n += 1
                 if best is None or sec < best[0]:
@@ -672,9 +681,11 @@ def autotune(ops: Optional[Sequence[str]] = None, smoke: bool = False,
 
     ``smoke`` shrinks every ladder to shapes that finish in seconds on a
     CPU interpret-mode host (the ``make tune-smoke`` contract: exits 0
-    anywhere, produces a valid table). ``save`` writes the table to the
-    cache dir and invalidates the memoized readers so the measurement is
-    live in the same process."""
+    anywhere, produces a valid table). ``save`` writes the table to
+    :func:`tuning_dir` and invalidates the memoized readers; the
+    measurement steers dispatch in the same process only when
+    ``DL4J_TPU_TUNING_DIR`` names that directory (see
+    :func:`active_table`)."""
     kind = device_kind or current_device_kind()
     table = TuningTable(device_kind=kind)
     report = TuneReport(device_kind=kind)

@@ -12,8 +12,8 @@ gradient exchange is inside the compiled step (ICI/DCN collectives), not a
 transport we operate. Data: deterministic per-host shard assignment
 (host_id → slice of files/examples), the VirtualDataSetIterator role.
 
-In this 1-chip environment multi-host paths are exercised via
-multi-process CPU tests (SURVEY §5.5 translation).
+Multi-host paths are exercised via multi-process CPU tests (SURVEY §5.5
+translation); see :func:`launch` for what its workers may touch.
 """
 
 from __future__ import annotations
@@ -131,11 +131,20 @@ def _free_port() -> int:
 
 def launch(nprocs: int, argv: Sequence[str], restarts: int = 0,
            env_extra: Optional[dict] = None, timeout: float = 600.0) -> int:
-    """Run ``argv`` as ``nprocs`` coordinated worker processes.
+    """Run ``argv`` as ``nprocs`` coordinated worker processes ON THIS HOST.
 
     Returns the exit code (0 = all workers succeeded on some attempt).
     Each attempt uses a fresh coordinator port; workers read the cluster
-    layout from DL4J_TPU_* env vars via initialize_distributed()."""
+    layout from DL4J_TPU_* env vars via initialize_distributed().
+
+    A TPU chip has one owner, and on a TPU host ONE process drives all
+    local chips (``ParallelWrapper`` over ``make_mesh()``; across hosts,
+    one such process per host, each calling ``initialize_distributed``).
+    Several workers on one host would fight over the chips, so with
+    ``nprocs > 1`` every worker is pinned to the CPU backend
+    (``JAX_PLATFORMS=cpu``): this launcher is the rehearsal and elasticity
+    harness of the multi-process protocol, not a way onto the chips.
+    ``env_extra`` is applied last and can say otherwise."""
     import subprocess
     import sys
     import time
@@ -145,6 +154,8 @@ def launch(nprocs: int, argv: Sequence[str], restarts: int = 0,
         procs = []
         for pid in range(nprocs):
             env = dict(os.environ)
+            if nprocs > 1:
+                env["JAX_PLATFORMS"] = "cpu"
             env.update(env_extra or {})
             env.update({
                 "DL4J_TPU_COORDINATOR": f"127.0.0.1:{port}",

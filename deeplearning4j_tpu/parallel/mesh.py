@@ -463,7 +463,7 @@ class ParallelInference:
                 if item is not None and not item[1].done():
                     # graftlife: justified(GR003): ParallelInference futures
                     # are batch-inference calls, not GenerationRequests — the
-                    # FINISH_REASONS taxonomy covers the generative stack only
+                    # FINISH_REASONS vocabulary covers the generative stack only
                     item[1].set_exception(
                         RuntimeError("ParallelInference stopped before this "
                                      "request was served"))
@@ -594,7 +594,7 @@ class ParallelInference:
             off = 0
             for fut, sz in zip(futs, sizes):
                 # graftlife: justified(GR003): batch-inference futures, not
-                # GenerationRequests — the FINISH_REASONS taxonomy covers
+                # GenerationRequests — the FINISH_REASONS vocabulary covers
                 # the generative serving stack only
                 fut.set_result(out[off:off + sz])
                 off += sz

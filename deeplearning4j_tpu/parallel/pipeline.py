@@ -27,12 +27,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def stack_stage_params(per_stage_params) -> Any:
@@ -86,11 +82,8 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, *, num_microbatches: int,
 
         act0 = jnp.zeros_like(micro[0])
         # the carry becomes device-varying after the first ppermute; mark
-        # the initial carry varying too (jax>=0.8 VMA checking)
-        if hasattr(jax.lax, "pcast"):
-            act0 = jax.lax.pcast(act0, (axis,), to="varying")
-        elif hasattr(jax.lax, "pvary"):
-            act0 = jax.lax.pvary(act0, (axis,))
+        # the initial carry varying too (VMA checking)
+        act0 = jax.lax.pcast(act0, (axis,), to="varying")
         _, ys = jax.lax.scan(tick, act0, jnp.arange(ticks))
         # ys[t] = this stage's output at tick t; the final stage emitted
         # microbatch j at tick j + S - 1

@@ -66,10 +66,7 @@ def ulysses_attention(q, k, v, *, mesh: Mesh, axis: str = "seq",
     """All-to-all sequence-parallel attention. q/k/v: (B, H, T, D) GLOBAL
     shapes, sharded over T on ``axis``. num_heads must divide by the axis
     size."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis]
     h = q.shape[1]

@@ -45,7 +45,7 @@ Every decision is observable: ``dl4j_tpu_slo_state`` (0/1/2),
 ``dl4j_tpu_slo_transitions_total{to}``, ``dl4j_tpu_slo_breaker_open``,
 plus ``slo_state``/``slo_shed``/``slo_breaker`` JSONL events
 (docs/OBSERVABILITY.md). Frontend sheds complete with the SAME terminal
-taxonomy as the engine (``FINISH_REASONS``; counted once in
+vocabulary as the engine (``FINISH_REASONS``; counted once in
 ``dl4j_tpu_serving_evicted_total{reason}`` via
 :func:`~deeplearning4j_tpu.serving.scheduler.count_terminal`).
 
@@ -628,7 +628,7 @@ class SLOFrontend:
         """Complete a denied admission terminally (never an exception:
         overload is an expected state, and callers always get an answer).
         Counts ONCE in the slo_shed family AND once in the shared
-        terminal-reason taxonomy.
+        terminal-reason vocabulary.
 
         ``deferred`` is the post-lock completion list from ``_admit``:
         ``set_result`` fires done-callbacks synchronously, so completing

@@ -46,7 +46,7 @@ from deeplearning4j_tpu import observe
 # "deadline" = its per-request deadline expired (queued or mid-decode),
 # "error" = a worker crash consumed its whole retry budget OR the
 # frontend's circuit breaker fast-failed it. The SLO frontend consumes
-# these as load signals AND produces them — one shared taxonomy, so
+# these as load signals AND produces them — one shared vocabulary, so
 # ``dl4j_tpu_serving_evicted_total{reason}`` is the single place every
 # terminal outcome is counted (asserted in tests/test_frontend.py).
 FINISH_REASONS = ("eos", "length", "overflow", "oom", "stopped",
@@ -56,7 +56,7 @@ FINISH_REASONS = ("eos", "length", "overflow", "oom", "stopped",
 def count_terminal(reason: str) -> None:
     """Increment the ONE terminal-outcome counter family. Every path that
     completes a request — retire, unslotted finish, fail_all/fail_pending,
-    frontend sheds — funnels through here so the taxonomy cannot drift."""
+    frontend sheds — funnels through here so the vocabulary cannot drift."""
     if reason not in FINISH_REASONS:
         raise ValueError(f"unknown finish reason {reason!r}")
     observe.metrics().counter(
@@ -325,7 +325,7 @@ class SlotScheduler:
         blocked callers wake instead of hanging (the ParallelInference.stop
         contract). Each future actually failed here counts ONCE under
         ``dl4j_tpu_serving_evicted_total{reason}`` — exception exits share
-        the terminal-reason taxonomy with result exits."""
+        the terminal-reason vocabulary with result exits."""
         for slot in list(self.slots):
             st = self.slots.pop(slot, None)  # tolerate a concurrent caller
             if st is not None and not st.future.done():

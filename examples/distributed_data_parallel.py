@@ -13,7 +13,6 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

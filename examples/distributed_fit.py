@@ -52,12 +52,6 @@ def main() -> int:
 
     import jax
 
-    # honor an explicit JAX_PLATFORMS=cpu via jax.config: a sitecustomize
-    # that pins another platform wins over the env var alone, and this
-    # multi-process demo must not have N workers fight over one real chip
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     # cluster formation MUST precede any backend-initializing jax call, and
     # importing the framework creates RNG keys — so initialize first
     from deeplearning4j_tpu.parallel.launch import initialize_distributed

@@ -6,11 +6,6 @@ import os
 
 import numpy as np
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from deeplearning4j_tpu import nn
 from deeplearning4j_tpu.arbiter import (
     ContinuousParameterSpace, DiscreteParameterSpace,

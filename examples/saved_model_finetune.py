@@ -8,11 +8,6 @@ import tempfile
 
 import numpy as np
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import tensorflow as tf
 
 from deeplearning4j_tpu import nn

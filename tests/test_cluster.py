@@ -196,7 +196,7 @@ class TestMigration:
             np.testing.assert_array_equal(
                 x.tokens, reference_generate(MODEL.params, CFG, p, 6))
         assert _serving_new_shape_count() == new_shape0
-        # terminal taxonomy: every request exactly one labelled counter
+        # terminal vocabulary: every request exactly one labelled counter
         assert (sum(evicted_counts().values())
                 == before_terminal + len(prompts))
         r.check_invariants()

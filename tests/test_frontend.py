@@ -367,11 +367,11 @@ class TestCircuitBreaker:
 
 
 # ---------------------------------------------------------------------------
-# one terminal taxonomy (satellite)
+# one terminal vocabulary (satellite)
 # ---------------------------------------------------------------------------
 
 
-class TestTerminalTaxonomy:
+class TestTerminalVocabulary:
     def test_count_terminal_rejects_unknown_reasons(self):
         with pytest.raises(ValueError, match="unknown finish reason"):
             count_terminal("vibes")
@@ -428,7 +428,7 @@ class TestTerminalTaxonomy:
 
     def test_all_reason_labels_are_in_finish_reasons(self):
         """Every reason label the counter family has ever seen must come
-        from the shared taxonomy."""
+        from the shared vocabulary."""
         fe = SLOFrontend(StubEngine(), max_queue_total=0)
         fe.submit(PROMPT, slo_class="batch")
         sched = SlotScheduler(1)
@@ -680,7 +680,7 @@ class TestFrontendEngineIntegration:
 
     def test_engine_submit_accepts_class_kwargs(self):
         """Plain engine.submit carries class labels through to results
-        (the frontend-free path keeps the taxonomy)."""
+        (the frontend-free path keeps the vocabulary)."""
         eng = self._engine()
         res = eng.generate([PROMPT], max_new_tokens=2, eos_token=-1,
                            slo_class="batch", priority=2)

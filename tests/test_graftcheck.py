@@ -115,7 +115,12 @@ class TestSymbolicDims:
         f32, i32 = np.dtype(np.float32), np.dtype(np.int32)
         assert promotion_surprise([f32, f32]) is None
         assert promotion_surprise([f32, i32]) is None  # ordinary promotion
-        assert promotion_surprise([i32, np.dtype(np.uint32)])  # widens
+        # x64 off (how programs run here): stays int32, uint32 wraps;
+        # x64 on: widens to int64 — a surprise either way
+        u32 = np.dtype(np.uint32)
+        assert promotion_surprise([i32, u32])
+        assert promotion_surprise([i32, np.dtype(np.uint16)]) is None  # fits
+        assert promotion_surprise([np.dtype(np.int8), np.dtype(np.uint8)])
         import jax.numpy as jnp
         assert promotion_surprise([np.dtype(jnp.bfloat16), f32])  # mixed
 

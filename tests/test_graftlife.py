@@ -288,7 +288,7 @@ class TestGR002DoubleRelease:
 
 
 # ---------------------------------------------------------------------------
-# GR003 — terminal-taxonomy exactly-once
+# GR003 — terminal-vocabulary exactly-once
 # ---------------------------------------------------------------------------
 
 

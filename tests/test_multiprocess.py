@@ -16,7 +16,6 @@ proc_id = int(sys.argv[1]); nprocs = int(sys.argv[2]); port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from deeplearning4j_tpu.parallel import initialize_distributed, host_shard
 initialize_distributed(coordinator_address=f"127.0.0.1:{port}",
                        num_processes=nprocs, process_id=proc_id)
@@ -101,7 +100,6 @@ class TestDistributedWord2Vec:
         worker = tmp_path / "w2v_worker.py"
         worker.write_text("""
 import jax
-jax.config.update("jax_platforms", "cpu")
 import sys, numpy as np
 sys.path.insert(0, %r)
 from deeplearning4j_tpu.parallel.launch import initialize_distributed

@@ -128,7 +128,6 @@ class TestDistributedEvaluate:
         worker.write_text(
             """
 import jax
-jax.config.update("jax_platforms", "cpu")
 import sys, json, numpy as np
 sys.path.insert(0, %r)
 sys.path.insert(0, %r)

@@ -286,3 +286,52 @@ class TestRequireNative:
         monkeypatch.setattr(T, "_TRIED", True)
         monkeypatch.delenv("DL4J_TPU_REQUIRE_NATIVE", raising=False)
         assert T._get_lib() is None  # caller uses the numpy path
+
+
+class TestNativeLoaderFreshness:
+    """The loader builds from what git would commit: native/build is
+    git-ignored and survives checkouts, so an old library beside newer
+    sources must never be loaded."""
+
+    def _fake_native(self, tmp_path, monkeypatch, so_age, src_age):
+        import os
+
+        from deeplearning4j_tpu.native_ops import threshold as T
+
+        (tmp_path / "build").mkdir()
+        so = tmp_path / "build" / "libdl4j_tpu_native.so"
+        so.write_bytes(b"")
+        src = tmp_path / "threshold_codec.cpp"
+        src.write_text("// source")
+        os.utime(so, (so_age, so_age))
+        os.utime(src, (src_age, src_age))
+        monkeypatch.setattr(T, "_NATIVE_DIR", str(tmp_path))
+        return T, str(so)
+
+    def test_library_older_than_a_source_is_stale(self, tmp_path,
+                                                  monkeypatch):
+        T, so = self._fake_native(tmp_path, monkeypatch, so_age=1000,
+                                  src_age=2000)
+        assert T._stale(so)
+
+    def test_library_newer_than_every_source_is_fresh(self, tmp_path,
+                                                      monkeypatch):
+        T, so = self._fake_native(tmp_path, monkeypatch, so_age=2000,
+                                  src_age=1000)
+        assert not T._stale(so)
+
+    def test_failed_rebuild_is_logged_and_not_loaded(self, tmp_path,
+                                                     monkeypatch, caplog):
+        import subprocess
+
+        T, _ = self._fake_native(tmp_path, monkeypatch, so_age=1000,
+                                 src_age=2000)
+
+        def boom(cmd, **kw):
+            raise subprocess.CalledProcessError(2, cmd,
+                                                stderr=b"no compiler")
+
+        monkeypatch.setattr(T.subprocess, "run", boom)
+        with caplog.at_level("WARNING", logger=T.__name__):
+            assert T._build_and_load() is None  # stale .so NOT loaded
+        assert "no compiler" in caplog.text

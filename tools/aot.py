@@ -22,15 +22,22 @@ Assertions (the acceptance criteria, not a vibe check):
     same seed — the exported artifact must reproduce the in-process jit
     token-for-token);
   * ZERO ``new_shape`` events on every leg — the symbolic/bucketed
-    executables absorb the full shape diversity;
-  * warm cold-start TTFT (process boot + first token) is within 2x the
-    cache-off leg — restoring must never be slower than recompiling.
+    executables absorb the full shape diversity.
+
+This is a CPU correctness gate, not a measurement: every leg runs with
+``JAX_PLATFORMS=cpu`` (the parent pins it and never touches JAX itself —
+a chip has one owner, and three restarting children cannot share it), and
+the record carries no time. What a warm boot saves on the chip is not
+measured here.
 
 Contract (same as lint/check/spec/prefix/...): ONE JSON summary line on
-stdout with ``"tool": "aot"``; exit 0 iff ``ok``. ``make aot-smoke``
-pins JAX_PLATFORMS=cpu; ``tools/gate.py``'s ``aot`` stage parses the
-line. ``--child`` runs a single leg in-process (the mode the parent —
-and bench.py's BENCH_COLD_RESTART model — spawns).
+stdout with ``"tool": "aot"``; exit 0 iff ``ok``. ``tools/gate.py``'s
+``aot`` stage parses the line. ``--child`` runs a single leg in-process
+(the mode the parent spawns). The exported artifacts live under a
+throwaway directory (``--cache-dir`` keeps them); JAX's own persistent
+cache follows the repo's one rule (``environment.enable_compile_cache``:
+``JAX_COMPILATION_CACHE_DIR`` if set, else ``.jax_cache/`` in the
+checkout).
 """
 
 from __future__ import annotations
@@ -41,7 +48,6 @@ import os
 import subprocess
 import sys
 import tempfile
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -55,20 +61,13 @@ def run_child_leg(requests: int, seed: int) -> dict:
     constructor does the warm boot / export."""
     from deeplearning4j_tpu.serving.replay import run_randomized_replay
 
-    t0 = time.perf_counter()
     out = run_randomized_replay(n_requests=requests, seed=seed)
     return {
         "outputs": out["outputs"],
-        "boot_s": out["boot_s"],
-        "ttft_first_ms": out["ttft_first_ms"],
-        "cold_start_ttft_ms": (
-            None if out["ttft_first_ms"] is None
-            else round(out["boot_s"] * 1e3 + out["ttft_first_ms"], 3)),
         "first_compile_keys": out["first_compile_keys"],
         "cache_hit_keys": out["cache_hit_keys"],
         "new_shape_events": out["new_shape_events"],
-        "all_terminal": all(out["all_terminal"] for _ in (0,)),
-        "wall_s": round(time.perf_counter() - t0, 3),
+        "all_terminal": bool(out["all_terminal"]),
     }
 
 
@@ -76,11 +75,10 @@ def spawn_leg(leg: str, cache_dir, requests: int, seed: int,
               timeout_s: float = 600.0) -> dict:
     """Run one leg in a FRESH python process — the restart the gate is
     about. Returns the child's JSON record."""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop(ENV_DIR, None)
     if cache_dir is not None:
         env[ENV_DIR] = cache_dir
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", leg,
          "--requests", str(requests), "--seed", str(seed)],
@@ -114,7 +112,6 @@ def main() -> int:
         print(json.dumps(rec), flush=True)
         return 0
 
-    t0 = time.perf_counter()
     tmp = None
     cache_dir = args.cache_dir
     if cache_dir is None:
@@ -134,19 +131,12 @@ def main() -> int:
     new_shape = (cold["new_shape_events"] + populate["new_shape_events"]
                  + warm["new_shape_events"])
     all_terminal = all(r["all_terminal"] for r in (cold, populate, warm))
-    ttft_cold = cold["cold_start_ttft_ms"]
-    ttft_warm = warm["cold_start_ttft_ms"]
-    ttft_ok = (ttft_cold is not None and ttft_warm is not None
-               and ttft_warm <= 2.0 * ttft_cold)
-    ratio = (round(ttft_cold / ttft_warm, 3)
-             if ttft_cold and ttft_warm else None)
 
     ok = (warm_first_compiles == []
           and len(warm["cache_hit_keys"]) > 0
           and identical
           and all_terminal
-          and new_shape == 0
-          and ttft_ok)
+          and new_shape == 0)
 
     rec = {
         "tool": "aot", "ok": ok,
@@ -155,23 +145,14 @@ def main() -> int:
         "outputs_identical": identical,
         "all_terminal": all_terminal,
         "new_shape_events": new_shape,
-        "cold_restart_ttft_ratio": ratio,
-        "ttft_cold_off_ms": ttft_cold,
-        "ttft_populate_ms": populate["cold_start_ttft_ms"],
-        "ttft_warm_ms": ttft_warm,
-        "boot_cold_s": cold["boot_s"],
-        "boot_populate_s": populate["boot_s"],
-        "boot_warm_s": warm["boot_s"],
         "cold_first_compile_keys": cold["first_compile_keys"],
         "requests_per_leg": args.requests,
-        "elapsed_s": round(time.perf_counter() - t0, 2),
     }
     print(json.dumps(rec), flush=True)
     if not args.json:
         print(f"aot: {'OK' if ok else 'FAIL'} — warm first_compiles="
               f"{warm_first_compiles}, cache_hits={warm['cache_hit_keys']}, "
-              f"identical={identical}, new_shape={new_shape}, "
-              f"ttft cold/warm={ttft_cold}/{ttft_warm}ms (x{ratio})",
+              f"identical={identical}, new_shape={new_shape}",
               file=sys.stderr)
     return 0 if ok else 1
 

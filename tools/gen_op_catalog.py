@@ -29,9 +29,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def generate() -> str:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import deeplearning4j_tpu  # noqa: F401 — triggers registrations
     from deeplearning4j_tpu.autodiff.samediff import GRAPH_OPS
     from deeplearning4j_tpu.ops.registry import registry

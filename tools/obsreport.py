@@ -13,10 +13,10 @@ Two modes:
 * ``--log PATH``: summarize an existing ``DL4J_TPU_OBS_LOG`` JSONL file
   instead of running anything (post-hoc analysis of a training/serving run).
 
-Backend safety: the default JAX backend is probed in a subprocess with a
-timeout (bench.py's PR-2 hardening) and the process pins itself to CPU when
-the probe fails, so an unreachable TPU degrades to a CPU smoke run instead
-of a hang.
+The smoke workload is a CPU correctness gate on the telemetry plumbing
+(``make obs-smoke`` pins ``JAX_PLATFORMS=cpu``): its ``--json`` line carries
+counts and whether percentiles were produced, never a time. It runs on
+whatever platform JAX has and names it; there is no fallback.
 """
 
 from __future__ import annotations
@@ -29,10 +29,6 @@ from collections import Counter as _Counter
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-# ONE backend probe for the whole repo: bench.py owns the subprocess-probe/
-# CPU-fallback logic (PR 2); reuse it instead of growing a drifting copy
-from bench import _ensure_backend  # noqa: E402
 
 
 def _demo_workload() -> None:
@@ -199,7 +195,9 @@ def main() -> int:
     if args.log:
         return _summarize_log(args.log, args.json)
 
-    backend = _ensure_backend()
+    import jax
+
+    backend = jax.devices()[0].platform
     _demo_workload()
     rep = _report(backend)
 
@@ -210,15 +208,13 @@ def main() -> int:
         rec = s.get("recompiles") or {}
         line = {"tool": "obsreport", "backend": backend,
                 "train_steps": tr.get("steps", 0),
-                "step_p99_ms": tr.get("step_p99_ms"),
                 "recompiles": rec.get("total", 0),
                 "recompile_causes": rec.get("by_cause", {}),
                 "serving_requests": sv.get("requests", 0),
-                "serving_p50_ms": sv.get("p50_ms"),
-                "serving_p99_ms": sv.get("p99_ms")}
+                "serving_percentiles": sv.get("p99_ms") is not None}
         ok = (line["train_steps"] > 0 and line["recompiles"] > 0
               and line["serving_requests"] > 0
-              and line["serving_p99_ms"] is not None)
+              and line["serving_percentiles"])
         line["ok"] = ok
         print(json.dumps(line, sort_keys=True))
         return 0 if ok else 1

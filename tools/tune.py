@@ -6,9 +6,11 @@
     python tools/tune.py --ops dot_product_attention,matmul_int8
 
 Runs ``ops.tuning.autotune`` (AOT-timed candidates, nothing enters the jit
-cache), writes the measured table to the tuning cache dir
-(``DL4J_TPU_TUNING_DIR``), then VERIFIES the measurement is live: reloads
-the table, resolves ``dot_product_attention`` on both sides of the tuned
+cache), writes the measured table to ``DL4J_TPU_TUNING_DIR`` (or, with
+that unset, to the git-ignored ``.tuning/`` of the checkout, which dispatch
+does not read — commit a copy under ``ops/tuning_tables/`` to make it
+live), then VERIFIES that the live threshold steers dispatch: reloads
+the tables, resolves ``dot_product_attention`` on both sides of the live
 ``flash_min_t`` under forced-pallas mode, and asserts via the
 ``dl4j_tpu_helper_dispatch_total`` counters that the small shape dispatched
 to the XLA generic and the large shape to the Pallas helper. One JSON line
