@@ -1482,16 +1482,17 @@ class CompiledGraph:
             t2 = time.perf_counter()
             self.stats.trace_seconds = round(t1 - t0, 4)
             self.stats.compile_seconds = round(t2 - t1, 4)
-            # the trace/compile split joins the unified span timeline and
-            # the compile-latency histograms (observe/)
+            # the trace half of the split joins the unified span timeline
+            # and the compile-latency histograms (observe/); the compile
+            # half is recorded where XLA builds the program
+            # (observe/ledger.py's listener: span xla_compile,
+            # dl4j_tpu_xla_compile_seconds)
             from deeplearning4j_tpu import observe
 
-            tr = observe.tracer()
-            tr.complete_between("jit_trace", t0, t1, category="compile")
-            tr.complete_between("xla_compile", t1, t2, category="compile")
-            m = observe.metrics()
-            m.histogram("dl4j_tpu_trace_seconds").observe(t1 - t0)
-            m.histogram("dl4j_tpu_xla_compile_seconds").observe(t2 - t1)
+            observe.tracer().complete_between("jit_trace", t0, t1,
+                                              category="compile")
+            observe.metrics().histogram(
+                "dl4j_tpu_trace_seconds").observe(t1 - t0)
             try:
                 return ex(var_arrays, feeds)
             except TypeError:

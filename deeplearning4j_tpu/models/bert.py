@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import observe
 from deeplearning4j_tpu.nn.updater import Adam, get_updater
 from deeplearning4j_tpu.ops.weight_init import init_weights
 
@@ -275,6 +276,9 @@ class BertModel:
 
     def fit_mlm(self, iterator, epochs: int = 1) -> List[float]:
         fn = self._jit.setdefault("mlm", self._mlm_step())
+        _m = observe.metrics()
+        steps_c = _m.counter("dl4j_tpu_train_steps_total", model="bert")
+        examples_c = _m.counter("dl4j_tpu_train_examples_total", model="bert")
         history = []
         for _ in range(epochs):
             losses = []
@@ -287,6 +291,8 @@ class BertModel:
                     jnp.asarray(batch["mlm_mask"]))
                 self.step += 1
                 losses.append(loss)
+                steps_c.inc()
+                examples_c.inc(int(np.shape(batch["ids"])[0]))
             history.append(float(jnp.mean(jnp.stack(losses))))
         return history
 
@@ -315,14 +321,24 @@ class BertModel:
                 return p, o, losses
 
             self._jit[key] = many
-        self._key, sub = jax.random.split(self._key)
-        self.params, self.opt_state, losses = many(
-            self.params, self.opt_state, jnp.asarray(self.step, jnp.int32), sub,
-            jnp.asarray(batch["ids"]), jnp.asarray(batch["segments"]),
-            jnp.asarray(batch["mask"]), jnp.asarray(batch["mlm_labels"]),
-            jnp.asarray(batch["mlm_mask"]))
-        self.step += steps
-        return np.asarray(losses)
+        observe.note_jit_signature(
+            many, graph="bert", key="mlm_scanned",
+            signature=observe.signature_of(ids=batch["ids"]))
+        with observe.scanned_call(
+                "bert", steps, steps * int(np.shape(batch["ids"])[0])) as call:
+            with call.dispatch():
+                self._key, sub = jax.random.split(self._key)
+                self.params, self.opt_state, losses = many(
+                    self.params, self.opt_state,
+                    jnp.asarray(self.step, jnp.int32), sub,
+                    jnp.asarray(batch["ids"]), jnp.asarray(batch["segments"]),
+                    jnp.asarray(batch["mask"]),
+                    jnp.asarray(batch["mlm_labels"]),
+                    jnp.asarray(batch["mlm_mask"]))
+            self.step += steps
+            with call.read():
+                losses = np.asarray(losses)  # host sync: the call is done here
+        return losses
 
     # -------------------------------------------------------------- inference
     def predict(self, ids, segments=None, mask=None) -> np.ndarray:

@@ -1038,11 +1038,10 @@ class ComputationGraph:
             features = {self.conf.network_inputs[0]: features}
         if not isinstance(labels, dict):
             labels = {self.conf.network_outputs[0]: labels}
-        feeds = {k: jnp.asarray(v) for k, v in features.items()}
-        labs = {k: jnp.asarray(v) for k, v in labels.items()}
         per_step_data = steps is None
-        n_steps = (int(next(iter(feeds.values())).shape[0]) if per_step_data
-                   else int(steps))
+        shape = np.shape(next(iter(features.values())))
+        n_steps = int(shape[0]) if per_step_data else int(steps)
+        self.last_batch_size = int(shape[1] if per_step_data else shape[0])
 
         cache_key = ("fit_scanned", per_step_data, n_steps)
         many = self._jit_cache.get(cache_key)
@@ -1064,20 +1063,24 @@ class ComputationGraph:
                 return p, o, s, losses
 
             self._jit_cache[cache_key] = many
-        self._key, sub = jax.random.split(self._key)
-        self.params, self.opt_state, self.net_state, losses = many(
-            self.params, self.opt_state, self.net_state,
-            jnp.asarray(self.iteration_count, jnp.int32), sub, feeds, labs)
-        start = self.iteration_count
-        self.iteration_count += n_steps
-        self._score = losses[-1]
-        losses = np.asarray(losses)
+        with observe.scanned_call(
+                "graph", n_steps, n_steps * self.last_batch_size) as call:
+            with call.dispatch():
+                feeds = {k: jnp.asarray(v) for k, v in features.items()}
+                labs = {k: jnp.asarray(v) for k, v in labels.items()}
+                self._key, sub = jax.random.split(self._key)
+                self.params, self.opt_state, self.net_state, losses = many(
+                    self.params, self.opt_state, self.net_state,
+                    jnp.asarray(self.iteration_count, jnp.int32), sub,
+                    feeds, labs)
+                self._score = losses[-1]
+            start = self.iteration_count
+            self.iteration_count += n_steps
+            with call.read():
+                losses = np.asarray(losses)
         # fire listeners after the fused chunk (per-step losses; params only
         # current as of chunk end) — the fast path no longer skips them.
         # Iteration-major order so multi-listener interleaving matches fit()
-        first_feed = next(iter(feeds.values()))
-        self.last_batch_size = int(first_feed.shape[1]) if per_step_data \
-            else int(first_feed.shape[0])
         for k in range(n_steps):
             for lst in self.listeners:
                 lst.iteration_done(self, start + k + 1, self.epoch_count,

@@ -589,9 +589,9 @@ class MultiLayerNetwork:
             step_fn = self._make_train_step()
             self._jit_cache["train_step"] = step_fn
         per_step_data = steps is None
-        xs = jnp.asarray(features)
-        ys = jnp.asarray(labels)
-        n_steps = int(xs.shape[0]) if per_step_data else int(steps)
+        shape = np.shape(features)
+        n_steps = int(shape[0]) if per_step_data else int(steps)
+        self.last_batch_size = int(shape[1] if per_step_data else shape[0])
 
         cache_key = ("fit_scanned", per_step_data, n_steps)
         many = self._jit_cache.get(cache_key)
@@ -613,34 +613,30 @@ class MultiLayerNetwork:
                 return p, o, s, losses
 
             self._jit_cache[cache_key] = many
-        observe.note_jit_signature(
-            many, graph="mln", key="fit_scanned",
-            signature=observe.signature_of(x=xs, y=ys))
-        self._key, sub = jax.random.split(self._key)
-        t0 = time.perf_counter()
-        self.params, self.opt_state, self.net_state, losses = many(
-            self.params, self.opt_state, self.net_state,
-            jnp.asarray(self.iteration_count, jnp.int32), sub, xs, ys)
-        start = self.iteration_count
-        self.iteration_count += n_steps
-        _m = observe.metrics()
-        _m.counter("dl4j_tpu_train_steps_total", model="mln").inc(n_steps)
-        _m.counter("dl4j_tpu_host_to_device_transfers_total",
-                   model="mln").inc(2)
-        self._score = losses[-1]
-        losses = np.asarray(losses)  # host sync: the chunk is done here
+        with observe.scanned_call(
+                "mln", n_steps, n_steps * self.last_batch_size) as call:
+            with call.dispatch():
+                xs = jnp.asarray(features)
+                ys = jnp.asarray(labels)
+                observe.note_jit_signature(
+                    many, graph="mln", key="fit_scanned",
+                    signature=observe.signature_of(x=xs, y=ys))
+                self._key, sub = jax.random.split(self._key)
+                self.params, self.opt_state, self.net_state, losses = many(
+                    self.params, self.opt_state, self.net_state,
+                    jnp.asarray(self.iteration_count, jnp.int32), sub, xs, ys)
+                self._score = losses[-1]
+            start = self.iteration_count
+            self.iteration_count += n_steps
+            observe.metrics().counter(
+                "dl4j_tpu_host_to_device_transfers_total", model="mln").inc(2)
+            with call.read():
+                losses = np.asarray(losses)  # host sync: the chunk is done here
         # listeners fire AFTER the fused chunk, once per inner step with the
         # recorded loss — coarser timing than fit() (params are only current
         # as of the chunk end) but checkpoint/score listeners keep working on
         # the fast path instead of silently not firing (round-2 weak #8).
         # Iteration-major order so multi-listener interleaving matches fit()
-        self.last_batch_size = int(xs.shape[1]) if per_step_data \
-            else int(xs.shape[0])
-        _m.counter("dl4j_tpu_train_examples_total", model="mln").inc(
-            n_steps * self.last_batch_size)
-        observe.tracer().complete_between(
-            "fit_scanned", t0, time.perf_counter(), category="train",
-            steps=n_steps)
         for k in range(n_steps):
             for lst in self.listeners:
                 lst.iteration_done(self, start + k + 1, self.epoch_count,

@@ -12,6 +12,9 @@ One metric model for the whole framework:
   ``SameDiff``/network jit-cache miss with its shape/dtype signature and
   cause.
 * :func:`log_event` — JSONL event log, enabled by ``DL4J_TPU_OBS_LOG=path``.
+* :class:`scanned_call` — the one record every scanned trainer keeps of a
+  call: span ``fit_scanned`` with its ``fit_scanned_dispatch`` /
+  ``fit_scanned_read`` children, and the step and example counters.
 * :func:`summary` — the compact snapshot ``bench.py`` embeds in its final
   JSON line and ``tools/obsreport.py`` prints.
 
@@ -21,6 +24,7 @@ import from any layer (including before backend selection).
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, Dict
 
 from deeplearning4j_tpu.observe.registry import (
@@ -35,6 +39,7 @@ from deeplearning4j_tpu.observe.registry import (
     reset_log_state,
 )
 from deeplearning4j_tpu.observe.tracing import (
+    Span,
     SpanTracer,
     default_tracer,
     reset_default_tracer,
@@ -43,6 +48,7 @@ from deeplearning4j_tpu.observe.ledger import (
     CompileEvent,
     RecompileLedger,
     default_ledger,
+    install_xla_listener,
     note_jit_signature,
     reset_default_ledger,
     signature_of,
@@ -60,6 +66,51 @@ def reset() -> None:
     reset_default_tracer()
     reset_default_ledger()
     reset_log_state()
+
+
+_SCANNED_CALLS = itertools.count(1)
+
+
+class scanned_call:
+    """What every scanned trainer (``fit_scanned``, ``fit_mlm_scanned``)
+    records of one call, so that the three keep one record and not three:
+
+        with observe.scanned_call("bert", steps, examples) as call:
+            with call.dispatch():   # key split, uploads, the jitted call
+                ...
+            with call.read():       # np.asarray(losses): the host waits
+                ...
+
+    The parent span is ``fit_scanned`` with ``model=``, ``steps=`` and a
+    running ``call=``; a call that returns counts its steps and examples
+    under ``dl4j_tpu_train_steps_total{model}`` /
+    ``dl4j_tpu_train_examples_total{model}``."""
+
+    def __init__(self, model: str, steps: int, examples: int):
+        self.model, self.steps, self.examples = model, steps, examples
+
+    def __enter__(self) -> "scanned_call":
+        self._span = tracer().span(
+            "fit_scanned", category="train", model=self.model,
+            steps=self.steps, call=next(_SCANNED_CALLS))
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._span.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            m = metrics()
+            m.counter("dl4j_tpu_train_steps_total",
+                      model=self.model).inc(self.steps)
+            m.counter("dl4j_tpu_train_examples_total",
+                      model=self.model).inc(self.examples)
+        return False
+
+    def dispatch(self) -> Span:
+        return tracer().span("fit_scanned_dispatch", category="train")
+
+    def read(self) -> Span:
+        return tracer().span("fit_scanned_read", category="train")
 
 
 def _ms(seconds) -> Any:
@@ -242,9 +293,10 @@ def summary() -> Dict[str, Any]:
 
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "SpanTracer",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "SpanTracer",
     "CompileEvent", "RecompileLedger", "OBS_LOG_ENV",
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
+    "install_xla_listener", "scanned_call",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

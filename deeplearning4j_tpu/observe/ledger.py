@@ -25,6 +25,14 @@ jit wrapper — as one :class:`CompileEvent` carrying:
 Events also increment ``dl4j_tpu_recompiles_total`` (plus a per-cause
 counter) in the default metrics registry and append a ``recompile`` JSONL
 event when ``DL4J_TPU_OBS_LOG`` is set.
+
+The ledger counts what call sites tell it. Beside it,
+:func:`install_xla_listener` counts every program XLA really builds, where
+it is built: a ``jax.monitoring`` listener (installed once, when
+``ops/registry.py`` is first imported) gives each one a
+``dl4j_tpu_xla_programs_total`` increment and an ``xla_compile`` span whose
+parent is the span open on the compiling thread — the request or the
+training call that paid for it.
 """
 
 from __future__ import annotations
@@ -38,6 +46,7 @@ from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
 from deeplearning4j_tpu.observe.registry import default_registry, log_event
+from deeplearning4j_tpu.observe.tracing import default_tracer
 
 CAUSES = ("first_compile", "new_shape", "graph_mutation",
           "constant_rebind", "variable_rebind", "cache_hit")
@@ -257,3 +266,50 @@ def note_jit_signature(fn: Any, *, graph: str, key: str, signature: str,
                             cause=cause, stats=stats if new_fn else None,
                             callsite=callsite)
     return cause
+
+
+# ---------------------------------------------------------------------------
+# every program XLA builds, counted where it is built
+# ---------------------------------------------------------------------------
+
+# jax 0.9 emits BACKEND_COMPILE once per program around
+# ``compile_or_get_cached`` — persistent cache warm or cold, so the count is
+# the same either way — and CACHE_RETRIEVAL inside it, just before, where the
+# executable came from the cache
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_xla_listening = False
+_xla_thread = threading.local()
+
+
+def _on_xla_event(event: str, duration: float, **_kw: Any) -> None:
+    if event == _CACHE_RETRIEVAL:
+        _xla_thread.retrieved = True
+        default_registry().histogram(
+            "dl4j_tpu_xla_cache_retrieval_seconds").observe(duration)
+    elif event == _BACKEND_COMPILE:
+        cached = getattr(_xla_thread, "retrieved", False)
+        _xla_thread.retrieved = False
+        m = default_registry()
+        m.counter("dl4j_tpu_xla_programs_total").inc()
+        if not cached:
+            m.histogram("dl4j_tpu_xla_compile_seconds").observe(duration)
+        tr = default_tracer()
+        now = time.perf_counter()
+        tr.complete_between("xla_compile", now - duration, now,
+                            category="compile", parent=tr.current(),
+                            cached=cached)
+
+
+def install_xla_listener() -> None:
+    """Register the listener with ``jax.monitoring`` (idempotent; the caller
+    has imported jax already). jax calls it on the compiling thread."""
+    global _xla_listening
+    with _DEFAULT_LOCK:
+        if _xla_listening:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_xla_event)
+        _xla_listening = True

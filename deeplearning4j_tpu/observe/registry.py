@@ -297,6 +297,9 @@ _DEFAULT_LOCK = threading.Lock()
 # and snapshots always carry the names (zero-valued until traffic arrives)
 _CORE_METRICS: Tuple[Tuple[str, str], ...] = (
     ("counter", "dl4j_tpu_recompiles_total"),
+    # every program XLA builds, from jax.monitoring (observe/ledger.py)
+    ("counter", "dl4j_tpu_xla_programs_total"),
+    ("histogram", "dl4j_tpu_xla_cache_retrieval_seconds"),
     ("counter", "dl4j_tpu_train_steps_total"),
     ("counter", "dl4j_tpu_train_examples_total"),
     ("counter", "dl4j_tpu_host_to_device_transfers_total"),

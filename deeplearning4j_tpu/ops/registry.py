@@ -27,8 +27,13 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 
 from deeplearning4j_tpu.environment import environment
+from deeplearning4j_tpu.observe import install_xla_listener
 
 logger = logging.getLogger(__name__)
+
+# every path into jax passes through this module's import: from here on
+# each program XLA builds is counted and spanned (observe/ledger.py)
+install_xla_listener()
 
 
 def current_platform() -> str:

@@ -408,7 +408,7 @@ class ClusterRouter:
                 items.append((req, st.future, st.submit_t))
             else:
                 self._obs["migration_failed"].inc()
-                eng._finish_unslotted(req, st.future, "error")
+                eng._finish_unslotted(req, st.future, "error", st.submit_t)
         with sched._plock:
             queued = list(sched.pending)
             sched.pending.clear()
@@ -421,7 +421,7 @@ class ClusterRouter:
             if sel is None:
                 n_failed += 1
                 self._obs["migration_failed"].inc()
-                eng._finish_unslotted(item[0], item[1], "error")
+                eng._finish_unslotted(item[0], item[1], "error", item[2])
                 continue
             dest = sel[0]
             groups.setdefault(dest.engine_id, []).append(item)

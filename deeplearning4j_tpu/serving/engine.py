@@ -85,7 +85,8 @@ from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
 from deeplearning4j_tpu.serving.speculative import SpeculativeDecoder
 from deeplearning4j_tpu.serving.sampling import sample_tokens
 from deeplearning4j_tpu.serving.scheduler import (
-    GenerationRequest, GenerationResult, SlotScheduler, count_terminal)
+    GenerationRequest, GenerationResult, SlotScheduler, count_terminal,
+    note_terminal)
 
 logger = logging.getLogger(__name__)
 
@@ -223,6 +224,7 @@ class GenerativeEngine:
         self.max_queue = None if max_queue is None else int(max_queue)
         self.default_deadline_s = default_deadline_s
         self.restarts = 0            # lifetime crash recoveries (<= cap)
+        self._step_count = 0         # running number of step(), for spans
         self.stopped_cleanly = True  # last stop() joined its worker in time
         # ------------------------------------------------- cluster membership
         # engine_id names this engine inside a ClusterRouter
@@ -241,6 +243,8 @@ class GenerativeEngine:
             "decode_h": m.histogram("dl4j_tpu_serving_decode_step_seconds"),
             "ttft_h": m.histogram("dl4j_tpu_serving_ttft_seconds"),
             "itl_h": m.histogram("dl4j_tpu_serving_intertoken_seconds"),
+            "queue_wait_h": m.histogram(
+                "dl4j_tpu_serving_queue_wait_seconds"),
             "restarts": m.counter("dl4j_tpu_serving_engine_restarts_total"),
             "retries": m.counter("dl4j_tpu_serving_retries_total"),
             # written ONLY by stop(): the gauge is process-global, and a
@@ -260,8 +264,10 @@ class GenerativeEngine:
     def _next_key(self):
         """Split a fresh subkey off the root key — the ONLY way keys leave
         the engine, so the audit trail sees every one exactly once."""
-        self._key, sub = jax.random.split(self._key)
-        self.key_trail.append(np.asarray(jax.random.key_data(sub)).tobytes())
+        with observe.tracer().span("serving_next_key", category="serving"):
+            self._key, sub = jax.random.split(self._key)
+            self.key_trail.append(
+                np.asarray(jax.random.key_data(sub)).tobytes())
         return sub
 
     # ---------------------------------------------------------- compiled fns
@@ -532,10 +538,26 @@ class GenerativeEngine:
                          "completed"), reason="stopped")
 
     def _serve_loop(self) -> None:
+        # the empty polls are coalesced into ONE serving_idle span, from the
+        # first of them to the first step with work: an idle engine must not
+        # write a thousand spans a second into a bounded buffer
+        idle_since: Optional[float] = None
+
+        def end_idle() -> None:
+            nonlocal idle_since
+            if idle_since is not None:
+                observe.tracer().complete_between(
+                    "serving_idle", idle_since, time.perf_counter(),
+                    category="serving")
+                idle_since = None
+
         while not self._stop_flag:
             if not self.scheduler.has_work():
+                if idle_since is None:
+                    idle_since = time.perf_counter()
                 time.sleep(1e-3)
                 continue
+            end_idle()
             try:
                 if faults.should_fire("engine_death"):
                     # a HARD whole-engine kill: spend the restart budget
@@ -563,6 +585,7 @@ class GenerativeEngine:
                 logger.exception("serving loop died (unrecoverable)")
                 self._die(e)
                 return
+        end_idle()
 
     # ------------------------------------------------------------ supervisor
     def _die(self, exc: Exception) -> None:
@@ -608,7 +631,8 @@ class GenerativeEngine:
         elif self._stop_flag:
             sched.fail_pending(RuntimeError("engine stopped"))
 
-    def _finish_unslotted(self, req, fut, reason: str) -> None:
+    def _finish_unslotted(self, req, fut, reason: str,
+                          submit_t: Optional[float] = None) -> None:
         """Complete a future that never held (or no longer holds) a slot
         with a terminal result: shed at admission, deadline in queue,
         error past the retry budget."""
@@ -619,6 +643,7 @@ class GenerativeEngine:
                 intertoken_s=[], slo_class=req.slo_class,
                 degraded=req.degraded, spec_disabled=req.spec_disabled))
         count_terminal(reason)
+        note_terminal(req, submit_t, reason)
         observe.log_event("serving_terminal", reason=reason,
                           slo_class=req.slo_class)
 
@@ -653,7 +678,7 @@ class GenerativeEngine:
                 with sched._plock:
                     sched.pending.appendleft((req, st.future, st.submit_t))
             else:
-                self._finish_unslotted(req, st.future, "error")
+                self._finish_unslotted(req, st.future, "error", st.submit_t)
         if self.prefix is not None:
             # reset_kv is about to zero the device pages, so every cached
             # prefix is garbage: drop the tree wholesale (pin intents
@@ -819,48 +844,67 @@ class GenerativeEngine:
     def step(self) -> int:
         """ONE scheduler iteration: capacity-evict, admit, retire finished,
         then one decode step for the whole slot bank. Returns the number of
-        tokens generated (0 when idle)."""
+        tokens generated (0 when idle). The whole of it is one
+        ``serving_step`` span whose children are the stages
+        (docs/OBSERVABILITY.md § Span catalogue)."""
+        sched = self.scheduler
+        # graftlock: justified(GL012): single-writer — only the (one) worker/inline step thread steps
+        self._step_count += 1
+        with observe.tracer().span(
+                "serving_step", category="serving", step=self._step_count,
+                pending=len(sched.pending), active=len(sched.slots)) as sp:
+            admitted, produced = self._step()
+            sp.set(admitted=admitted, produced=produced)
+        return produced
+
+    def _step(self) -> tuple:
+        """The stages of :meth:`step`; returns (admitted, produced)."""
         cache, sched = self.cache, self.scheduler
+        tracer = observe.tracer()
+        admitted = 0
 
-        # 1. retire sequences completed by the previous iteration FIRST:
-        #    a finished slot must neither grab capacity pages it will never
-        #    write nor be mis-retired as oom/overflow (which would skip the
-        #    eos trim and steal pages a live neighbour needed)
-        for slot in sched.active_slots():
-            reason = sched.should_finish(slot)
-            if reason:
-                self._retire(slot, reason)
+        with tracer.span("serving_schedule", category="serving") as stage:
+            held = len(sched.slots)
+            # 1. retire sequences completed by the previous iteration FIRST:
+            #    a finished slot must neither grab capacity pages it will never
+            #    write nor be mis-retired as oom/overflow (which would skip the
+            #    eos trim and steal pages a live neighbour needed)
+            for slot in sched.active_slots():
+                reason = sched.should_finish(slot)
+                if reason:
+                    self._retire(slot, reason)
 
-        # 1b. deadlines — AFTER completion so a finished sequence keeps its
-        #     honest eos/length reason; overdue work retires as "deadline"
-        #     (active: partial tokens; queued: empty result, no slot taken)
-        now = time.perf_counter()
-        for slot in sched.active_slots():
-            dl = sched.slots[slot].request.deadline_s
-            if dl is not None and now - sched.slots[slot].submit_t > dl:
-                self._retire(slot, "deadline")
-        expired = []
-        with sched._plock:
-            for _ in range(len(sched.pending)):
-                item = sched.pending.popleft()
-                if (item[0].deadline_s is not None
-                        and now - item[2] > item[0].deadline_s):
-                    expired.append(item)
-                else:
-                    sched.pending.append(item)
-        for req, fut, _t in expired:  # complete OUTSIDE the queue lock —
-            # future callbacks (frontend accounting) must not run under it
-            self._finish_unslotted(req, fut, "deadline")
+            # 1b. deadlines — AFTER completion so a finished sequence keeps its
+            #     honest eos/length reason; overdue work retires as "deadline"
+            #     (active: partial tokens; queued: empty result, no slot taken)
+            now = time.perf_counter()
+            for slot in sched.active_slots():
+                dl = sched.slots[slot].request.deadline_s
+                if dl is not None and now - sched.slots[slot].submit_t > dl:
+                    self._retire(slot, "deadline")
+            expired = []
+            with sched._plock:
+                for _ in range(len(sched.pending)):
+                    item = sched.pending.popleft()
+                    if (item[0].deadline_s is not None
+                            and now - item[2] > item[0].deadline_s):
+                        expired.append(item)
+                    else:
+                        sched.pending.append(item)
+            for req, fut, t_sub in expired:  # complete OUTSIDE the queue lock
+                # — future callbacks (frontend accounting) must not run under it
+                self._finish_unslotted(req, fut, "deadline", t_sub)
 
-        # 2. capacity: every surviving slot needs room for one more token
-        for slot in sched.active_slots():
-            need = int(cache.seq_lens[slot]) + 1
-            if need > self.cfg.max_position:
-                self._retire(slot, "overflow")
-                continue
-            status = cache.ensure_capacity(slot, need)
-            if status != "ok":
-                self._retire(slot, status)
+            # 2. capacity: every surviving slot needs room for one more token
+            for slot in sched.active_slots():
+                need = int(cache.seq_lens[slot]) + 1
+                if need > self.cfg.max_position:
+                    self._retire(slot, "overflow")
+                    continue
+                status = cache.ensure_capacity(slot, need)
+                if status != "ok":
+                    self._retire(slot, status)
+            stage.set(retired=held - len(sched.slots) + len(expired))
 
         # 3. admissions into free slots, highest-priority first (FIFO
         #    within a priority — peek_best_pending orders by (priority,
@@ -877,104 +921,120 @@ class GenerativeEngine:
             if item is None:
                 break
             req, fut, t_sub = item
-            p_len = int(req.prompt.size)
-            # p_len + 1 everywhere: the SAME iteration's decode writes the
-            # first generated token's K/V at position p_len, so a page-
-            # aligned prompt needs its next page NOW — allocating only the
-            # prompt's pages would send that write to the trash page.
-            # A prefix-cache match discounts its shared full pages from
-            # the bill (the CoW tail still costs a fresh page), and the
-            # tree's unpinned pages count as reclaimable supply.
-            match = self._match_prefix(req)
-            need_new = cache.pages_for(p_len + 1) - (
-                match.matched // cache.page_size if match else 0)
-            if need_new > cache.free_pages:
-                # only now pay the O(tree) reclaimable walk: tree pages
-                # eviction would ACTUALLY free (no slot holders, and not
-                # the match's own pages — those are being consumed, not
-                # freed) count as supply — overcounting here would turn
-                # this wait into a spurious terminal oom downstream
-                reclaimable = (self.prefix.reclaimable_pages(
-                    exclude=match.pages if match else ())
-                    if self.prefix is not None else 0)
-                if need_new > cache.free_pages + reclaimable:
-                    if not sched.slots:
-                        # nothing active to ever free pages —
-                        # config-impossible
-                        if sched.remove_pending(item) and not fut.done():
-                            fut.set_exception(RuntimeError(
-                                f"prompt needs {need_new} free pages but "
-                                f"the pool only has {cache.num_pages} "
-                                f"({reclaimable} reclaimable from the "
-                                f"prefix tree)"))
-                            count_terminal("error")
-                        continue
-                    break  # pool pressure: wait for evictions
-            if not sched.remove_pending(item):
-                continue  # a frontend steal raced us — re-select
-            slot = free[0]
-            try:
-                status, hit_tokens = self._admit_pages(slot, req, match)
-            except BaseException:
-                # same unwind as the prefill crash below: admission may
-                # have mapped shared pages / grown the slot before dying
-                # (eviction callback, allocator fault) — release whatever
-                # the slot holds and put the request back at the queue
-                # FRONT so supervision retries it instead of leaking the
-                # pages and stranding the future
-                cache.free_slot(slot)
-                with sched._plock:
-                    sched.pending.appendleft(item)
-                raise
-            if status != "ok":
-                # the free-pages precheck passed, so this is injected pool
-                # pressure (faults.page_oom) or an allocator race: complete
-                # the request terminally instead of prefilling into a
-                # trash-page-only row (which would corrupt the invariants)
-                self._finish_unslotted(req, fut, status)
-                continue
-            self._slot_match[slot] = match if hit_tokens else None
-            try:
-                first_tok = self._prefill_into(slot, req)
-            except BaseException:
-                # the request sits in neither pending nor a slot right
-                # now — put it back at the queue FRONT (original submit
-                # time) and release the just-grown pages, so supervision
-                # retries it instead of stranding its future forever
-                cache.free_slot(slot)
-                with sched._plock:
-                    sched.pending.appendleft(item)
-                raise
-            cache.seq_lens[slot] = p_len
-            now = time.perf_counter()
-            sched.admit(slot, req, fut, t_sub, first_tok, now,
-                        prefix_hit_tokens=hit_tokens)
-            self._obs["admitted"].inc()
-            self._obs["generated"].inc()
-            self._obs["ttft_h"].observe(now - t_sub)
-            if (self.spec is not None and req.temperature <= 0.0
-                    and not req.spec_disabled):
-                # greedy slots speculate: the draft prefills the SAME
-                # prompt (full — the draft cache has no prefix tree) so
-                # draft and target agree on a cached length of p_len.
-                # Sampling (temperature > 0) and spec_disabled requests
-                # stay on the plain decode path. A crash in here is
-                # supervised like any admission crash: the request
-                # already holds its slot, so _recover re-queues it.
-                self.spec.prefill(slot, req.prompt)
-                self._spec_slots.add(slot)
+            with tracer.span("serving_admit", category="serving",
+                             request=req.request_id, slot=free[0],
+                             admitted=False) as adm:
+                p_len = int(req.prompt.size)
+                # p_len + 1 everywhere: the SAME iteration's decode writes the
+                # first generated token's K/V at position p_len, so a page-
+                # aligned prompt needs its next page NOW — allocating only the
+                # prompt's pages would send that write to the trash page.
+                # A prefix-cache match discounts its shared full pages from
+                # the bill (the CoW tail still costs a fresh page), and the
+                # tree's unpinned pages count as reclaimable supply.
+                match = self._match_prefix(req)
+                need_new = cache.pages_for(p_len + 1) - (
+                    match.matched // cache.page_size if match else 0)
+                if need_new > cache.free_pages:
+                    # only now pay the O(tree) reclaimable walk: tree pages
+                    # eviction would ACTUALLY free (no slot holders, and not
+                    # the match's own pages — those are being consumed, not
+                    # freed) count as supply — overcounting here would turn
+                    # this wait into a spurious terminal oom downstream
+                    reclaimable = (self.prefix.reclaimable_pages(
+                        exclude=match.pages if match else ())
+                        if self.prefix is not None else 0)
+                    if need_new > cache.free_pages + reclaimable:
+                        if not sched.slots:
+                            # nothing active to ever free pages —
+                            # config-impossible
+                            if sched.remove_pending(item) and not fut.done():
+                                fut.set_exception(RuntimeError(
+                                    f"prompt needs {need_new} free pages but "
+                                    f"the pool only has {cache.num_pages} "
+                                    f"({reclaimable} reclaimable from the "
+                                    f"prefix tree)"))
+                                count_terminal("error")
+                                note_terminal(req, t_sub, "error")
+                            continue
+                        break  # pool pressure: wait for evictions
+                if not sched.remove_pending(item):
+                    continue  # a frontend steal raced us — re-select
+                slot = free[0]
+                try:
+                    status, hit_tokens = self._admit_pages(slot, req, match)
+                except BaseException:
+                    # same unwind as the prefill crash below: admission may
+                    # have mapped shared pages / grown the slot before dying
+                    # (eviction callback, allocator fault) — release whatever
+                    # the slot holds and put the request back at the queue
+                    # FRONT so supervision retries it instead of leaking the
+                    # pages and stranding the future
+                    cache.free_slot(slot)
+                    with sched._plock:
+                        sched.pending.appendleft(item)
+                    raise
+                if status != "ok":
+                    # the free-pages precheck passed, so this is injected pool
+                    # pressure (faults.page_oom) or an allocator race: complete
+                    # the request terminally instead of prefilling into a
+                    # trash-page-only row (which would corrupt the invariants)
+                    self._finish_unslotted(req, fut, status, t_sub)
+                    continue
+                self._slot_match[slot] = match if hit_tokens else None
+                try:
+                    first_tok = self._prefill_into(slot, req)
+                except BaseException:
+                    # the request sits in neither pending nor a slot right
+                    # now — put it back at the queue FRONT (original submit
+                    # time) and release the just-grown pages, so supervision
+                    # retries it instead of stranding its future forever
+                    cache.free_slot(slot)
+                    with sched._plock:
+                        sched.pending.appendleft(item)
+                    raise
+                cache.seq_lens[slot] = p_len
+                now = time.perf_counter()
+                sched.admit(slot, req, fut, t_sub, first_tok, now,
+                            prefix_hit_tokens=hit_tokens)
+                self._obs["admitted"].inc()
+                self._obs["generated"].inc()
+                self._obs["ttft_h"].observe(now - t_sub)
+                # the wait in the queue: submit -> the start of the admission
+                # that took the request (a retried request waits twice)
+                tracer.async_between(
+                    "serving_queue_wait", t_sub, adm.start,
+                    key=req.request_id, category="serving",
+                    request=req.request_id, priority=req.priority)
+                self._obs["queue_wait_h"].observe(adm.start - t_sub)
+                adm.set(admitted=True)
+                admitted += 1
+                if (self.spec is not None and req.temperature <= 0.0
+                        and not req.spec_disabled):
+                    # greedy slots speculate: the draft prefills the SAME
+                    # prompt (full — the draft cache has no prefix tree) so
+                    # draft and target agree on a cached length of p_len.
+                    # Sampling (temperature > 0) and spec_disabled requests
+                    # stay on the plain decode path. A crash in here is
+                    # supervised like any admission crash: the request
+                    # already holds its slot, so _recover re-queues it.
+                    self.spec.prefill(slot, req.prompt)
+                    self._spec_slots.add(slot)
 
-        # 4. a just-admitted sequence can already be done (first token was
-        #    its eos, or max_new_tokens == 1) — retire before decoding
-        for slot in sched.active_slots():
-            reason = sched.should_finish(slot)
-            if reason:
-                self._retire(slot, reason)
+        with tracer.span("serving_schedule", category="serving") as stage:
+            held = len(sched.slots)
+            # 4. a just-admitted sequence can already be done (first token was
+            #    its eos, or max_new_tokens == 1) — retire before decoding
+            for slot in sched.active_slots():
+                reason = sched.should_finish(slot)
+                if reason:
+                    self._retire(slot, reason)
+            stage.set(retired=held - len(sched.slots))
 
         self._obs["occupancy"].set(sched.occupancy())
         active = sched.active_slots()
         if not active:
-            return 0
+            return admitted, 0
 
         # 5. one decode iteration over the whole slot bank. With
         #    speculation on, the bank splits: slots that can take a
@@ -1014,54 +1074,61 @@ class GenerativeEngine:
             produced += self._step_decode(plain)
         if spec_now:
             produced += self._step_speculative(spec_now)
-        return produced
+        return admitted, produced
 
     def _step_decode(self, active: List[int]) -> int:
         """The plain one-token decode iteration over ``active`` (the
         whole bank when speculation is off)."""
         cache, sched = self.cache, self.scheduler
-        s_n = cache.max_slots
-        tokens = np.zeros((s_n,), np.int32)
-        act = np.zeros((s_n,), np.int32)
-        temp = np.zeros((s_n,), np.float32)
-        top_k = np.zeros((s_n,), np.int32)
-        top_p = np.ones((s_n,), np.float32)
-        for slot in active:
-            st = sched.slots[slot]
-            tokens[slot] = st.tokens[-1]
-            act[slot] = 1
-            temp[slot] = st.request.temperature
-            top_k[slot] = st.request.top_k
-            top_p[slot] = st.request.top_p
+        tracer = observe.tracer()
         if self._decode_fn is None:
             self._decode_fn = self._build_decode()
         key = self._next_key()
-        args = (jnp.asarray(cache.page_table), jnp.asarray(cache.seq_lens),
-                jnp.asarray(tokens), jnp.asarray(act))
-        observe.note_jit_signature(
-            self._decode_fn, graph="serving", key="decode",
-            signature=observe.signature_of(
-                page_table=cache.page_table, seq_lens=cache.seq_lens,
-                tokens=tokens, active=act))
+        with tracer.span("serving_decode_upload", category="serving"):
+            s_n = cache.max_slots
+            tokens = np.zeros((s_n,), np.int32)
+            act = np.zeros((s_n,), np.int32)
+            temp = np.zeros((s_n,), np.float32)
+            top_k = np.zeros((s_n,), np.int32)
+            top_p = np.ones((s_n,), np.float32)
+            for slot in active:
+                st = sched.slots[slot]
+                tokens[slot] = st.tokens[-1]
+                act[slot] = 1
+                temp[slot] = st.request.temperature
+                top_k[slot] = st.request.top_k
+                top_p[slot] = st.request.top_p
+            args = (jnp.asarray(cache.page_table),
+                    jnp.asarray(cache.seq_lens),
+                    jnp.asarray(tokens), jnp.asarray(act))
+            observe.note_jit_signature(
+                self._decode_fn, graph="serving", key="decode",
+                signature=observe.signature_of(
+                    page_table=cache.page_table, seq_lens=cache.seq_lens,
+                    tokens=tokens, active=act))
         t0 = time.perf_counter()
-        with observe.tracer().span("serving_decode", category="serving",
-                                   slots=len(active)):
-            cache.kv, next_toks, _logits = self._decode_fn(
-                self.model.params, cache.kv, *args, key,
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p))
-            next_toks = np.asarray(next_toks)
+        with tracer.span("serving_decode", category="serving",
+                         slots=len(active)):
+            with tracer.span("serving_decode_launch", category="serving"):
+                cache.kv, next_toks, _logits = self._decode_fn(
+                    self.model.params, cache.kv, *args, key,
+                    jnp.asarray(temp), jnp.asarray(top_k),
+                    jnp.asarray(top_p))
+            with tracer.span("serving_decode_read", category="serving"):
+                next_toks = np.asarray(next_toks)
         dt = time.perf_counter() - t0
         self._obs["decode_h"].observe(dt)
-        now = time.perf_counter()
-        for slot in active:
-            cache.seq_lens[slot] += 1  # the fed token is cached now
-            st = sched.slots[slot]
-            if st.last_token_t is not None:
-                self._obs["itl_h"].observe(now - st.last_token_t)
-            sched.on_decode_token(slot, int(next_toks[slot]), now)
-        self._obs["generated"].inc(len(active))
-        observe.log_event("serving_decode", slots=len(active),
-                          step_seconds=round(dt, 6))
+        with tracer.span("serving_commit", category="serving"):
+            now = time.perf_counter()
+            for slot in active:
+                cache.seq_lens[slot] += 1  # the fed token is cached now
+                st = sched.slots[slot]
+                if st.last_token_t is not None:
+                    self._obs["itl_h"].observe(now - st.last_token_t)
+                sched.on_decode_token(slot, int(next_toks[slot]), now)
+            self._obs["generated"].inc(len(active))
+            observe.log_event("serving_decode", slots=len(active),
+                              step_seconds=round(dt, 6))
         return len(active)
 
     def _step_speculative(self, spec_now: List[int]) -> int:
@@ -1180,18 +1247,21 @@ class GenerativeEngine:
         observe.note_jit_signature(
             self._write_fn, graph="serving", key="write_prompt",
             signature=observe.signature_of(ids=ids))
-        with observe.tracer().span("serving_prefill", category="serving",
-                                   prompt_len=p_len):
-            kv_prompt, tok = self._prefill_fn(
-                self.model.params, jnp.asarray(ids),
-                jnp.asarray(p_len, jnp.int32), key,
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32),
-                jnp.asarray([req.top_p], jnp.float32))
-            cache.kv = self._write_fn(
-                cache.kv, kv_prompt, jnp.asarray(cache.page_table[slot]),
-                jnp.asarray(p_len, jnp.int32))
-            tok = int(tok)
+        tracer = observe.tracer()
+        with tracer.span("serving_prefill", category="serving",
+                         prompt_len=p_len, request=req.request_id):
+            with tracer.span("serving_prefill_launch", category="serving"):
+                kv_prompt, tok = self._prefill_fn(
+                    self.model.params, jnp.asarray(ids),
+                    jnp.asarray(p_len, jnp.int32), key,
+                    jnp.asarray([req.temperature], jnp.float32),
+                    jnp.asarray([req.top_k], jnp.int32),
+                    jnp.asarray([req.top_p], jnp.float32))
+                cache.kv = self._write_fn(
+                    cache.kv, kv_prompt, jnp.asarray(cache.page_table[slot]),
+                    jnp.asarray(p_len, jnp.int32))
+            with tracer.span("serving_prefill_read", category="serving"):
+                tok = int(tok)
         return tok
 
     def _prefill_suffix_into(self, slot: int, req: GenerationRequest,
@@ -1209,16 +1279,19 @@ class GenerativeEngine:
         observe.note_jit_signature(
             self._suffix_fn, graph="serving", key="suffix_prefill",
             signature=observe.signature_of(ids=ids))
-        with observe.tracer().span("serving_prefill", category="serving",
-                                   prompt_len=p_len,
-                                   prefix_hit=match.matched):
-            cache.kv, tok = self._suffix_fn(
-                self.model.params, cache.kv, jnp.asarray(ids),
-                jnp.asarray(match.matched, jnp.int32),
-                jnp.asarray(suffix.size, jnp.int32),
-                jnp.asarray(cache.page_table[slot]), key,
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32),
-                jnp.asarray([req.top_p], jnp.float32))
-            tok = int(tok)
+        tracer = observe.tracer()
+        with tracer.span("serving_prefill", category="serving",
+                         prompt_len=p_len, prefix_hit=match.matched,
+                         request=req.request_id):
+            with tracer.span("serving_prefill_launch", category="serving"):
+                cache.kv, tok = self._suffix_fn(
+                    self.model.params, cache.kv, jnp.asarray(ids),
+                    jnp.asarray(match.matched, jnp.int32),
+                    jnp.asarray(suffix.size, jnp.int32),
+                    jnp.asarray(cache.page_table[slot]), key,
+                    jnp.asarray([req.temperature], jnp.float32),
+                    jnp.asarray([req.top_k], jnp.int32),
+                    jnp.asarray([req.top_p], jnp.float32))
+            with tracer.span("serving_prefill_read", category="serving"):
+                tok = int(tok)
         return tok

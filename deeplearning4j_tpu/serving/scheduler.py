@@ -30,6 +30,7 @@ completes over-capacity submissions immediately (docs/ROBUSTNESS.md).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -51,6 +52,32 @@ from deeplearning4j_tpu import observe
 # terminal outcome is counted (asserted in tests/test_frontend.py).
 FINISH_REASONS = ("eos", "length", "overflow", "oom", "stopped",
                   "shed", "deadline", "error")
+
+
+# one running number for every request of the process: spans of one request
+# (serving_queue_wait, serving_admit, serving_prefill, serving_request) carry
+# it as ``request``, across supervisor retries and cluster migration
+_REQUEST_IDS = itertools.count(1)
+
+
+def request_id_of(request: "GenerationRequest") -> int:
+    if request.request_id is None:
+        request.request_id = next(_REQUEST_IDS)
+    return request.request_id
+
+
+def note_terminal(request: "GenerationRequest", submit_t: Optional[float],
+                  reason: str, tokens: int = 0) -> None:
+    """The ``serving_request`` span: one per request at its terminal state,
+    submit -> terminal (zero long where the submit time is not known: shed
+    at the gate). A request's span, not a thread's: an async pair."""
+    now = time.perf_counter()
+    rid = request_id_of(request)
+    observe.tracer().async_between(
+        "serving_request", now if submit_t is None else submit_t, now,
+        key=rid, category="serving", request=rid, reason=reason,
+        prompt_len=int(request.prompt.size), tokens=int(tokens),
+        retries_used=request.retries_used)
 
 
 def count_terminal(reason: str) -> None:
@@ -93,6 +120,9 @@ class GenerationRequest:
     # the engine then decodes it non-speculatively even when spec is on.
     # Rides into the GenerationResult like ``degraded``.
     spec_disabled: bool = False
+    # assigned by SlotScheduler.submit, never by the caller; the SAME
+    # request object is re-queued by a supervisor retry, so it keeps its id
+    request_id: Optional[int] = None
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)
@@ -172,6 +202,7 @@ class SlotScheduler:
     # ------------------------------------------------------------ submission
     def submit(self, request: GenerationRequest) -> "Future[GenerationResult]":
         fut: "Future[GenerationResult]" = Future()
+        request_id_of(request)
         with self._plock:
             self.pending.append((request, fut, time.perf_counter()))
         return fut
@@ -313,6 +344,7 @@ class SlotScheduler:
             spec_proposed_tokens=st.spec_proposed_tokens,
             spec_accepted_tokens=st.spec_accepted_tokens,
             spec_disabled=st.request.spec_disabled)
+        note_terminal(st.request, st.submit_t, reason, len(toks))
         if not st.future.done():
             # graftlife: justified(GR003): retire() only forms the result —
             # its callers (engine._retire, frontend._shed_victim) own the
@@ -331,6 +363,7 @@ class SlotScheduler:
             if st is not None and not st.future.done():
                 st.future.set_exception(exc)
                 count_terminal(reason)
+                note_terminal(st.request, st.submit_t, reason, len(st.tokens))
         self.fail_pending(exc, reason=reason)
 
     def fail_pending(self, exc: Exception, reason: str = "error") -> None:
@@ -344,7 +377,8 @@ class SlotScheduler:
                     drained.append(self.pending.popleft())
                 except IndexError:  # drained (possibly by a concurrent one)
                     break
-        for _req, fut, _t in drained:
+        for req, fut, t_sub in drained:
             if not fut.done():
                 fut.set_exception(exc)
                 count_terminal(reason)
+                note_terminal(req, t_sub, reason)

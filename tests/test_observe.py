@@ -139,7 +139,9 @@ class TestSpanTracer:
         assert names == ["inner", "mark", "outer"]  # inner completes first
         ev = {e["name"]: e for e in tr.events}
         assert ev["outer"]["ph"] == "X" and ev["outer"]["dur"] >= 0
-        assert ev["outer"]["args"] == {"k": 1}
+        assert ev["outer"]["args"]["k"] == 1
+        # every span carries its id and its parent's (tests/test_stage_spans.py)
+        assert ev["inner"]["args"]["parent"] == ev["outer"]["args"]["id"]
         p = str(tmp_path / "trace.json")
         tr.write(p)
         data = json.load(open(p))
