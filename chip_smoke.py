@@ -33,6 +33,7 @@ from __future__ import annotations
 import argparse
 import gc
 import json
+import re
 import statistics
 import sys
 import time
@@ -283,6 +284,7 @@ def phase_gpt(*, cfg=None, slots: int = 16, page_size: int = 16,
     gaps = [g for res in results for g in res.intertoken_s]
     report = {"phase": "serve/gpt2", "params": model.num_params(),
             "slots": slots, "pages": eng.cache.num_pages,
+            "kv_pool": list(eng.cache.kv.shape),
             "requests": 1 + len(results),
             "finish_reasons": sorted({r.finish_reason
                                       for r in [first] + results}),
@@ -390,6 +392,13 @@ MUST_TAKE_TPU_HELPER = {
 }
 
 
+def pool_copies(compiled_text: str, pool_shape) -> int:
+    """``copy`` instructions of a compiled program whose result has the KV
+    pool's shape: a change of the pool's layout, 2.45 GB at a time."""
+    dims = ",".join(str(d) for d in pool_shape)
+    return len(re.findall(rf"\[{dims}\]\S* copy\(", compiled_text))
+
+
 def check_chip_evidence(report: dict) -> None:
     phase = report["phase"]
     for op in MUST_TAKE_TPU_HELPER[phase]:
@@ -401,6 +410,10 @@ def check_chip_evidence(report: dict) -> None:
           f"{phase}: a usable() gate raised ({report['dispatch']})")
     check(report["mosaic_calls"] > 0,
           f"{phase}: no tpu_custom_call in the compiled text")
+    check(not report.get("pool_copies"),
+          f"{phase}: the compiled decode step copies the KV pool "
+          f"{report.get('kv_pool')} {report.get('pool_copies')} times: it "
+          f"has to update the pool in place (docs/SERVING.md)")
     check(report["platforms"] == ["tpu"],
           f"{phase}: arrays live on {report['platforms']}")
 
@@ -449,8 +462,11 @@ def main(argv=None) -> int:
             for phase in (phase_bert, phase_resnet, phase_gpt):
                 t0 = time.perf_counter()
                 report, compiled_text = phase(seed=args.seed)
-                report["mosaic_calls"] = compiled_text().count(
-                    "tpu_custom_call")
+                text = compiled_text()
+                report["mosaic_calls"] = text.count("tpu_custom_call")
+                if "kv_pool" in report:
+                    report["pool_copies"] = pool_copies(text,
+                                                        report["kv_pool"])
                 say(**report, phase_s=round(time.perf_counter() - t0, 1),
                     **_peak_bytes(dev0))
                 check_chip_evidence(report)

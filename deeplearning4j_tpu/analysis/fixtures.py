@@ -137,13 +137,11 @@ def fused_graph_sym_batch(seq: int = 32, d: int = 64, heads: int = 4,
     catt.rename("causal_att")
     # decode tier: one query token per slot against a block-paged KV cache
     dq = sd.placeholder("dq", shape=(None, heads, hd))
-    kp = sd.var("k_pages", (r.randn(6, page, heads, hd) * 0.1)
-                .astype(np.float32))
-    vp = sd.var("v_pages", (r.randn(6, page, heads, hd) * 0.1)
+    kv = sd.var("kv_pages", (r.randn(2, 2, 6, page, d) * 0.1)
                 .astype(np.float32))
     pt = sd.placeholder("page_table", shape=(None, 3), dtype=np.int32)
     sl = sd.placeholder("seq_lens", shape=(None,), dtype=np.int32)
-    sd.op("paged_decode_attention", dq, kp, vp, pt, sl).rename("decoded")
+    sd.op("paged_decode_attention", dq, kv, pt, sl, layer=1).rename("decoded")
     sd.graph_inputs = ["q", "k", "v", "mask", "x", "dq", "page_table",
                        "seq_lens"]
     sd.graph_outputs = ["att", "causal_att", "h", "decoded"]

@@ -824,22 +824,34 @@ def _dot_product_attention(node, ins, emit):
 
 @op_rule("paged_decode_attention")
 def _paged_decode_attention(node, ins, emit):
-    q, kp, vp, pt, sl = ins[0], ins[1], ins[2], ins[3], ins[4]
+    q, kv, pt, sl = ins[0], ins[1], ins[2], ins[3]
     dt = q.dtype  # impl casts the f32 accumulator back to q's dtype
-    want_ranks = (("q", q, 3), ("k_pages", kp, 4), ("v_pages", vp, 4),
+    want_ranks = (("q", q, 3), ("kv_pages", kv, 5),
                   ("page_table", pt, 2), ("seq_lens", sl, 1))
     for name, a, want in want_ranks:
         if a.rank is not None and a.rank != want:
             emit("GC001", f"'paged_decode_attention': {name} must be rank "
                           f"{want}, got {fmt_shape(a.shape)}")
             return [AVal(None, dt)]
-    if q.shape is not None and kp.shape is not None:
-        for axis_q, axis_p, what in ((1, 2, "heads"), (2, 3, "head dim")):
-            if dims_provably_unequal(q.shape[axis_q], kp.shape[axis_p]):
-                emit("GC002", f"'paged_decode_attention': {what} differ — "
-                              f"q {fmt_shape(q.shape)} vs k_pages "
-                              f"{fmt_shape(kp.shape)}")
-                return [AVal(None, dt)]
+    if q.shape is not None and kv.shape is not None:
+        # the pool's rows are heads * head_dim wide (heads merged)
+        h, d = q.shape[1], q.shape[2]
+        if isinstance(h, int) and isinstance(d, int) and \
+                dims_provably_unequal(h * d, kv.shape[4]):
+            emit("GC002", f"'paged_decode_attention': row widths differ — "
+                          f"q {fmt_shape(q.shape)} needs heads * head dim = "
+                          f"{h * d}, kv_pages {fmt_shape(kv.shape)}")
+            return [AVal(None, dt)]
+        if dims_provably_unequal(kv.shape[1], 2):
+            emit("GC002", f"'paged_decode_attention': kv_pages axis 1 holds "
+                          f"K and V and must be 2, got "
+                          f"{fmt_shape(kv.shape)}")
+            return [AVal(None, dt)]
+        layer = node.kwargs.get("layer", 0)
+        if isinstance(kv.shape[0], int) and not 0 <= layer < kv.shape[0]:
+            emit("GC002", f"'paged_decode_attention': layer {layer} outside "
+                          f"the pool's {kv.shape[0]} layers")
+            return [AVal(None, dt)]
     if q.shape is not None and pt.shape is not None and \
             dims_provably_unequal(q.shape[0], pt.shape[0]):
         emit("GC002", f"'paged_decode_attention': slot counts differ — "

@@ -148,8 +148,9 @@ def gpt_prefill(params, ids, cfg: GptConfig, *, mask=None
     """Causal full-prompt forward.
 
     ids: (N, T) int32; mask: optional (N, T) 1=real token (end padding).
-    Returns ``(logits (N, T, V), kv (L, 2, N, T, H, Dh))`` — the per-layer
-    keys/values the serving engine scatters into its paged cache.
+    Returns ``(logits (N, T, V), kv (L, 2, N, T, H*Dh))`` — the per-layer
+    keys/values as the paged pool holds them (token-major, heads merged:
+    the projections' own rows), for the serving engine to write as pages.
     """
     from deeplearning4j_tpu.ops import exec_op
 
@@ -173,11 +174,10 @@ def gpt_prefill(params, ids, cfg: GptConfig, *, mask=None
     for blk in params["blocks"]:
         a = blk["attn"]
         q = split(x @ a["Wq"] + a["bq"])
-        k = split(x @ a["Wk"] + a["bk"])
-        v = split(x @ a["Wv"] + a["bv"])
-        # (2, N, T, H, Dh) — token-major, the paged-cache scatter layout
-        kvs.append(jnp.stack([k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3)]))
+        k_row = x @ a["Wk"] + a["bk"]
+        v_row = x @ a["Wv"] + a["bv"]
+        kvs.append(jnp.stack([k_row, v_row]))  # (2, N, T, H*Dh)
+        k, v = split(k_row), split(v_row)
         out = exec_op("dot_product_attention", q, k, v, m4, scaled=True,
                       causal=True)
         out = out.transpose(0, 2, 1, 3).reshape(n, t, cfg.hidden)
@@ -194,15 +194,15 @@ def gpt_prefill_suffix(params, ids, prefix_kv, prefix_len, suffix_len,
     cache's fast path, docs/SERVING.md § Radix prefix cache).
 
     ids: (1, B) int32 — the prompt's UNCACHED tail, zero-padded to the
-    engine's suffix bucket; prefix_kv: (L, 2, Tpre, H, Dh) — the cached
-    prefix K/V gathered from the paged cache (positions >= ``prefix_len``
+    engine's suffix bucket; prefix_kv: (L, 2, Tpre, H*Dh) — the cached
+    prefix K/V gathered from the paged pool (positions >= ``prefix_len``
     are garbage and masked); prefix_len/suffix_len: scalars. Suffix token
     i sits at absolute position ``prefix_len + i`` and attends to every
     valid prefix position plus suffix positions <= i — the same causal
     math as :func:`gpt_prefill`, computed for B tokens instead of the
-    whole prompt. Returns ``(logits (1, B, V), kv (L, 2, B, H, Dh))`` —
-    the suffix K/V for the cache scatter (token-major, like the prefill
-    layout the engine already writes).
+    whole prompt. Returns ``(logits (1, B, V), kv (L, 2, B, H*Dh))`` —
+    the suffix K/V for the cache scatter (the pool's rows, like the
+    prefill's).
     """
     from deeplearning4j_tpu.ops import exec_op
 
@@ -225,16 +225,20 @@ def gpt_prefill_suffix(params, ids, prefix_kv, prefix_len, suffix_len,
     js = jnp.arange(b)[None, :]
     m_suf = (js <= qi) & (js < suffix_len)
     m4 = jnp.concatenate([m_pre, m_suf], axis=1)[None, None]
+
+    def heads_first(a):  # (Tpre, H*Dh) -> (1, H, Tpre, Dh)
+        return a.reshape(t_pre, h, dh).transpose(1, 0, 2)[None]
+
     kvs = []
     for li, blk in enumerate(params["blocks"]):
         a = blk["attn"]
         q = split(x @ a["Wq"] + a["bq"])
-        k = split(x @ a["Wk"] + a["bk"])
-        v = split(x @ a["Wv"] + a["bv"])
-        kvs.append(jnp.stack([k.transpose(0, 2, 1, 3)[0],
-                              v.transpose(0, 2, 1, 3)[0]]))  # (2, B, H, Dh)
-        kp = prefix_kv[li, 0].transpose(1, 0, 2)[None]  # (1, H, Tpre, Dh)
-        vp = prefix_kv[li, 1].transpose(1, 0, 2)[None]
+        k_row = x @ a["Wk"] + a["bk"]
+        v_row = x @ a["Wv"] + a["bv"]
+        kvs.append(jnp.stack([k_row[0], v_row[0]]))  # (2, B, H*Dh)
+        k, v = split(k_row), split(v_row)
+        kp = heads_first(prefix_kv[li, 0])
+        vp = heads_first(prefix_kv[li, 1])
         out = exec_op("dot_product_attention", q,
                       jnp.concatenate([kp, k], axis=2),
                       jnp.concatenate([vp, v], axis=2), m4, scaled=True)
@@ -253,7 +257,7 @@ def gpt_verify(params, kv_pages, tokens, seq_lens, page_table, write_pages,
     tokens per slot in ONE causal forward against the paged KV cache
     (docs/SERVING.md § Speculative decoding).
 
-    kv_pages: (L, 2, P, page, H, Dh) — functionally updated (donate it);
+    kv_pages: (L, 2, P, page, H*Dh) — functionally updated (donate it);
     tokens: (S, B) int32 — per slot, the last committed token followed by
     the draft's K proposals; seq_lens: (S,) tokens already CACHED for the
     slot (the fed run occupies absolute positions ``seq_lens + i``);
@@ -273,6 +277,7 @@ def gpt_verify(params, kv_pages, tokens, seq_lens, page_table, write_pages,
     second write pass.
     """
     from deeplearning4j_tpu.ops import exec_op
+    from deeplearning4j_tpu.ops.pallas_attention import gather_pages
 
     emb = params["embeddings"]
     s_n, b = tokens.shape
@@ -298,20 +303,21 @@ def gpt_verify(params, kv_pages, tokens, seq_lens, page_table, write_pages,
     m4 = jnp.concatenate(
         [m_ctx, jnp.broadcast_to(m_fed[None], (s_n, b, b))],
         axis=2)[:, None]
-    gpage = page_table[:, tpos // page_size]          # (S, Tv)
-    goff = tpos % page_size
+
+    def cached(rows):  # the slots' page runs (S, P, page, E) -> (S,H,Tv,Dh)
+        return rows.reshape(s_n, t_v, h, dh).transpose(0, 2, 1, 3)
+
     for li, blk in enumerate(params["blocks"]):
         a = blk["attn"]
         q = split(x @ a["Wq"] + a["bq"])
-        k = split(x @ a["Wk"] + a["bk"])
-        v = split(x @ a["Wv"] + a["bv"])
-        # scatter fed K/V token-major; trash-page duplicates are benign
-        kv_pages = kv_pages.at[li, 0, write_pages, write_offsets].set(
-            k.transpose(0, 2, 1, 3))
-        kv_pages = kv_pages.at[li, 1, write_pages, write_offsets].set(
-            v.transpose(0, 2, 1, 3))
-        kc = kv_pages[li, 0][gpage, goff].transpose(0, 2, 1, 3)  # (S,H,Tv,Dh)
-        vc = kv_pages[li, 1][gpage, goff].transpose(0, 2, 1, 3)
+        k_row = x @ a["Wk"] + a["bk"]
+        v_row = x @ a["Wv"] + a["bv"]
+        # scatter the fed rows; trash-page duplicates are benign
+        kv_pages = kv_pages.at[li, 0, write_pages, write_offsets].set(k_row)
+        kv_pages = kv_pages.at[li, 1, write_pages, write_offsets].set(v_row)
+        k, v = split(k_row), split(v_row)
+        kc = cached(gather_pages(kv_pages, li, 0, page_table))
+        vc = cached(gather_pages(kv_pages, li, 1, page_table))
         out = exec_op("dot_product_attention", q,
                       jnp.concatenate([kc, k], axis=2),
                       jnp.concatenate([vc, v], axis=2), m4, scaled=True)
@@ -328,7 +334,10 @@ def gpt_decode_step(params, kv_pages, tokens, positions, page_table,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One decode token for every slot, against the paged KV cache.
 
-    kv_pages: (L, 2, P, page, H, Dh) — functionally updated (donate it);
+    kv_pages: (L, 2, P, page, H*Dh) — the whole pool, updated in place
+    (donate it): this token's K/V rows are scattered into it and the
+    attention reads it where it lies, the layer picked inside the op, so no
+    layer of it is ever sliced out;
     tokens/positions: (S,) int32 — the token being fed and its position;
     page_table: (S, max_pages) int32; seq_lens_incl: (S,) valid length
     INCLUDING this token; write_page/write_offset: (S,) where this token's
@@ -346,13 +355,12 @@ def gpt_decode_step(params, kv_pages, tokens, positions, page_table,
     for li, blk in enumerate(params["blocks"]):
         a = blk["attn"]
         q = (x @ a["Wq"] + a["bq"]).reshape(s_n, h, dh)
-        k = (x @ a["Wk"] + a["bk"]).reshape(s_n, h, dh)
-        v = (x @ a["Wv"] + a["bv"]).reshape(s_n, h, dh)
-        kv_pages = kv_pages.at[li, 0, write_page, write_offset].set(k)
-        kv_pages = kv_pages.at[li, 1, write_page, write_offset].set(v)
-        attn = exec_op("paged_decode_attention", q, kv_pages[li, 0],
-                       kv_pages[li, 1], page_table, seq_lens_incl,
-                       scale=1.0 / math.sqrt(dh))
+        kv_pages = kv_pages.at[li, 0, write_page, write_offset].set(
+            x @ a["Wk"] + a["bk"])
+        kv_pages = kv_pages.at[li, 1, write_page, write_offset].set(
+            x @ a["Wv"] + a["bv"])
+        attn = exec_op("paged_decode_attention", q, kv_pages, page_table,
+                       seq_lens_incl, layer=li, scale=1.0 / math.sqrt(dh))
         attn = attn.reshape(s_n, cfg.hidden)
         x = _layer_norm(x + attn @ a["Wo"] + a["bo"],
                         a["ln_gamma"], a["ln_beta"], cfg.layer_norm_eps)

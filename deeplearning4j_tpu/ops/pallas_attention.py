@@ -632,39 +632,60 @@ def flash_mha(q, k, v, *, num_heads: int, causal: bool = False,
 # Paged decode attention — the serving-side kernel (docs/SERVING.md)
 # ---------------------------------------------------------------------------
 #
-# Generation serves ONE query token per sequence against a block-paged KV
-# cache (vLLM/PagedAttention layout): K/V live in fixed-size pages
-# (num_pages, page_size, heads, head_dim) and each sequence owns a page-table
-# row of page indices. The decode step therefore needs a gather-attention:
-# softmax(q · K[pages]) · V[pages] with positions >= seq_len masked out.
+# Generation serves ONE query token per sequence against the block-paged KV
+# pool of serving/cache.py (the vLLM/PagedAttention memory model). There is
+# ONE layout: ``kv_pages`` is ``(layers, 2, num_pages + 1, page_size,
+# heads * head_dim)`` with heads and head size MERGED in the minor dimension,
+# so a page is a ``(page_size, heads * head_dim)`` matrix that fills the
+# TPU's (8, 128) float32 / (16, 128) bfloat16 tiles with no padding, the
+# device keeps the pool row-major, and every program updates it in place.
+# The op takes the WHOLE pool and a static ``layer``: slicing a layer out
+# first would copy it.
 #
-# Two implementations, selected through the registry platform table exactly
-# like flash attention above:
-#   * `paged_decode_attention_xla` — generic: gather the page table with
-#     fancy indexing and run masked attention; runs anywhere (the CPU-host
-#     fallback) at the cost of materializing the gathered (S, T_max, H, D)
-#     keys in HBM.
-#   * `_paged_decode_call` — Pallas: grid (slot, page) with the page walk
-#     innermost; the page table rides scalar-prefetch (PrefetchScalarGridSpec)
-#     so each grid step DMAs exactly ONE (page_size, H, D) K/V tile straight
-#     from its paged HBM home — the gathered contiguous copy never exists.
-#     Online-softmax running state lives in VMEM scratch across the page
-#     walk of one slot (the FlashAttention-2 recurrence, page-granular).
+# Two implementations of one contract, selected through the registry
+# platform table exactly like flash attention above:
+#   * `paged_decode_attention_xla` — generic: gather the page table's pages
+#     out of the pool and run masked attention; runs anywhere, and is what
+#     narrow models (heads * head_dim not a multiple of 128) take on a TPU
+#     too, at the cost of materializing the gathered (S, T_max, H, D) keys.
+#   * `_paged_decode_call` — Pallas: grid (slot, group of pages) with the page
+#     walk innermost; the page table rides scalar-prefetch
+#     (PrefetchScalarGridSpec) and the layer is a constant of the index_map,
+#     so each grid step DMAs its pages' K and V straight from where they lie
+#     in the pool — neither a gathered copy nor a layer's slice ever exists —
+#     and the walk stops at the sequence's length. Online-softmax running
+#     state lives in VMEM scratch across the page walk of one slot (the
+#     FlashAttention-2 recurrence, a group of pages at a time).
 
 
-def paged_decode_attention_xla(q, k_pages, v_pages, page_table, seq_lens, *,
+def gather_pages(kv_pages, layer: int, side: int, pages):
+    """``kv_pages[layer, side, pages]``: the pages ``pages`` (any shape of
+    int32) of one layer's K (``side`` 0) or V (1), ``pages.shape + (page,
+    H*D)``, as a gather with ONE index a page over the pool seen as a run of
+    pages (a bitcast of the row-major pool, no copy). Written
+    ``kv_pages[layer, side, pages]`` the TPU compiler packs the three index
+    components into bit fields of one word, and the program that unpacks them
+    halted the v5e at the serving cell's pool size (PERF.md, PR 26)."""
+    n_l, _, n_p, page, width = kv_pages.shape
+    run = kv_pages.reshape(n_l * 2 * n_p, page, width)
+    return run[(layer * 2 + side) * n_p + pages]
+
+
+def paged_decode_attention_xla(q, kv_pages, page_table, seq_lens, *,
+                               layer: int = 0,
                                scale: Optional[float] = None):
-    """Generic gather path: q:[S,H,D], k/v_pages:[P,page,H,D],
-    page_table:[S,max_pages] int32, seq_lens:[S] int32 -> [S,H,D].
+    """Generic gather path: q:[S,H,D], kv_pages:[L,2,P,page,H*D] (the whole
+    pool), page_table:[S,max_pages] int32, seq_lens:[S] int32 -> [S,H,D];
+    ``layer`` picks the pool's layer.
 
     Scores accumulate in f32 regardless of cache dtype (matches the Pallas
     kernel's preferred_element_type accumulators)."""
     s_n, h, d = q.shape
-    page = k_pages.shape[1]
+    page = kv_pages.shape[3]
     max_pages = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    k = k_pages[page_table].reshape(s_n, max_pages * page, h, d)
-    v = v_pages[page_table].reshape(s_n, max_pages * page, h, d)
+    k, v = (gather_pages(kv_pages, layer, side, page_table).reshape(
+        s_n, max_pages * page, h, d) for side in (0, 1))
     s = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     pos = jnp.arange(max_pages * page)
@@ -674,14 +695,37 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, seq_lens, *,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, mx_ref, l_ref, *, page: int, scale: float,
-                         heads: int):
-    """One (slot, page) grid step. The per-head q·K dots run as unrolled 2D
-    matmuls (heads is static and small at decode) — Mosaic lowers plain 2D
-    dots reliably where a batched dot_general would not; M=1 rows waste MXU
-    lanes but decode is memory-bound on the K/V stream, not FLOP-bound."""
+# Pages one grid step of the paged kernel attends to. A grid step has a cost
+# whatever it does, and one page of 16 positions gives the MXU a sixteenth of
+# a tile to do; 8 pages of 16 make the scores one full 128-lane tile. On the
+# v5e at GPT-2 small's widths (32 slots x 65 pages of 16) the whole decode
+# program took 11.8, 9.4, 8.3, 7.8 and 8.0 ms at 1, 2, 4, 8 and 16 pages a
+# step (PERF.md, PR 26).
+_PAGES_PER_STEP = 8
+
+
+def _paged_decode_kernel(pt_ref, sl_ref, q_ref, *refs, page: int,
+                         scale: float, head_dim: int, group: int):
+    """One (slot, group of pages) grid step over merged-head pages.
+
+    Row ``h`` of the block-diagonal query holds head ``h``'s query in its
+    own ``head_dim`` columns and zeros elsewhere, so ONE (rows, H*D) x
+    (H*D, positions) matmul gives every head's scores and ONE (rows,
+    positions) x (positions, H*D) matmul every head's values; the diagonal
+    blocks of the accumulator are the output. The zeros cost operations the
+    MXU has to spare (decode is bound by the K/V stream) and save the
+    per-head lane slices of an unaligned ``head_dim``.
+
+    A group wholly past ``seq_len`` is skipped (its probabilities would all
+    be exactly 0), and the index maps re-point its pages at the sequence's
+    last page, which the pipeline then does not fetch again: the walk over
+    the page table costs DMA and arithmetic only as far as the sequence
+    goes. Within the last group, positions past ``seq_len`` are masked."""
+    kv_refs, (o_ref, acc_ref, mx_ref, l_ref) = refs[:group], refs[group:]
     s_idx, j = pl.program_id(0), pl.program_id(1)
+    rows, width = acc_ref.shape      # heads rounded up to 8, heads * head_dim
+    span = group * page
+    seq_len = sl_ref[s_idx]
 
     @pl.when(j == 0)
     def _init():
@@ -689,115 +733,138 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         mx_ref[:] = jnp.full_like(mx_ref, -1e30)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]        # (H, D)
-    kblk = k_ref[0]     # (page, H, D)
-    vblk = v_ref[0]
-    seq_len = sl_ref[s_idx]
+    first = head_dim * jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    own = (col >= first) & (col < first + head_dim)
 
-    rows = [_mm_nt(q[h:h + 1, :], kblk[:, h, :]) for h in range(heads)]
-    s = jnp.concatenate(rows, axis=0) * scale   # f32 (H, page)
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (heads, page), 1)
-    s = jnp.where(pos < seq_len, s, -1e30)
+    @pl.when(j * span < seq_len)
+    def _attend():
+        # selected in f32: a bf16 select would need the int32 mask in bf16's
+        # (16, 128) tiling, a relayout Mosaic refuses
+        q = jnp.where(own, q_ref[:].astype(jnp.float32), 0.0).astype(
+            q_ref.dtype)             # (rows, H*D)
+        kblk = jnp.concatenate([r[0] for r in kv_refs], axis=0)
+        vblk = jnp.concatenate([r[1] for r in kv_refs], axis=0)
 
-    m_prev = mx_ref[:, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-    outs = [_mm_nn(p[h:h + 1, :], vblk[:, h, :]) for h in range(heads)]
-    acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
-    mx_ref[:, :1] = m_new
+        s = _mm_nt(q, kblk) * scale  # f32 (rows, span)
+        pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        s = jnp.where(pos < seq_len, s, -1e30)
+
+        m_prev = mx_ref[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + _mm_nn(p, vblk)
+        mx_ref[:, :1] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        out = jnp.where(own, acc_ref[:] / l, 0.0)
+        o_ref[:] = out.sum(axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-def _paged_decode_call(q, k_pages, v_pages, page_table, seq_lens, *,
+def _paged_decode_call(q, kv_pages, page_table, seq_lens, *, layer: int = 0,
                        scale: Optional[float] = None,
                        interpret: Optional[bool] = None):
-    """Pallas paged decode. Same contract as paged_decode_attention_xla."""
+    """Pallas paged decode. Same contract as paged_decode_attention_xla,
+    except that a slot of length 0 (inactive: nobody reads it) gives zeros
+    where the gather path gives the mean of its masked values."""
     s_n, h, d = q.shape
-    page = k_pages.shape[1]
+    page, width = kv_pages.shape[3], kv_pages.shape[4]
     max_pages = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     interpret = pallas_interpret(interpret)
+    rows = -(-h // 8) * 8
+    group = min(_PAGES_PER_STEP, max_pages)
     kernel = functools.partial(_paged_decode_kernel, page=page, scale=scale,
-                               heads=h)
+                               head_dim=d, group=group)
+    layer = int(layer)
+
+    def page_block(i):
+        """K and V of the group's ``i``-th page of one layer, where they
+        lie in the pool; past the sequence's end, its last page again."""
+        def index(s, j, pt, sl):
+            last = jnp.maximum(sl[s] - 1, 0) // page
+            return (layer, 0, pt[s, jnp.minimum(j * group + i, last)], 0, 0)
+
+        return pl.BlockSpec((None, 2, None, page, width), index)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s_n, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda s, j, pt, sl: (s, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda s, j, pt, sl: (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda s, j, pt, sl: (pt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda s, j, pt, sl: (s, 0, 0)),
+        grid=(s_n, -(-max_pages // group)),
+        in_specs=[pl.BlockSpec((None, 1, width),
+                               lambda s, j, pt, sl: (s, 0, 0))]
+        + [page_block(i) for i in range(group)],
+        out_specs=pl.BlockSpec((None, 1, width),
+                               lambda s, j, pt, sl: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((rows, width), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_n, 1, width), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.reshape(s_n, 1, width), *([kv_pages] * group))
+    return out.reshape(s_n, h, d)
 
 
-def _paged_usable(q, k_pages, v_pages, page_table, seq_lens, **kw):
-    """PlatformHelper::isUsable for the Pallas paged path: shapes must be
-    the documented ranks, the page/head-dim tiles Mosaic-aligned, and the
-    page walk long enough to beat the XLA gather (measured min_pages from
-    the tuning table; default 1 = always, matching pre-tuning behavior)."""
-    if getattr(q, "ndim", 0) != 3 or getattr(k_pages, "ndim", 0) != 4:
+def _paged_usable(q, kv_pages, page_table, seq_lens, **kw):
+    """PlatformHelper::isUsable for the Pallas paged path: the documented
+    ranks, a page that fills whole tiles as it crosses the DMA (merged width
+    a multiple of 128 lanes, page size of 8 sublanes), and a page walk long
+    enough to beat the XLA gather (measured min_pages from the tuning table;
+    default 1 = always, matching pre-tuning behavior). Narrow models (the
+    tiny test configurations) take the generic path on every platform."""
+    if getattr(q, "ndim", 0) != 3 or getattr(kv_pages, "ndim", 0) != 5:
         return False
     if getattr(page_table, "ndim", 0) != 2 or getattr(seq_lens, "ndim", 0) != 1:
+        return False
+    if kv_pages.shape[1] != 2 or \
+            q.shape[1] * q.shape[2] != kv_pages.shape[4]:
         return False
     from deeplearning4j_tpu.ops import tuning
 
     if page_table.shape[1] < int(tuning.tuned("paged_decode_attention",
                                               "min_pages", 1)):
         return False
-    return q.shape[-1] % 8 == 0 and k_pages.shape[1] % 8 == 0
+    return kv_pages.shape[4] % 128 == 0 and kv_pages.shape[3] % 8 == 0
 
 
 def _check_paged_decode_attention():
     """Validation case (ops.validation ratchet): XLA gather path vs a
-    straight numpy oracle, and the Pallas interpret kernel vs both."""
+    straight numpy oracle, and the Pallas interpret kernel vs both, on the
+    second layer of a two-layer pool."""
     import numpy as np
 
     r = np.random.RandomState(7)
-    s_n, h, d, page, n_pages, max_pages = 3, 4, 16, 8, 10, 3
+    s_n, h, d, page, n_pages, max_pages, layer = 3, 4, 32, 8, 10, 3, 1
     q = r.randn(s_n, h, d).astype(np.float32)
-    kp = r.randn(n_pages, page, h, d).astype(np.float32)
-    vp = r.randn(n_pages, page, h, d).astype(np.float32)
+    kv = r.randn(2, 2, n_pages, page, h * d).astype(np.float32)
     pt = np.stack([r.choice(n_pages, max_pages, replace=False)
                    for _ in range(s_n)]).astype(np.int32)
     sl = np.array([5, 17, 24], np.int32)
     scale = 1.0 / math.sqrt(d)
     want = np.zeros_like(q)
     for i in range(s_n):
-        gk = kp[pt[i]].reshape(-1, h, d)[:sl[i]]
-        gv = vp[pt[i]].reshape(-1, h, d)[:sl[i]]
+        gk = kv[layer, 0, pt[i]].reshape(-1, h, d)[:sl[i]]
+        gv = kv[layer, 1, pt[i]].reshape(-1, h, d)[:sl[i]]
         for hh in range(h):
             sc = gk[:, hh] @ q[i, hh] * scale
             p = np.exp(sc - sc.max())
             p = p / p.sum()
             want[i, hh] = p @ gv[:, hh]
-    got = paged_decode_attention_xla(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(pt), jnp.asarray(sl))
+    args = (jnp.asarray(q), jnp.asarray(kv), jnp.asarray(pt), jnp.asarray(sl))
+    got = paged_decode_attention_xla(*args, layer=layer)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
-    got_pl = _paged_decode_call(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(pt), jnp.asarray(sl))
+    assert _paged_usable(*args)
+    got_pl = _paged_decode_call(*args, layer=layer)
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
 
 
@@ -814,9 +881,9 @@ def register_platform_attention() -> None:
     if "paged_decode_attention" not in reg:
         reg.register(
             "paged_decode_attention", paged_decode_attention_xla,
-            doc="decode-step attention over a block-paged KV cache "
-                "(q:[S,H,D], k/v_pages:[P,page,H,D], page_table:[S,max_pages],"
-                " seq_lens:[S] -> [S,H,D])")
+            doc="decode-step attention over the block-paged KV pool "
+                "(q:[S,H,D], kv_pages:[L,2,P,page,H*D], page_table:"
+                "[S,max_pages], seq_lens:[S], layer= -> [S,H,D])")
         reg.register_platform("paged_decode_attention", "tpu",
                               _paged_decode_call, _paged_usable)
         _validation.add_case("paged_decode_attention",

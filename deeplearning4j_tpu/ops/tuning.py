@@ -604,7 +604,8 @@ def _tune_paged_decode(table: TuningTable, smoke: bool) -> int:
         _paged_decode_call, paged_decode_attention_xla)
 
     ladders = (2, 4) if smoke else (4, 16, 64)
-    s_n, h, d, page = (2, 2, 8, 8) if smoke else (8, 8, 64, 16)
+    # heads * head_dim a multiple of 128: a width the kernel takes
+    s_n, h, d, page = (2, 2, 64, 8) if smoke else (8, 8, 64, 16)
     r = np.random.RandomState(5)
     n = 0
     pallas_ms: Dict[int, float] = {}
@@ -613,13 +614,13 @@ def _tune_paged_decode(table: TuningTable, smoke: bool) -> int:
         for max_pages in ladders:
             n_pages = max_pages * s_n + 1
             q = jnp.asarray(r.randn(s_n, h, d).astype(np.float32))
-            kp = jnp.asarray(
-                r.randn(n_pages, page, h, d).astype(np.float32))
+            kv = jnp.asarray(
+                r.randn(1, 2, n_pages, page, h * d).astype(np.float32))
             pt = jnp.asarray(
                 r.randint(0, n_pages, (s_n, max_pages)).astype(np.int32))
             sl = jnp.asarray(
                 np.full((s_n,), max_pages * page, np.int32))
-            args = (q, kp, kp, pt, sl)
+            args = (q, kv, pt, sl)
             xla_ms[max_pages] = aot_time(paged_decode_attention_xla, args)
             pallas_ms[max_pages] = aot_time(_paged_decode_call, args)
             n += 2

@@ -104,8 +104,8 @@ def _fn_table(engine) -> List[Dict[str, Any]]:
     KD = SDS(tuple(kd.shape), kd.dtype)
     PARAMS = spec_of(engine.model.params)
     KV = SDS(tuple(cache.kv.shape), cache.kv.dtype)
-    kv_prompt = SDS((cfg.layers, 2, engine.max_prompt, cfg.heads,
-                     cfg.hidden // cfg.heads), cache.kv.dtype)
+    kv_prompt = SDS((cfg.layers, 2, engine.max_prompt, cache.kv.shape[-1]),
+                    cache.kv.dtype)
     table = [
         dict(key="prefill", owner=engine, attr="_prefill_fn",
              build=engine._build_prefill, key_idx=3, donate=(),

@@ -6,10 +6,21 @@ and the reserved-but-unused tail is the memory that would have held more
 concurrent sequences. The vLLM answer, reproduced here:
 
 * KV storage is ONE device array of fixed-size **pages**
-  ``(layers, 2, num_pages + 1, page_size, heads, head_dim)`` allocated once
+  ``(layers, 2, num_pages + 1, page_size, heads * head_dim)`` allocated once
   at server start — decode steps never reallocate device memory and their
   jit signature never changes (the compile-once property
-  ``tests/test_serving.py`` asserts through the RecompileLedger).
+  ``tests/test_serving.py`` asserts through the RecompileLedger). This is
+  the ONE layout: a token's K (or V) of one layer is a row of ``heads *
+  head_dim`` numbers, the projection's own output, heads merged. At GPT-2's
+  widths a page ``(16, 768)`` fills the TPU's ``(8, 128)`` float32 and
+  ``(16, 128)`` bfloat16 tiles exactly, so the device keeps the pool
+  row-major, scatters update it in place and the paged kernel reads pages
+  where they lie; with heads and head size as two minor dimensions
+  ``(12, 64)`` the device padded every page 2.67-fold and copied the whole
+  pool into another layout and back in every program (PERF.md, PR 26).
+  Every serving program takes and returns this array; none slices a layer
+  out of it (``tests/test_tpu_compile.py`` holds the compiled ``decode`` and
+  ``write_prompt`` to that).
 * Each sequence owns an ordered list of pages recorded in a **page table**
   row ``(max_slots, max_pages_per_seq)``; logical token position ``t`` lives
   at ``(page_table[slot, t // page_size], t % page_size)``.
@@ -71,7 +82,7 @@ class PagedKVCache:
         self.trash_page = self.num_pages
         # +1: the trash page — see module docstring
         self._kv_shape = (layers, 2, self.num_pages + 1, self.page_size,
-                          heads, head_dim)
+                          heads * head_dim)
         self._kv_dtype = dtype
         self.kv = jnp.zeros(self._kv_shape, self._kv_dtype)
         self.free: List[int] = list(range(self.num_pages))

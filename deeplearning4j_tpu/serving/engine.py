@@ -10,9 +10,10 @@ admits/evicts (asserted in tests/test_serving.py):
 * **prefill** — the whole (padded) prompt through one causal
   ``gpt_prefill`` pass + first-token sampling; returns the per-layer K/V
   for the cache scatter. TTFT is measured across this call.
-* **write-prompt** — scatter the prefill K/V into the slot's pages
-  (donated cache array; unused prompt-pad positions land on the trash
-  page).
+* **write-prompt** — write the prefill K/V into the slot's pages, whole
+  pages at a time and in place (donated pool; pages past the prompt land
+  on the trash page, the tail of the prompt's last page is masked garbage
+  until decode overwrites it).
 * **decode** — one token for EVERY slot (inactive slots ride along masked:
   they write to the trash page and their outputs are ignored), paged
   attention via the registry's ``paged_decode_attention``, then the
@@ -79,7 +80,9 @@ import numpy as np
 
 from deeplearning4j_tpu import faults, observe
 from deeplearning4j_tpu.models.gpt import (
-    GptModel, gpt_decode_step, gpt_prefill, gpt_prefill_suffix, gpt_verify)
+    GptConfig, GptModel, gpt_decode_step, gpt_prefill, gpt_prefill_suffix,
+    gpt_verify)
+from deeplearning4j_tpu.ops.pallas_attention import gather_pages
 from deeplearning4j_tpu.serving.cache import PagedKVCache
 from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
 from deeplearning4j_tpu.serving.speculative import SpeculativeDecoder
@@ -89,6 +92,53 @@ from deeplearning4j_tpu.serving.scheduler import (
     note_terminal)
 
 logger = logging.getLogger(__name__)
+
+
+def build_write(page: int, trash: int):
+    """The jitted ``write_prompt``: a prefill's K/V rows ``(L, 2, T, H*Dh)``
+    go into the donated pool as WHOLE pages, in place. The prompt's last
+    page carries the padded positions' rows past ``prompt_len`` — garbage
+    that attention masks and decode overwrites; pages past the prompt go to
+    the trash page. Only pages the slot owns alone are written: a full
+    prefill runs only without a prefix match, so its pages are all fresh.
+    A function of the page geometry alone, so it can be lowered for a
+    described device without an engine (tests/test_tpu_compile.py)."""
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def write_prompt(kv_pages, kv_prompt, pt_row, prompt_len):
+        n_l, _, t, width = kv_prompt.shape
+        n = -(-t // page)
+        pages = jnp.pad(kv_prompt, ((0, 0), (0, 0), (0, n * page - t),
+                                    (0, 0))).reshape(n_l, 2, n, page, width)
+        page_idx = jnp.where(jnp.arange(n) * page < prompt_len,
+                             pt_row[:n], trash)
+        return kv_pages.at[:, :, page_idx].set(pages)
+
+    return write_prompt
+
+
+def build_decode(cfg: GptConfig, page: int, trash: int):
+    """The jitted ``decode``: one token for every slot against the donated
+    pool (:func:`gpt_decode_step`) and the sampler. Like
+    :func:`build_write`, a function of configuration and page geometry
+    alone."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode(params, kv_pages, page_table, seq_lens, tokens, active,
+               key, temp, top_k, top_p):
+        s_n = tokens.shape[0]
+        on = active > 0
+        write_page = jnp.where(
+            on, page_table[jnp.arange(s_n), seq_lens // page], trash)
+        write_off = seq_lens % page
+        seq_incl = seq_lens + on.astype(jnp.int32)
+        kv_pages, logits = gpt_decode_step(
+            params, kv_pages, tokens, seq_lens, page_table, seq_incl,
+            write_page, write_off, cfg)
+        toks = sample_tokens(logits, key, temp, top_k, top_p)
+        return kv_pages, toks, logits
+
+    return decode
 
 
 class GenerativeEngine:
@@ -281,23 +331,12 @@ class GenerativeEngine:
                                      mask=mask.astype(jnp.int32))
             last = logits[0, prompt_len - 1][None]  # (1, V)
             tok = sample_tokens(last, key, temp, top_k, top_p)[0]
-            return kv[:, :, 0], tok  # (L, 2, T, H, Dh), scalar
+            return kv[:, :, 0], tok  # (L, 2, T, H*Dh), scalar
 
         return prefill
 
     def _build_write(self):
-        cache = self.cache
-        page, trash = cache.page_size, cache.trash_page
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def write_prompt(kv_pages, kv_prompt, pt_row, prompt_len):
-            pos = jnp.arange(kv_prompt.shape[2])
-            valid = pos < prompt_len
-            page_idx = jnp.where(valid, pt_row[pos // page], trash)
-            off = pos % page
-            return kv_pages.at[:, :, page_idx, off].set(kv_prompt)
-
-        return write_prompt
+        return build_write(self.cache.page_size, self.cache.trash_page)
 
     def _build_suffix(self):
         """Suffix-only prefill for prefix-cache hits: gather the cached
@@ -310,12 +349,20 @@ class GenerativeEngine:
         cfg, cache = self.cfg, self.cache
         page, trash = cache.page_size, cache.trash_page
         t_pre = self.max_prompt
+        n_pre = cache.pages_for(t_pre)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def suffix_prefill(params, kv_pages, ids, prefix_len, suffix_len,
                            pt_row, key, temp, top_k, top_p):
-            pos = jnp.arange(t_pre)
-            prefix_kv = kv_pages[:, :, pt_row[pos // page], pos % page]
+            # the prompt bucket's whole pages, gathered a layer and side at
+            # a time (as the pool is written below, and for the same
+            # reason): (L, 2, n, page, E) -> (L, 2, Tpre, E)
+            run = jnp.stack([
+                jnp.stack([gather_pages(kv_pages, li, side, pt_row[:n_pre])
+                           for side in (0, 1)])
+                for li in range(cfg.layers)])
+            prefix_kv = run.reshape(run.shape[:2] + (n_pre * page, -1))
+            prefix_kv = prefix_kv[:, :, :t_pre]
             logits, kv_suf = gpt_prefill_suffix(
                 params, ids, prefix_kv, prefix_len, suffix_len, cfg)
             last = logits[0, suffix_len - 1][None]  # (1, V)
@@ -325,31 +372,20 @@ class GenerativeEngine:
             valid = jnp.arange(b) < suffix_len
             row_idx = jnp.clip(apos // page, 0, pt_row.shape[0] - 1)
             wpage = jnp.where(valid, pt_row[row_idx], trash)
-            kv_pages = kv_pages.at[:, :, wpage, apos % page].set(kv_suf)
+            # one scatter a layer and side, the form the decode step's
+            # writes take: a scatter over the leading axes too makes the
+            # TPU compiler copy the whole pool into another layout and back
+            for li in range(cfg.layers):
+                for side in (0, 1):
+                    kv_pages = kv_pages.at[li, side, wpage, apos % page].set(
+                        kv_suf[li, side])
             return kv_pages, tok
 
         return suffix_prefill
 
     def _build_decode(self):
-        cfg, cache = self.cfg, self.cache
-        page, trash = cache.page_size, cache.trash_page
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def decode(params, kv_pages, page_table, seq_lens, tokens, active,
-                   key, temp, top_k, top_p):
-            s_n = tokens.shape[0]
-            on = active > 0
-            write_page = jnp.where(
-                on, page_table[jnp.arange(s_n), seq_lens // page], trash)
-            write_off = seq_lens % page
-            seq_incl = seq_lens + on.astype(jnp.int32)
-            kv_pages, logits = gpt_decode_step(
-                params, kv_pages, tokens, seq_lens, page_table, seq_incl,
-                write_page, write_off, cfg)
-            toks = sample_tokens(logits, key, temp, top_k, top_p)
-            return kv_pages, toks, logits
-
-        return decode
+        return build_decode(self.cfg, self.cache.page_size,
+                            self.cache.trash_page)
 
     def _build_verify(self):
         """Speculative verification (docs/SERVING.md § Speculative

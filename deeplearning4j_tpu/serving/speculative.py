@@ -23,9 +23,10 @@ test scale).
 This module owns the DRAFT half:
 
 * a **dense per-slot draft KV cache** ``(L, 2, max_slots, max_ctx + 1,
-  H, Dh)`` — the draft is small, so the paged indirection would cost more
-  than it saves; the final position is the trash position (inactive
-  slots' writes land there, mirroring the page trick);
+  H * Dh)`` — rows as the paged pool holds them (heads merged), but the
+  draft is small, so the paged indirection would cost more than it saves;
+  the final position is the trash position (inactive slots' writes land
+  there, mirroring the page trick);
 * ``draft_prefill`` — the draft's full-prompt pass at admission (the
   prompt rides the same ``max_prompt`` bucket as the target prefill);
 * ``draft_decode`` — ONE compiled fn proposing all K tokens: a
@@ -95,7 +96,7 @@ def perturbed_draft(model: GptModel, *, scale: float = 1e-2,
 def _draft_decode_step(params, kv, tokens, pos, active, cfg):
     """One greedy draft token for every slot against the dense cache.
 
-    kv: (L, 2, S, T+1, H, Dh) — position T is the trash position;
+    kv: (L, 2, S, T+1, H*Dh) — position T is the trash position;
     tokens/pos: (S,) the fed token and its absolute position; active:
     (S,) int32. Writes the fed token's K/V at ``pos`` (trash when
     inactive), attends over positions ``<= pos``, returns
@@ -119,12 +120,11 @@ def _draft_decode_step(params, kv, tokens, pos, active, cfg):
     for li, blk in enumerate(params["blocks"]):
         a = blk["attn"]
         q = (x @ a["Wq"] + a["bq"]).reshape(s_n, h, 1, dh)
-        k = (x @ a["Wk"] + a["bk"]).reshape(s_n, h, dh)
-        v = (x @ a["Wv"] + a["bv"]).reshape(s_n, h, dh)
-        kv = kv.at[li, 0, rows, wpos].set(k)
-        kv = kv.at[li, 1, rows, wpos].set(v)
-        kc = kv[li, 0, :, :t_all - 1].transpose(0, 2, 1, 3)  # (S, H, T, Dh)
-        vc = kv[li, 1, :, :t_all - 1].transpose(0, 2, 1, 3)
+        kv = kv.at[li, 0, rows, wpos].set(x @ a["Wk"] + a["bk"])
+        kv = kv.at[li, 1, rows, wpos].set(x @ a["Wv"] + a["bv"])
+        kc, vc = (kv[li, c, :, :t_all - 1].reshape(
+            s_n, t_all - 1, h, dh).transpose(0, 2, 1, 3)  # (S, H, T, Dh)
+            for c in (0, 1))
         out = exec_op("dot_product_attention", q, kc, vc, m4, scaled=True)
         out = out.reshape(s_n, cfg.hidden)
         x = _layer_norm(x + out @ a["Wo"] + a["bo"],
@@ -161,7 +161,7 @@ class SpeculativeDecoder:
         dtype = jax.tree.leaves(draft_model.params)[0].dtype
         # +1: the trash position — inactive slots' scan writes land there
         self._kv_shape = (cfg.layers, 2, self.max_slots, self.max_ctx + 1,
-                          cfg.heads, cfg.hidden // cfg.heads)
+                          cfg.hidden)
         self._kv_dtype = dtype
         self.kv = jnp.zeros(self._kv_shape, dtype)
         self.lens = np.zeros((self.max_slots,), np.int32)
@@ -183,12 +183,12 @@ class SpeculativeDecoder:
             mask = (jnp.arange(ids.shape[1]) < prompt_len)[None, :]
             _logits, kvp = gpt_prefill(params, ids, cfg,
                                        mask=mask.astype(jnp.int32))
-            # kvp (L, 2, 1, Tpre, H, Dh) drops into the slot's row;
+            # kvp (L, 2, 1, Tpre, H*Dh) drops into the slot's row;
             # positions >= prompt_len hold pad garbage the <= pos decode
             # mask never reads (the first propose overwrites position
             # prompt_len before attending to it)
             return jax.lax.dynamic_update_slice(
-                kv, kvp, (0, 0, slot, 0, 0, 0))
+                kv, kvp, (0, 0, slot, 0, 0))
 
         return draft_prefill
 

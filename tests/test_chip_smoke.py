@@ -77,7 +77,13 @@ def test_gpt_phase_tiny(smoke):
                            vocab_size=32, max_position=32),
         slots=4, page_size=8, pages_per_seq=4, max_prompt=16,
         prompt_lens=(12, 5, 9), new_tokens=4)
-    assert "HloModule" in compiled_text()  # the decode step, lowered again
+    text = compiled_text()  # the decode step, lowered again
+    assert "HloModule" in text
+    assert rep["kv_pool"] == [1, 2, 17, 8, 32]  # heads merged: 2 x 16
+    assert smoke.pool_copies(text, rep["kv_pool"]) == 0
+    assert smoke.pool_copies(
+        "%copy.10 = f32[1,2,17,8,32]{4,3,2,1,0:T(8,128)} copy(%p)",
+        rep["kv_pool"]) == 1
     assert rep["requests"] == 4 and rep["bank_tokens"] == 12
     assert rep["finish_reasons"] == ["length"]
     assert rep["first_token"] in rep["reference_top2"]
@@ -99,4 +105,9 @@ def test_chip_evidence_rejects_a_phase_that_ran_generic(smoke):
     rep = {"phase": "serve/gpt2", "mosaic_calls": 0, "platforms": ["cpu"],
            "dispatch": {"paged_decode_attention/generic/no_helper": 12}}
     with pytest.raises(smoke.CheckFailed, match="never dispatched impl=tpu"):
+        smoke.check_chip_evidence(rep)
+    rep = {"phase": "serve/gpt2", "mosaic_calls": 12, "platforms": ["tpu"],
+           "dispatch": {"paged_decode_attention/tpu/usable": 12},
+           "kv_pool": [12, 2, 1041, 16, 768], "pool_copies": 2}
+    with pytest.raises(smoke.CheckFailed, match="copies the KV pool"):
         smoke.check_chip_evidence(rep)

@@ -249,41 +249,37 @@ class TestFusedOpRules:
         assert any(f.rule == "GC002" and "sequence lengths" in f.message
                    for f in report.findings)
 
-    def test_paged_decode_attention_symbolic_slots(self):
+    @staticmethod
+    def _paged_graph(q=(2, 4, 16), kv=(3, 2, 6, 8, 64), pt=(2, 3),
+                     pt_dtype=np.int32, **kwargs):
         sd = SameDiff()
-        q = sd.placeholder("q", (None, 4, 16))
-        kp = sd.var("kp", np.zeros((6, 8, 4, 16), np.float32))
-        vp = sd.var("vp", np.zeros((6, 8, 4, 16), np.float32))
-        pt = sd.placeholder("pt", (None, 3), dtype=np.int32)
-        sl = sd.placeholder("sl", (None,), dtype=np.int32)
-        sd.op("paged_decode_attention", q, kp, vp, pt, sl).rename("o")
-        report = check_samediff(sd)
+        qv = sd.placeholder("q", q)
+        pool = sd.var("kv", np.zeros(kv, np.float32))
+        ptv = sd.placeholder("pt", pt, dtype=pt_dtype)
+        sl = sd.placeholder("sl", (q[0],), dtype=np.int32)
+        sd.op("paged_decode_attention", qv, pool, ptv, sl,
+              **kwargs).rename("o")
+        return check_samediff(sd)
+
+    def test_paged_decode_attention_symbolic_slots(self):
+        report = self._paged_graph(q=(None, 4, 16), pt=(None, 3), layer=2)
         assert not report.findings
         aval = report.avals["o"]
         assert isinstance(aval.shape[0], Dim) and aval.shape[1:] == (4, 16)
 
-    def test_paged_decode_attention_rank_and_dtype_findings(self):
-        sd = SameDiff()
-        q = sd.placeholder("q", (2, 4, 16))
-        kp = sd.var("kp", np.zeros((6, 8, 4, 16), np.float32))
-        vp = sd.var("vp", np.zeros((6, 8, 4, 16), np.float32))
-        pt = sd.placeholder("pt", (2, 3, 1), dtype=np.int32)  # rank 3
-        sl = sd.placeholder("sl", (2,), dtype=np.int32)
-        sd.op("paged_decode_attention", q, kp, vp, pt, sl)
-        report = check_samediff(sd)
-        assert any(f.rule == "GC001" and "page_table" in f.message
-                   for f in report.findings)
-
-        sd = SameDiff()
-        pt_f = sd.placeholder("pt", (2, 3))  # float page table
-        sl = sd.placeholder("sl", (2,), dtype=np.int32)
-        q = sd.placeholder("q", (2, 4, 16))
-        kp = sd.var("kp", np.zeros((6, 8, 4, 16), np.float32))
-        vp = sd.var("vp", np.zeros((6, 8, 4, 16), np.float32))
-        sd.op("paged_decode_attention", q, kp, vp, pt_f, sl)
-        report = check_samediff(sd)
-        assert any(f.rule == "GC003" and "not integral" in f.message
-                   for f in report.findings)
+    @pytest.mark.parametrize("graph,rule,needle", [
+        (dict(pt=(2, 3, 1)), "GC001", "page_table"),            # rank 3
+        (dict(kv=(6, 8, 4, 16)), "GC001", "kv_pages"),  # a layer's K alone
+        (dict(pt_dtype=np.float32), "GC003", "not integral"),
+        (dict(kv=(3, 2, 6, 8, 48)), "GC002", "row widths"),  # 4 x 16 != 48
+        (dict(kv=(3, 1, 6, 8, 64)), "GC002", "K and V"),
+        (dict(layer=3), "GC002", "layer 3"),
+        (dict(q=(2, 4, 16), pt=(5, 3)), "GC002", "slot counts"),
+    ])
+    def test_paged_decode_attention_findings(self, graph, rule, needle):
+        report = self._paged_graph(**graph)
+        assert any(f.rule == rule and needle in f.message
+                   for f in report.findings), report.findings
 
     def test_fused_matmul_bias_act_symbolic_batch(self):
         sd = SameDiff()

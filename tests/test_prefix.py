@@ -429,6 +429,29 @@ class TestPrefixEngine:
             "dl4j_tpu_prefix_cow_copies_total").value >= 2
         eng.check_invariants()
 
+    @pytest.mark.parametrize("tail,hit", [
+        ([50], True),                  # suffix prefill into the CoW'd tail
+        ([50, 51, 52, 53, 54], True),  # ... and on into a fresh page
+        (list(range(50, 59)), False),  # over the bucket: whole-page write
+    ])
+    def test_shared_pages_keep_their_bits(self, tail, hit):
+        """An admission beside a cached prefix (by the suffix path or by
+        the whole-page ``write_prompt``) and the decode steps after it
+        never write a page the tree holds: bit for bit."""
+        eng = make_engine(max_prompt=24)
+        warm = np.concatenate([SYS, np.asarray([40], np.int32)])
+        eng.generate([warm], max_new_tokens=2, eos_token=-1)
+        held = sorted(eng.prefix.page_refs())
+        assert held
+        before = np.asarray(eng.cache.kv[:, :, held])
+        p = np.concatenate([SYS, np.asarray(tail, np.int32)])
+        res = eng.generate([p], max_new_tokens=7, eos_token=-1)[0]
+        assert (res.prefix_hit_tokens > 0) is hit
+        assert_oracle(p, res)
+        np.testing.assert_array_equal(
+            np.asarray(eng.cache.kv[:, :, held]), before)
+        eng.check_invariants()
+
     def test_midflight_admits_with_shared_prefix(self):
         """Several same-prefix requests through 2 slots with different
         budgets: mid-flight turnover, shared pages across LIVE slots,

@@ -12,6 +12,8 @@ Covers the four properties the subsystem is built around:
     scheduler loop (the graftlint GL004 property, asserted at runtime).
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -23,8 +25,9 @@ from deeplearning4j_tpu.models.gpt import (
     GptConfig, GptModel, reference_generate,
 )
 from deeplearning4j_tpu.ops.pallas_attention import (
-    _paged_decode_call, paged_decode_attention_xla,
+    _paged_decode_call, _paged_usable, paged_decode_attention_xla,
 )
+from deeplearning4j_tpu.ops.registry import registry
 from deeplearning4j_tpu.serving import GenerativeEngine, PagedKVCache
 from deeplearning4j_tpu.serving.sampling import sample_tokens
 
@@ -181,44 +184,132 @@ class TestSampling:
 # ---------------------------------------------------------------------------
 
 
+def _numpy_paged_attention(q, kv, pt, sl, layer):
+    """Straight per-head softmax over each slot's first ``sl`` tokens."""
+    s_n, h, d = q.shape
+    want = np.zeros_like(q)
+    for i in range(s_n):
+        gk = kv[layer, 0, pt[i]].reshape(-1, h, d)[:sl[i]]
+        gv = kv[layer, 1, pt[i]].reshape(-1, h, d)[:sl[i]]
+        for hh in range(h):
+            sc = gk[:, hh] @ q[i, hh] / np.sqrt(d)
+            p = np.exp(sc - sc.max())
+            want[i, hh] = (p / p.sum()) @ gv[:, hh]
+    return want
+
+
+@contextlib.contextmanager
+def helper_mode(mode):
+    from deeplearning4j_tpu.environment import environment
+
+    env = environment()
+    old = env.helper_mode
+    env.helper_mode = mode
+    try:
+        yield
+    finally:
+        env.helper_mode = old
+
+
+def _dispatches(op="paged_decode_attention"):
+    return {k: v for k, v in observe.dispatch_summary().items()
+            if k.startswith(op + "/")}
+
+
+WIDE_CFG = GptConfig.tiny(hidden=128)   # 4 heads x 32: the kernel's width
+WIDE_MODEL = GptModel(WIDE_CFG, seed=1)
+
+
 class TestPagedDecodeEquivalence:
-    def test_kernel_matches_fallback(self):
+    # (heads, head_dim, page, pages a slot, kernel takes it): the merged
+    # width has to be a multiple of 128 lanes and the page of 8 sublanes. 19
+    # pages a slot is three grid steps of the kernel's 8 pages, the last one
+    # short, with sequences that end inside the first, second and third.
+    @pytest.mark.parametrize("h,d,page,max_pages,taken", [
+        (4, 32, 8, 4, True), (4, 64, 8, 19, True), (12, 64, 16, 19, True),
+        (4, 16, 8, 4, False), (2, 64, 4, 19, False)])
+    def test_kernel_and_fallback_match_numpy_oracle(self, h, d, page,
+                                                    max_pages, taken):
         r = np.random.RandomState(3)
-        s_n, h, d, page, n_pages, max_pages = 4, 4, 16, 8, 12, 4
-        q = jnp.asarray(r.randn(s_n, h, d).astype(np.float32))
-        kp = jnp.asarray(r.randn(n_pages, page, h, d).astype(np.float32))
-        vp = jnp.asarray(r.randn(n_pages, page, h, d).astype(np.float32))
-        pt = jnp.asarray(np.stack(
-            [r.choice(n_pages, max_pages, replace=False)
-             for _ in range(s_n)]).astype(np.int32))
-        sl = jnp.asarray(np.array([1, 9, 25, 32], np.int32))
-        want = paged_decode_attention_xla(q, kp, vp, pt, sl)
-        got = _paged_decode_call(q, kp, vp, pt, sl, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-2, atol=1e-5)
+        s_n, n_pages, layer = 4, 5 * max_pages, 1
+        q = r.randn(s_n, h, d).astype(np.float32)
+        kv = r.randn(2, 2, n_pages, page, h * d).astype(np.float32)
+        pt = np.stack([r.choice(n_pages, max_pages, replace=False)
+                       for _ in range(s_n)]).astype(np.int32)
+        sl = np.array([1, max_pages * page // 2 + 1,
+                       (max_pages - 1) * page + 1, max_pages * page],
+                      np.int32)
+        want = _numpy_paged_attention(q, kv, pt, sl, layer)
+        args = tuple(jnp.asarray(a) for a in (q, kv, pt, sl))
+        got = paged_decode_attention_xla(*args, layer=layer)
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   rtol=1e-4, atol=1e-5)
+        assert _paged_usable(*args, layer=layer) is taken
+        desc = registry().get("paged_decode_attention")
+        with helper_mode("pallas"):
+            impl = desc.resolve(*args, layer=layer)
+        assert (impl is not desc.fn) is taken
+        got = impl(*args, layer=layer)
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   rtol=1e-4, atol=1e-5)
+
+    def test_bfloat16_pool_kernel_matches_fallback(self):
+        r = np.random.RandomState(4)
+        q = jnp.asarray(r.randn(3, 4, 32), jnp.bfloat16)
+        kv = jnp.asarray(r.randn(1, 2, 7, 16, 128), jnp.bfloat16)
+        pt = jnp.asarray(r.randint(0, 7, (3, 3)).astype(np.int32))
+        sl = jnp.asarray(np.array([0, 17, 48], np.int32))
+        want = paged_decode_attention_xla(q, kv, pt, sl)
+        got = _paged_decode_call(q, kv, pt, sl, interpret=True)
+        # slot 0 is inactive (length 0): its output is never read
+        np.testing.assert_allclose(np.asarray(got[1:], np.float32),
+                                   np.asarray(want[1:], np.float32),
+                                   rtol=5e-2, atol=5e-2)
 
     def test_greedy_engine_equivalence_pallas_vs_xla(self):
-        """Whole-loop equivalence: greedy generation with the registry
-        resolving the Pallas paged path (forced helper_mode, interpret on
-        CPU) must emit the SAME tokens as the XLA gather fallback."""
-        from deeplearning4j_tpu.environment import environment
-
+        """Whole-loop equivalence at a width the kernel takes: greedy
+        generation with the registry resolving the Pallas paged path
+        (forced helper_mode, interpret on CPU) must emit the SAME tokens as
+        the XLA gather fallback."""
         def run():
-            eng = make_engine(max_slots=2)
+            eng = GenerativeEngine(WIDE_MODEL, max_slots=2, page_size=8,
+                                   max_pages_per_seq=6, max_prompt=16, seed=3)
             return [r.tokens for r in
                     eng.generate(PROMPTS[:3], max_new_tokens=6)]
 
-        env = environment()
-        old = env.helper_mode
-        try:
-            env.helper_mode = "xla"
+        with helper_mode("xla"):
             xla_toks = run()
-            env.helper_mode = "pallas"
+        before = _dispatches()
+        with helper_mode("pallas"):
             pallas_toks = run()
-        finally:
-            env.helper_mode = old
+        took = {k: v - before.get(k, 0) for k, v in _dispatches().items()}
+        assert took.get("paged_decode_attention/tpu/usable") == \
+            WIDE_CFG.layers, took
         for a, b in zip(xla_toks, pallas_toks):
             np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("model,mode", [(MODEL, "auto"),
+                                            (WIDE_MODEL, "pallas")],
+                             ids=["narrow-generic", "wide-kernel"])
+    @pytest.mark.parametrize("p_len", [5, 8, 13, 20])
+    def test_whole_page_prompt_write_then_decode_matches_oracle(
+            self, model, mode, p_len):
+        """``write_prompt`` writes whole pages: a prompt that ends mid-page
+        leaves the padded positions' rows in the tail of its last page.
+        Attention must mask them and decode overwrite them, so the tokens
+        that follow (here across the next page boundary, with a prompt
+        bucket that is no multiple of the page) are the oracle's."""
+        prompt = (np.arange(p_len, dtype=np.int32) * 7 + 3) % 200 + 1
+        with helper_mode(mode):
+            eng = GenerativeEngine(model, max_slots=2, page_size=8,
+                                   max_pages_per_seq=5, max_prompt=20,
+                                   seed=3)
+            res = eng.generate([prompt], max_new_tokens=12)[0]
+        assert res.finish_reason == "length"
+        np.testing.assert_array_equal(
+            res.tokens, reference_generate(model.params, model.cfg, prompt,
+                                           12))
+        eng.cache.check_invariants()
 
     def test_greedy_matches_full_attention_oracle(self):
         """Paged decode vs an O(T²) full-prefill autoregressive oracle —
@@ -413,7 +504,7 @@ class TestContinuousBatching:
         page1 = int(eng.cache.page_table[slot, 1])
         assert page1 != eng.cache.trash_page, (
             "admission did not allocate the page the first decode writes")
-        pos8_kv = np.asarray(eng.cache.kv[:, :, page1, 0])
+        pos8_kv = np.asarray(eng.cache.kv[:, :, page1, 0])  # (L, 2, H*Dh)
         assert np.abs(pos8_kv).max() > 0, (
             "first generated token's K/V was lost to the trash page")
         while eng.scheduler.has_work():

@@ -244,16 +244,30 @@ class TestSchedulerIntegration:
         assert rs.spec_proposed_tokens == 0 and len(rs.tokens) == 8
         eng.check_invariants()
 
-    def test_near_context_limit_degrades_to_plain(self):
+    @pytest.mark.parametrize("hidden,mode", [(64, "auto"), (128, "pallas")],
+                             ids=["narrow-generic", "wide-kernel"])
+    def test_near_context_limit_degrades_to_plain(self, hidden, mode):
         """A sequence whose verify window no longer fits its page-table
         row finishes NON-speculatively instead of overflowing — and the
-        tokens stay exact across the switchover."""
-        # context = 2 pages * 8 = 16; prompt 6 + 10 tokens hits the edge
-        eng = make_engine(spec_k=4, draft_model=MODEL, max_pages_per_seq=2,
-                          max_prompt=8)
-        p = PROMPTS[2]
-        r = eng.generate([p], max_new_tokens=9, eos_token=-1)[0]
-        assert r.tokens.tolist() == oracle(p, 9)
+        tokens stay exact across the switchover: the plain decode (the
+        paged kernel, interpreted, at the width it takes; the gather path
+        at the width it refuses) reads the rows verify scattered."""
+        from deeplearning4j_tpu.environment import environment
+
+        model = GptModel(GptConfig.tiny(hidden=hidden), seed=1)
+        env = environment()
+        old, env.helper_mode = env.helper_mode, mode
+        try:
+            # context = 2 pages * 8 = 16; prompt 6 + 10 tokens hits the edge
+            eng = GenerativeEngine(model, spec_k=4, draft_model=model,
+                                   max_slots=2, page_size=8, seed=3,
+                                   max_pages_per_seq=2, max_prompt=8)
+            p = PROMPTS[2]
+            r = eng.generate([p], max_new_tokens=9, eos_token=-1)[0]
+        finally:
+            env.helper_mode = old
+        assert r.tokens.tolist() == reference_generate(
+            model.params, model.cfg, p, 9).tolist()
         assert r.finish_reason == "length"
         eng.check_invariants()
 

@@ -159,6 +159,8 @@ class TestSpanIds:
 # ------------------------------------------------------- the serving engine
 
 
+EPS = 1.0  # microseconds, for the float arithmetic behind the timestamps
+
 STAGES = ("serving_schedule", "serving_admit", "serving_next_key",
           "serving_prefill", "serving_prefill_launch", "serving_prefill_read",
           "serving_decode_upload", "serving_decode", "serving_decode_launch",
@@ -229,10 +231,19 @@ class TestServingStageSpans:
             assert req["args"]["reason"] == res.finish_reason
             assert req["args"]["tokens"] == len(res.tokens)
             assert prefills[rid]["args"]["parent"] == admits[rid]["args"]["id"]
-            # queue wait + the admission up to the first token = TTFT
-            first_token = prefills[rid]["ts"] + prefills[rid]["dur"]
-            got = waits[rid]["dur"] + first_token - admits[rid]["ts"]
-            assert got / 1e6 == pytest.approx(res.ttft_s, abs=1e-3)
+            # queue wait + the admission up to the first token = TTFT, from
+            # the spans' own clock (microseconds; EPS for their rounding):
+            # the wait ends where the admission that took the request
+            # starts, the prefill lies inside that admission, and the first
+            # token's time is read after the prefill closes and before the
+            # admission does. No wall-clock tolerance: under other workers
+            # any stretch between two of these reads can take milliseconds.
+            wait, adm, pre = waits[rid], admits[rid], prefills[rid]
+            end = lambda e: e["ts"] + e["dur"]  # noqa: E731
+            assert abs(end(wait) - adm["ts"]) <= EPS
+            assert adm["ts"] <= pre["ts"] and end(pre) <= end(adm) + EPS
+            assert (end(pre) - wait["ts"] - EPS <= res.ttft_s * 1e6
+                    <= end(adm) - wait["ts"] + EPS)
         wait_h = observe.metrics().histogram(
             "dl4j_tpu_serving_queue_wait_seconds")
         assert wait_h.count == len(PROMPTS)

@@ -3,9 +3,12 @@
 Interpret mode (all the rest of the suite) never legalises for Mosaic, so a
 kernel can pass every CPU test and still be refused by the chip's compiler.
 Each case here lowers one kernel with ``interpret=False`` at the width
-``chip_smoke.py`` runs it (BERT-base b16 x s512, GPT-2-small with 16 slots
-and 65 pages of 16) for a DESCRIBED ``v5e:2x2`` device — no chip attached,
-nothing executes — and asserts the Mosaic call is in the compiled text.
+``chip_smoke.py`` runs it (BERT-base b16 x s512) for a DESCRIBED ``v5e:2x2``
+device — no chip attached, nothing executes — and asserts the Mosaic call is
+in the compiled text. The paged decode kernel is compiled inside the serving
+engine's own ``decode`` program, at the benchmark cell's geometry, together
+with the other programs that take the KV pool: what is held there is that
+none of them copies the pool.
 
 The topology is described inside the module-scoped fixture only: one
 process at a time may load the TPU library, and pytest-xdist imports this
@@ -20,8 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning4j_tpu.nn.updater import UPDATERS
-from deeplearning4j_tpu.ops.pallas_attention import (
-    _paged_decode_call, flash_attention)
+from deeplearning4j_tpu.ops.pallas_attention import flash_attention
 from deeplearning4j_tpu.ops.pallas_layernorm import fused_layer_norm_pallas
 from deeplearning4j_tpu.ops.pallas_matmul import fused_matmul_bias_act_pallas
 from deeplearning4j_tpu.ops.pallas_updater import fused_updater_helper
@@ -57,16 +59,95 @@ def _assert_mosaic(text):
     assert "tpu_custom_call" in text
 
 
+# The serving cell's geometry (benchmarks/traffic/chat-closed.json): GPT-2
+# small, 32 slots x 65 pages of 16, prompts bucketed to 896.
+SLOTS, PAGE, PAGES_PER_SEQ, MAX_PROMPT = 32, 16, 65, 896
+N_PAGES = SLOTS * PAGES_PER_SEQ
+
+
+def _pool_programs(one_chip, monkeypatch, dtype):
+    """The engine's own ``decode`` and ``write_prompt`` (and the cache's
+    ``copy_page``) compiled from shapes alone: neither the pool (2.45 GB in
+    float32) nor the weights exist on this host. Dispatch is steered to its
+    TPU branch here, in the test: the process still sees the CPU."""
+    import importlib
+
+    from deeplearning4j_tpu.models.gpt import GptConfig, init_gpt_params
+    from deeplearning4j_tpu.ops import tuning
+    from deeplearning4j_tpu.serving.cache import PagedKVCache
+    from deeplearning4j_tpu.serving.engine import build_decode, build_write
+
+    registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(tuning, "current_device_kind", lambda: "tpu_v5_lite")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cfg = GptConfig.base()
+    i32, f32 = jnp.int32, jnp.float32
+    pool = sds((cfg.layers, 2, N_PAGES + 1, PAGE, cfg.hidden), dtype)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_gpt_params(jax.random.key(0), cfg,
+                                               dtype)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    slot = lambda dt: sds((SLOTS,), dt)  # noqa: E731
+    programs = {
+        "decode": build_decode(cfg, PAGE, N_PAGES).lower(
+            params, pool, sds((SLOTS, PAGES_PER_SEQ), i32), slot(i32),
+            slot(i32), slot(i32), key, slot(f32), slot(i32), slot(f32)),
+        "write_prompt": build_write(PAGE, N_PAGES).lower(
+            pool, sds((cfg.layers, 2, MAX_PROMPT, cfg.hidden), dtype),
+            sds((PAGES_PER_SEQ,), i32), sds((), i32)),
+        "copy_page": PagedKVCache._build_copy(None).lower(
+            pool, sds((), i32), sds((), i32)),
+    }
+    return pool, {k: v.compile() for k, v in programs.items()}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_gpt2_small(one_chip, dtype):
-    slots, heads, dh, page, pages_per_seq = 16, 12, 64, 16, 65
-    n_pages = slots * pages_per_seq + 1
-    kv = ((n_pages, page, heads, dh), dtype)
-    text = _compiled_text(
-        one_chip, functools.partial(_paged_decode_call, interpret=False),
-        ((slots, heads, dh), dtype), kv, kv,
-        ((slots, pages_per_seq), jnp.int32), ((slots,), jnp.int32))
-    _assert_mosaic(text)
+def test_serving_programs_update_the_pool_in_place(one_chip, monkeypatch,
+                                                   dtype):
+    """No program that takes the KV pool copies it, re-lays it out or slices
+    a layer out of it (PERF.md, PR 26: two such copies in ``decode``, two in
+    ``write_prompt`` and twelve layer slices were 49 of a 70 ms decode step).
+    Held on the compiled text: every instruction whose result has the pool's
+    element count is the pool's parameter, an in-place scatter or
+    dynamic-update-slice (or the fusion around one), in the pool's row-major
+    layout; nothing has a layer's ``[2081,16,...]`` shape; and the program's
+    temporaries stay under a tenth of the pool."""
+    import math
+    import re
+
+    pool, compiled = _pool_programs(one_chip, monkeypatch, dtype)
+    n_pool = math.prod(pool.shape)
+    pool_bytes = n_pool * jnp.dtype(dtype).itemsize
+    row_major = "{4,3,2,1,0:"
+    in_place = ("parameter", "scatter", "dynamic-update-slice", "fusion",
+                "get-tuple-element", "tuple", "bitcast")
+    result = re.compile(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\(?)\w+\[([\d,]*)\](\{[^ ]*)? "
+        r"([\w\-]+)\(")
+    for name, c in compiled.items():
+        text = c.as_text()
+        for line in text.splitlines():
+            m = result.match(line)
+            if not m or m.group(1):
+                continue
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            assert dims[:2] != [N_PAGES + 1, PAGE], (name, line[:200])
+            if math.prod(dims) != n_pool:
+                continue
+            assert m.group(4) in in_place, (name, line[:200])
+            assert (m.group(3) or "").startswith(row_major), (name,
+                                                              line[:200])
+        temp = c.memory_analysis().temp_size_in_bytes
+        assert temp < pool_bytes / 10, (name, temp)
+    decode = compiled["decode"].as_text()
+    assert decode.count('custom_call_target="tpu_custom_call"') == 12
+    assert decode.count("scatter(") >= 24
 
 
 @pytest.mark.parametrize("bh,t,causal,rate", [

@@ -315,17 +315,17 @@ class TestMeasuredDispatch:
         _write_table(tuning_sandbox,
                      {"paged_decode_attention": {"min_pages": 4}})
         desc = registry().get("paged_decode_attention")
-        q = jnp.zeros((2, 2, 8), jnp.float32)
-        kp = jnp.zeros((8, 8, 2, 8), jnp.float32)
+        q = jnp.zeros((2, 2, 64), jnp.float32)
+        kv = jnp.zeros((1, 2, 8, 8, 128), jnp.float32)
         sl = jnp.zeros((2,), jnp.int32)
 
         def pt(pages):
             return jnp.zeros((2, pages), jnp.int32)
 
         below, d1 = _dispatch_delta(
-            lambda: desc.resolve(q, kp, kp, pt(2), sl))
+            lambda: desc.resolve(q, kv, pt(2), sl, layer=0))
         above, d2 = _dispatch_delta(
-            lambda: desc.resolve(q, kp, kp, pt(4), sl))
+            lambda: desc.resolve(q, kv, pt(4), sl, layer=0))
         assert below is desc.fn
         assert above is not desc.fn
         assert d1.get("paged_decode_attention/generic/not_usable") == 1
