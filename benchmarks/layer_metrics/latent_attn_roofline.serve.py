@@ -1,0 +1,34 @@
+"""Least time by the roofline (bytes-bound: counts/longcat.py
+``latent_bytes_per_token``, over the decode tokens that arrived inside the
+traced window) over the summed device time of the latent decode attention
+kernel's events there. The events are found by the NAME the program gives
+the kernel (``pallas_call(name="latent_decode_attention")``, which the trace
+prints as ``%latent_decode_attention.N = ... custom_call_target=
+"tpu_custom_call"``), never by an operand's shape. Nothing found (the op on
+its generic path, a program without it): nothing returned."""
+
+import common
+import trace_reduce
+
+PATTERN = ("%latent_decode_attention", "custom_call_target=\"tpu_custom_call\"")
+
+
+def read(ctx):
+    tr, span = ctx.get("trace"), ctx.get("traced")
+    if not tr or not span:
+        return None
+    found = trace_reduce.matching(tr, PATTERN)
+    if not found or found[0] <= 0:
+        return None
+    counts = common.module("counts", "longcat")
+    cfg = ctx["cell"]["cfg"]
+    t0, t1 = span
+    need = 0.0
+    for p, times in ctx["tokens"]:
+        for i, t in enumerate(times[1:], start=1):
+            if t0 <= t <= t1:
+                need += counts.latent_bytes_per_token(cfg, p + i)
+    if not need:
+        return None
+    least = need / common.peaks_of(ctx["kind"])["bytes_per_s"]
+    return 100.0 * least / found[0]

@@ -357,7 +357,7 @@ def phase_dp(*, chips: int = 4, make_net=_resnet50, image: int = 224,
           f"dp: the batch is not sharded {shard} per device (replicated "
           f"fallback of ParallelWrapper._data_spec?)")
     # where would serving replicas land? nothing in serving/ names a device
-    probe = PagedKVCache(layers=1, heads=1, head_dim=8, num_pages=2,
+    probe = PagedKVCache(layers=1, row_width=8, num_pages=2,
                          max_slots=1, max_pages_per_seq=1)
     return {"phase": f"train/resnet-dp{chips}", "batch": batch,
             "one_chip_losses": one, "mesh_losses": many,

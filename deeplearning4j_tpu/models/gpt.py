@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.models.bert import _layer_norm
+from deeplearning4j_tpu.models.served import CacheRows, ServingPrograms
 
 
 @dataclasses.dataclass(frozen=True)
@@ -369,6 +370,36 @@ def gpt_decode_step(params, kv_pages, tokens, positions, page_table,
     return kv_pages, logits
 
 
+def gpt_cache_rows(cfg: GptConfig) -> CacheRows:
+    """A key row and a value row a token a layer, heads merged."""
+    if cfg.hidden % cfg.heads:
+        raise ValueError("hidden must be divisible by heads")
+    return CacheRows(layers=cfg.layers, sides=2, width=cfg.hidden)
+
+
+def gpt_programs(cfg: GptConfig) -> ServingPrograms:
+    """What the serving engine asks a model for (models/served.py): the
+    functions above bound to ``cfg`` (needs no weights)."""
+
+    def prefill(params, ids, prompt_len):
+        mask = (jnp.arange(ids.shape[1]) < prompt_len)[None, :]
+        logits, kv = gpt_prefill(params, ids, cfg,
+                                 mask=mask.astype(jnp.int32))
+        return logits[0, prompt_len - 1][None], kv[:, :, 0], None
+
+    def decode_step(*args):
+        return (*gpt_decode_step(*args, cfg), None)
+
+    def prefill_suffix(*args):
+        return gpt_prefill_suffix(*args, cfg)
+
+    def verify(*args, page_size: int):
+        return gpt_verify(*args, cfg, page_size=page_size)
+
+    return ServingPrograms(prefill=prefill, decode_step=decode_step,
+                           prefill_suffix=prefill_suffix, verify=verify)
+
+
 def reference_generate(params, cfg: GptConfig, prompt, n_new: int
                        ) -> np.ndarray:
     """Greedy autoregressive oracle: re-runs the FULL causal prefill for
@@ -396,6 +427,12 @@ class GptModel:
     def num_params(self) -> int:
         return sum(int(np.prod(l.shape))
                    for l in jax.tree.leaves(self.params))
+
+    def cache_rows(self) -> CacheRows:
+        return gpt_cache_rows(self.cfg)
+
+    def serving_programs(self) -> ServingPrograms:
+        return gpt_programs(self.cfg)
 
     def logits(self, ids) -> np.ndarray:
         """Convenience full-sequence forward (no cache)."""

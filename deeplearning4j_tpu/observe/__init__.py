@@ -54,6 +54,8 @@ from deeplearning4j_tpu.observe.ledger import (
     signature_of,
 )
 
+from deeplearning4j_tpu.observe.moe import note_moe
+
 # short accessors — the names call sites use
 metrics = default_registry
 tracer = default_tracer
@@ -297,6 +299,6 @@ __all__ = [
     "CompileEvent", "RecompileLedger", "OBS_LOG_ENV",
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
-    "install_xla_listener", "scanned_call",
+    "install_xla_listener", "scanned_call", "note_moe",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

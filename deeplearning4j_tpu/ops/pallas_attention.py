@@ -660,15 +660,16 @@ def flash_mha(q, k, v, *, num_heads: int, causal: bool = False,
 
 def gather_pages(kv_pages, layer: int, side: int, pages):
     """``kv_pages[layer, side, pages]``: the pages ``pages`` (any shape of
-    int32) of one layer's K (``side`` 0) or V (1), ``pages.shape + (page,
-    H*D)``, as a gather with ONE index a page over the pool seen as a run of
-    pages (a bitcast of the row-major pool, no copy). Written
-    ``kv_pages[layer, side, pages]`` the TPU compiler packs the three index
-    components into bit fields of one word, and the program that unpacks them
-    halted the v5e at the serving cell's pool size (PERF.md, PR 26)."""
-    n_l, _, n_p, page, width = kv_pages.shape
-    run = kv_pages.reshape(n_l * 2 * n_p, page, width)
-    return run[(layer * 2 + side) * n_p + pages]
+    int32) of one layer's K (``side`` 0) or V (1) — or of a latent pool's one
+    side —, ``pages.shape + (page, width)``, as a gather with ONE index a
+    page over the pool seen as a run of pages (a bitcast of the row-major
+    pool, no copy). Written ``kv_pages[layer, side, pages]`` the TPU compiler
+    packs the three index components into bit fields of one word, and the
+    program that unpacks them halted the v5e at the serving cell's pool size
+    (PERF.md, PR 26)."""
+    n_l, n_s, n_p, page, width = kv_pages.shape
+    run = kv_pages.reshape(n_l * n_s * n_p, page, width)
+    return run[(layer * n_s + side) * n_p + pages]
 
 
 def paged_decode_attention_xla(q, kv_pages, page_table, seq_lens, *,
@@ -868,6 +869,195 @@ def _check_paged_decode_attention():
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# Latent decode attention (MLA, projections absorbed) over a latent paged pool
+# ---------------------------------------------------------------------------
+#
+# A latent cache holds ONE row a token an attention sub-layer: the normalised
+# latent ``c`` (``value_width`` values, which are the keys' content part AND
+# the values) followed by the rotated positional key shared by every head,
+# then dead lanes up to a multiple of 128 so that the device keeps the pool
+# row-major and unpadded: ``(sub-layers, 1, P, page, W)``. With the key and
+# value up-projections absorbed into the query and the output, decode is all
+# the heads against that one row: ``score_h = (q_abs_h . c + q_rope_h . k_r)
+# * scale`` and ``o_h = sum_t p_ht c_t``. Two implementations of one
+# contract, as above: the generic gather (every platform) and a Pallas kernel
+# that reads the pages where they lie.
+
+
+# Pages a grid step: a latent page is 20 KB at the published widths (16 rows
+# of 640 bfloat16), a fifth of a GPT-2 page's K and V, and a grid step's cost
+# is the wait for its pages more than their bytes. Timed on the v5e at the
+# serving cell's geometry (128 slots, contexts ~550, 96 pages a sequence),
+# the call by pages a step 4, 8, 16, 32, 48, 96: 1.53, 1.19, 1.07, 0.99,
+# 1.00, 1.00 ms (PERF.md, PR 27).
+_LATENT_PAGES_PER_STEP = 32
+
+
+def _latent_query(q_abs, q_rope, width: int):
+    """[q_abs | q_rope | 0]: one query row a head over the pool's row."""
+    s_n, h, r = q_abs.shape
+    dead = width - r - q_rope.shape[-1]
+    return jnp.concatenate(
+        [q_abs, q_rope, jnp.zeros((s_n, h, dead), q_abs.dtype)], axis=-1)
+
+
+def latent_decode_attention_xla(q_abs, q_rope, kv_pages, page_table, seq_lens,
+                                *, layer: int = 0, scale: float,
+                                value_width: Optional[int] = None):
+    """Generic gather path: q_abs:[S,H,R], q_rope:[S,H,Dr], kv_pages:
+    [L,1,P,page,W] (the whole pool, W >= R + Dr), page_table:[S,max_pages],
+    seq_lens:[S] -> [S,H,R] (the probabilities over the latents; the caller
+    projects them through the values' up-projection). Scores and softmax in
+    float32."""
+    s_n, h, r = q_abs.shape
+    value_width = r if value_width is None else value_width
+    page, width = kv_pages.shape[3], kv_pages.shape[4]
+    max_pages = page_table.shape[1]
+    with jax.named_scope("latent_decode_attention"):
+        rows = gather_pages(kv_pages, layer, 0, page_table).reshape(
+            s_n, max_pages * page, width).astype(jnp.float32)
+        q = _latent_query(q_abs, q_rope, width).astype(jnp.float32)
+        s = jnp.einsum("shw,stw->sht", q, rows) * scale
+        pos = jnp.arange(max_pages * page)
+        s = jnp.where(pos[None, None, :] < seq_lens[:, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("sht,stv->shv", p,
+                          rows[..., :value_width]).astype(q_abs.dtype)
+
+
+def _latent_decode_kernel(pt_ref, sl_ref, q_ref, *refs, page: int,
+                          scale: float, group: int, value_width: int):
+    """One (slot, group of pages) grid step: every head's scores against the
+    group's rows in ONE (H, W) x (W, positions) product and every head's
+    weighted latents in ONE (H, positions) x (positions, R) product. The
+    walk stops at the sequence's length as in ``_paged_decode_kernel``."""
+    kv_refs, (o_ref, acc_ref, mx_ref, l_ref) = refs[:group], refs[group:]
+    s_idx, j = pl.program_id(0), pl.program_id(1)
+    rows = acc_ref.shape[0]
+    span = group * page
+    seq_len = sl_ref[s_idx]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        mx_ref[:] = jnp.full_like(mx_ref, -1e30)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * span < seq_len)
+    def _attend():
+        blk = jnp.concatenate([r[:] for r in kv_refs], axis=0)  # (span, W)
+        s = _mm_nt(q_ref[:], blk) * scale                       # f32 (H, span)
+        pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        s = jnp.where(pos < seq_len, s, -1e30)
+        m_prev = mx_ref[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + _mm_nn(
+            p.astype(blk.dtype), blk[:, :value_width])
+        mx_ref[:, :1] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def _latent_decode_call(q_abs, q_rope, kv_pages, page_table, seq_lens, *,
+                        layer: int = 0, scale: float,
+                        value_width: Optional[int] = None,
+                        interpret: Optional[bool] = None):
+    """Pallas latent decode. Same contract as latent_decode_attention_xla,
+    except that a slot of length 0 (inactive: nobody reads it) gives zeros."""
+    s_n, h, r = q_abs.shape
+    value_width = r if value_width is None else value_width
+    page, width = kv_pages.shape[3], kv_pages.shape[4]
+    max_pages = page_table.shape[1]
+    interpret = pallas_interpret(interpret)
+    group = min(_LATENT_PAGES_PER_STEP, max_pages)
+    kernel = functools.partial(_latent_decode_kernel, page=page, scale=scale,
+                               group=group, value_width=value_width)
+    layer = int(layer)
+
+    def page_block(i):
+        def index(s, j, pt, sl):
+            last = jnp.maximum(sl[s] - 1, 0) // page
+            return (layer, 0, pt[s, jnp.minimum(j * group + i, last)], 0, 0)
+
+        return pl.BlockSpec((None, None, None, page, width), index)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_n, -(-max_pages // group)),
+        in_specs=[pl.BlockSpec((None, h, width),
+                               lambda s, j, pt, sl: (s, 0, 0))]
+        + [page_block(i) for i in range(group)],
+        out_specs=pl.BlockSpec((None, h, value_width),
+                               lambda s, j, pt, sl: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, value_width), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+        ],
+    )
+    with jax.named_scope("latent_decode_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, h, value_width), q_abs.dtype),
+            interpret=interpret,
+            name="latent_decode_attention",
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+          _latent_query(q_abs, q_rope, width), *([kv_pages] * group))
+
+
+def _latent_usable(q_abs, q_rope, kv_pages, page_table, seq_lens, **kw):
+    """The Pallas latent path takes whole-tile pages (row a multiple of 128
+    lanes, page of 8 sublanes), a latent that is whole lanes too, and heads
+    that fill sublanes; the tiny test models take the generic path."""
+    if getattr(q_abs, "ndim", 0) != 3 or getattr(kv_pages, "ndim", 0) != 5:
+        return False
+    if getattr(page_table, "ndim", 0) != 2 or kv_pages.shape[1] != 1:
+        return False
+    value_width = kw.get("value_width") or q_abs.shape[2]
+    return (kv_pages.shape[4] % 128 == 0 and kv_pages.shape[3] % 8 == 0
+            and value_width % 128 == 0 and q_abs.shape[2] % 128 == 0
+            and q_abs.shape[1] % 8 == 0)
+
+
+def _check_latent_decode_attention():
+    """Validation case: the generic path against a numpy oracle and the
+    Pallas kernel (interpreted) against both, on the second sub-layer."""
+    import numpy as np
+
+    rs = np.random.RandomState(11)
+    s_n, h, r, dr, page, n_pages, max_pages, layer = 3, 8, 128, 32, 8, 10, 3, 1
+    width = 256
+    qa = rs.randn(s_n, h, r).astype(np.float32)
+    qr = rs.randn(s_n, h, dr).astype(np.float32)
+    kv = np.zeros((2, 1, n_pages, page, width), np.float32)
+    kv[..., :r + dr] = rs.randn(2, 1, n_pages, page, r + dr)
+    pt = np.stack([rs.choice(n_pages, max_pages, replace=False)
+                   for _ in range(s_n)]).astype(np.int32)
+    sl = np.array([5, 17, 24], np.int32)
+    scale = 0.11
+    want = np.zeros((s_n, h, r), np.float32)
+    for i in range(s_n):
+        rows = kv[layer, 0, pt[i]].reshape(-1, width)[:sl[i]]
+        for hh in range(h):
+            sc = (rows[:, :r] @ qa[i, hh] + rows[:, r:r + dr] @ qr[i, hh]) * scale
+            p = np.exp(sc - sc.max())
+            want[i, hh] = (p / p.sum()) @ rows[:, :r]
+    args = tuple(jnp.asarray(a) for a in (qa, qr, kv, pt, sl))
+    got = latent_decode_attention_xla(*args, layer=layer, scale=scale)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    assert _latent_usable(*args)
+    got_pl = _latent_decode_call(*args, layer=layer, scale=scale)
+    np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
+
+
 def register_platform_attention() -> None:
     """Install flash attention as the TPU platform override for the generic
     dot_product_attention op, and register the paged decode-attention op
@@ -888,6 +1078,18 @@ def register_platform_attention() -> None:
                               _paged_decode_call, _paged_usable)
         _validation.add_case("paged_decode_attention",
                              _check_paged_decode_attention)
+
+    if "latent_decode_attention" not in reg:
+        reg.register(
+            "latent_decode_attention", latent_decode_attention_xla,
+            doc="decode-step latent (MLA, absorbed) attention over the latent "
+                "paged pool (q_abs:[S,H,R], q_rope:[S,H,Dr], kv_pages:"
+                "[L,1,P,page,W], page_table:[S,max_pages], seq_lens:[S], "
+                "layer=, scale=, value_width= -> [S,H,R])")
+        reg.register_platform("latent_decode_attention", "tpu",
+                              _latent_decode_call, _latent_usable)
+        _validation.add_case("latent_decode_attention",
+                             _check_latent_decode_attention)
 
     def flash_dpa(q, k, v, mask=None, *, scaled: bool = True,
                   causal: bool = False,

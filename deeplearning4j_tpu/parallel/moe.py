@@ -118,3 +118,99 @@ def moe_forward(mesh: Mesh, *, n_experts: int, capacity_factor: float = 1.25,
         return y, jnp.mean(aux)
 
     return run
+
+
+# ---------------------------------------------------------------------------
+# Served expert layer: top-k over routed + zero-compute experts, one share
+# ---------------------------------------------------------------------------
+
+
+def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
+                   scale: float, held, bias=None, valid=None):
+    """One chip's share of a top-``k`` expert layer with zero-compute
+    (identity) experts, for serving: no capacity and no dropped token,
+    whatever the imbalance.
+
+    params: ``router`` (d, n_routed + n_zero) and the HELD experts' SwiGLU
+    weights ``Wg``/``Wu`` (count, d, w) and ``Wd`` (count, w, d); u: (T, d);
+    ``held = (first, count)``: the routed experts ``first .. first+count-1``
+    live here. The router's softmax (float32) runs over ALL outputs; the
+    chosen are the top ``k`` of ``s + bias`` (the bias moves the choice, not
+    the weight); a chosen output's weight is ``scale * s``, not renormalised.
+    The result is the partial sum this chip can give: its held experts' terms
+    and the zero experts' ``w * u`` (an identity needs no owner); the absent
+    experts' terms are left out. The (token, pick) rows of held experts are
+    sorted by expert and go through ONE grouped product a matrix
+    (``jax.lax.ragged_dot``): every shape is static, so the caller's program
+    compiles once, and a row is computed by its own expert only. The product
+    runs over the head of the sorted rows where the held rows fit it (twice
+    what even routing gives this rank) and over all of them where not.
+
+    ``valid``: (T,) bool, the tokens that count: padding and idle slots take
+    no row of the grouped product (nobody reads their result: a prompt's 300
+    padded positions are one token over and over, route alike and would
+    swamp one expert) and stay out of the statistics. Returns ``(y (T, d), stats (count + 2,) int32)``:
+    tokens a held expert, then the picks that went to zero experts and to
+    absent experts."""
+    first, count = held
+    t, d = u.shape
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(u.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.softmax(logits, axis=-1)
+        ranked = s if bias is None else s + bias.astype(jnp.float32)
+        _, chosen = jax.lax.top_k(ranked, top_k)                  # (T, k)
+        weight = scale * jnp.take_along_axis(s, chosen, axis=-1)  # (T, k)
+        is_zero = chosen >= n_routed
+        local = chosen - first
+        is_held = (local >= 0) & (local < count) & ~is_zero
+        seen = (jnp.ones((t,), bool) if valid is None else valid)[:, None]
+        per_expert = jnp.zeros((count + 1,), jnp.int32).at[
+            jnp.where(is_held & seen, local, count)].add(1)[:count]
+        stats = jnp.concatenate([
+            per_expert,
+            jnp.sum(is_zero & seen, dtype=jnp.int32)[None],
+            jnp.sum(~is_zero & ~is_held & seen, dtype=jnp.int32)[None]])
+        w_zero = jnp.sum(jnp.where(is_zero, weight, 0.0), axis=-1)
+    y = w_zero[:, None] * u.astype(jnp.float32)
+    if count == 0:
+        return y.astype(u.dtype), stats
+    with jax.named_scope("moe_experts"):
+        # rows (token, pick), the held experts' first and in expert order
+        # (``per_expert`` is each group's size); the others form no group
+        # and give nought
+        group = jnp.where(is_held & seen, local, count).reshape(-1)
+        order = jnp.argsort(group, stable=True)
+        sizes = per_expert
+        row_w = jnp.where(group[order] < count, weight.reshape(-1)[order], 0.0)
+
+        def experts(n):
+            """The first ``n`` sorted rows through the grouped products."""
+            token, w_n = order[:n] // top_k, row_w[:n, None]
+            rows = u[token]
+            hidden = (jax.nn.silu(jax.lax.ragged_dot(
+                rows, params["Wg"], sizes, preferred_element_type=jnp.float32))
+                * jax.lax.ragged_dot(rows, params["Wu"], sizes,
+                                     preferred_element_type=jnp.float32))
+            out = jax.lax.ragged_dot(hidden.astype(u.dtype), params["Wd"],
+                                     sizes, preferred_element_type=jnp.float32)
+            # a row past the groups holds whatever the product left there
+            out = jnp.where(w_n != 0.0, out * w_n, 0.0)
+            return jnp.zeros((t, d), jnp.float32).at[token].add(out)
+
+        # The held rows are the sorted rows' head, and a rank holds few of
+        # the experts: under even routing t * k * count / outputs rows, 32 of
+        # a decode step's 1536. The grouped product works in tiles of rows,
+        # so it is given twice the expected rows (whole 128-row tiles) where
+        # the held rows fit, and all t * k rows where they do not: nothing is
+        # dropped whatever the imbalance, and every shape is static.
+        n_rows = t * top_k
+        twice = -(-2 * n_rows * count // (n_routed + n_zero))
+        fit = 128 * -(-twice // 128)
+        if fit < n_rows:
+            y = y + jax.lax.cond(jnp.sum(sizes) <= fit,
+                                 lambda: experts(fit), lambda: experts(n_rows))
+        else:
+            y = y + experts(n_rows)
+    return y.astype(u.dtype), stats

@@ -104,7 +104,8 @@ def _fn_table(engine) -> List[Dict[str, Any]]:
     KD = SDS(tuple(kd.shape), kd.dtype)
     PARAMS = spec_of(engine.model.params)
     KV = SDS(tuple(cache.kv.shape), cache.kv.dtype)
-    kv_prompt = SDS((cfg.layers, 2, engine.max_prompt, cache.kv.shape[-1]),
+    kv_prompt = SDS((cache.layers, cache.sides, engine.max_prompt,
+                     cache.row_width),
                     cache.kv.dtype)
     table = [
         dict(key="prefill", owner=engine, attr="_prefill_fn",

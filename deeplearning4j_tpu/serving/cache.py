@@ -6,12 +6,16 @@ and the reserved-but-unused tail is the memory that would have held more
 concurrent sequences. The vLLM answer, reproduced here:
 
 * KV storage is ONE device array of fixed-size **pages**
-  ``(layers, 2, num_pages + 1, page_size, heads * head_dim)`` allocated once
+  ``(layers, sides, num_pages + 1, page_size, row_width)`` allocated once
   at server start — decode steps never reallocate device memory and their
   jit signature never changes (the compile-once property
   ``tests/test_serving.py`` asserts through the RecompileLedger). This is
-  the ONE layout: a token's K (or V) of one layer is a row of ``heads *
-  head_dim`` numbers, the projection's own output, heads merged. At GPT-2's
+  the ONE layout, and its geometry is the MODEL's (``models/served.py``
+  ``CacheRows``): with two ``sides`` a token's K (or V) of one layer is a row
+  of ``heads * head_dim`` numbers, the projection's own output, heads merged;
+  with one side a token leaves ONE latent row an attention sub-layer (MLA:
+  the normalised latent, the rotated shared key, dead lanes up to whole
+  128-lane tiles). At GPT-2's
   widths a page ``(16, 768)`` fills the TPU's ``(8, 128)`` float32 and
   ``(16, 128)`` bfloat16 tiles exactly, so the device keeps the pool
   row-major, scatters update it in place and the paged kernel reads pages
@@ -66,23 +70,23 @@ class PagedKVCache:
     (host-side bookkeeping, device-side ``kv`` array threaded through the
     jitted decode step functionally)."""
 
-    def __init__(self, *, layers: int, heads: int, head_dim: int,
+    def __init__(self, *, layers: int, row_width: int, sides: int = 2,
                  page_size: int = 16, num_pages: int = 64,
                  max_slots: int = 4, max_pages_per_seq: int = 8,
                  dtype=jnp.float32):
         if page_size <= 0 or num_pages <= 0:
             raise ValueError("page_size and num_pages must be positive")
-        self.layers = layers
-        self.heads = heads
-        self.head_dim = head_dim
+        self.layers = int(layers)
+        self.sides = int(sides)
+        self.row_width = int(row_width)
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         self.max_slots = int(max_slots)
         self.max_pages_per_seq = int(max_pages_per_seq)
         self.trash_page = self.num_pages
         # +1: the trash page — see module docstring
-        self._kv_shape = (layers, 2, self.num_pages + 1, self.page_size,
-                          heads * head_dim)
+        self._kv_shape = (self.layers, self.sides, self.num_pages + 1,
+                          self.page_size, self.row_width)
         self._kv_dtype = dtype
         self.kv = jnp.zeros(self._kv_shape, self._kv_dtype)
         self.free: List[int] = list(range(self.num_pages))

@@ -1,15 +1,17 @@
 """Generative serving engine — prefill/decode dispatch over the paged cache.
 
 The device half of the serving subsystem (docs/SERVING.md): three jitted
-functions whose signatures depend ONLY on server-start configuration
+functions — built from the programs the MODEL hands over
+(``models/served.py``: the engine knows no model by name) — whose
+signatures depend ONLY on server-start configuration
 (slot capacity, page geometry, prompt bucket) — never on the number of
 active sequences — so the RecompileLedger records exactly one
 ``first_compile`` per function and NO ``new_shape`` events across
 admits/evicts (asserted in tests/test_serving.py):
 
-* **prefill** — the whole (padded) prompt through one causal
-  ``gpt_prefill`` pass + first-token sampling; returns the per-layer K/V
-  for the cache scatter. TTFT is measured across this call.
+* **prefill** — the whole (padded) prompt through one causal pass of the
+  MODEL's prefill program + first-token sampling; returns the per-layer
+  cache rows for the scatter. TTFT is measured across this call.
 * **write-prompt** — write the prefill K/V into the slot's pages, whole
   pages at a time and in place (donated pool; pages past the prompt land
   on the trash page, the tail of the prompt's last page is masked garbage
@@ -29,7 +31,7 @@ system prompts admit in O(suffix) instead of O(prompt). All four
 signatures stay config-only — prefix hits never recompile.
 
 With ``spec_k > 0`` (plus a ``draft_model``) a fifth joins —
-**verify** (``models.gpt.gpt_verify``): speculative decoding
+**verify** (the model's ``verify`` program): speculative decoding
 (docs/SERVING.md § Speculative decoding, ``serving/speculative.py``).
 Each step, greedy slots run K draft-model decode steps (one compiled
 ``draft_decode`` scan over a dense per-slot draft cache) to propose K
@@ -79,9 +81,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu import faults, observe
-from deeplearning4j_tpu.models.gpt import (
-    GptConfig, GptModel, gpt_decode_step, gpt_prefill, gpt_prefill_suffix,
-    gpt_verify)
 from deeplearning4j_tpu.ops.pallas_attention import gather_pages
 from deeplearning4j_tpu.serving.cache import PagedKVCache
 from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
@@ -93,10 +92,14 @@ from deeplearning4j_tpu.serving.scheduler import (
 
 logger = logging.getLogger(__name__)
 
+# keys split ahead of their use, while the device runs a decode step: one for
+# the next step and a few for the admissions before it
+_KEY_RESERVE = 4
+
 
 def build_write(page: int, trash: int):
-    """The jitted ``write_prompt``: a prefill's K/V rows ``(L, 2, T, H*Dh)``
-    go into the donated pool as WHOLE pages, in place. The prompt's last
+    """The jitted ``write_prompt``: a prefill's cache rows ``(L, sides, T,
+    width)`` go into the donated pool as WHOLE pages, in place. The prompt's last
     page carries the padded positions' rows past ``prompt_len`` — garbage
     that attention masks and decode overwrites; pages past the prompt go to
     the trash page. Only pages the slot owns alone are written: a full
@@ -106,10 +109,11 @@ def build_write(page: int, trash: int):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def write_prompt(kv_pages, kv_prompt, pt_row, prompt_len):
-        n_l, _, t, width = kv_prompt.shape
+        n_l, sides, t, width = kv_prompt.shape
         n = -(-t // page)
         pages = jnp.pad(kv_prompt, ((0, 0), (0, 0), (0, n * page - t),
-                                    (0, 0))).reshape(n_l, 2, n, page, width)
+                                    (0, 0))).reshape(n_l, sides, n, page,
+                                                     width)
         page_idx = jnp.where(jnp.arange(n) * page < prompt_len,
                              pt_row[:n], trash)
         return kv_pages.at[:, :, page_idx].set(pages)
@@ -117,11 +121,12 @@ def build_write(page: int, trash: int):
     return write_prompt
 
 
-def build_decode(cfg: GptConfig, page: int, trash: int):
+def build_decode(decode_step, page: int, trash: int):
     """The jitted ``decode``: one token for every slot against the donated
-    pool (:func:`gpt_decode_step`) and the sampler. Like
+    pool (the model's ``decode_step`` program) and the sampler. Like
     :func:`build_write`, a function of configuration and page geometry
-    alone."""
+    alone. The model's statistics (``None`` for a model without an expert
+    layer) ride out beside the tokens."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, kv_pages, page_table, seq_lens, tokens, active,
@@ -132,17 +137,19 @@ def build_decode(cfg: GptConfig, page: int, trash: int):
             on, page_table[jnp.arange(s_n), seq_lens // page], trash)
         write_off = seq_lens % page
         seq_incl = seq_lens + on.astype(jnp.int32)
-        kv_pages, logits = gpt_decode_step(
+        kv_pages, logits, stats = decode_step(
             params, kv_pages, tokens, seq_lens, page_table, seq_incl,
-            write_page, write_off, cfg)
+            write_page, write_off)
         toks = sample_tokens(logits, key, temp, top_k, top_p)
-        return kv_pages, toks, logits
+        return kv_pages, toks, logits, stats
 
     return decode
 
 
 class GenerativeEngine:
-    """Continuous-batching text generation over a ``GptModel``.
+    """Continuous-batching text generation over any model handle that
+    speaks the protocol of ``models/served.py`` (``GptModel``,
+    ``LongcatModel``).
 
     Synchronous use (tests, batch jobs)::
 
@@ -157,7 +164,7 @@ class GenerativeEngine:
         eng.stop()
     """
 
-    def __init__(self, model: GptModel, *, max_slots: int = 4,
+    def __init__(self, model, *, max_slots: int = 4,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  max_pages_per_seq: int = 8, max_prompt: int = 32,
                  seed: int = 0, supervise: bool = True,
@@ -168,13 +175,20 @@ class GenerativeEngine:
                  suffix_bucket: Optional[int] = None,
                  prefix_min_match: Optional[int] = None,
                  spec_k: int = 0,
-                 draft_model: Optional[GptModel] = None,
+                 draft_model=None,
                  engine_id: int = 0):
         cfg = model.cfg
-        if cfg.hidden % cfg.heads:
-            raise ValueError("hidden must be divisible by heads")
+        self.programs = model.serving_programs()
+        if prefix_pages and self.programs.prefill_suffix is None:
+            raise ValueError(
+                f"prefix_pages={prefix_pages} needs a suffix-prefill program "
+                f"and {type(model).__name__} has none (docs/SERVING.md)")
+        if spec_k and self.programs.verify is None:
+            raise ValueError(
+                f"spec_k={spec_k} needs a verify program and "
+                f"{type(model).__name__} has none (docs/SERVING.md)")
         if max_prompt > cfg.max_position:
-            # gpt_prefill's position gather would silently CLAMP indices
+            # a prefill's position gather would silently CLAMP indices
             # past max_position — reject the misconfiguration instead
             raise ValueError(
                 f"max_prompt={max_prompt} exceeds the model's "
@@ -188,9 +202,10 @@ class GenerativeEngine:
             # cache gets its page budget ON TOP so the tree never starves
             # the slot bank by default.
             num_pages = max_slots * max_pages_per_seq + max(0, prefix_pages)
+        rows = model.cache_rows()
         self.cache = PagedKVCache(
-            layers=cfg.layers, heads=cfg.heads,
-            head_dim=cfg.hidden // cfg.heads, page_size=page_size,
+            layers=rows.layers, sides=rows.sides, row_width=rows.width,
+            page_size=page_size,
             num_pages=num_pages, max_slots=max_slots,
             max_pages_per_seq=max_pages_per_seq,
             dtype=jax.tree.leaves(model.params)[0].dtype)
@@ -249,9 +264,13 @@ class GenerativeEngine:
                 max_prompt=self.max_prompt)
             self._spec_limit = min(cfg.max_position, dcfg.max_position)
         self._key = jax.random.key(seed)
-        # key-hygiene audit trail: raw key data of every key handed to a
-        # jitted sampler, bounded; tests assert no value ever repeats
-        self.key_trail: "deque[bytes]" = deque(maxlen=4096)
+        # key-hygiene audit trail: every key handed to a jitted sampler,
+        # bounded; tests assert no value ever repeats. The keys stay on the
+        # device until ``key_trail`` is read: no step waits for one
+        self._key_trail: deque = deque(maxlen=4096)
+        self._key_reserve: deque = deque()
+        # name -> (host values, their copy on the device): _resident
+        self._resident_args: dict = {}
         self._prefill_fn = None
         self._write_fn = None
         self._decode_fn = None
@@ -311,27 +330,53 @@ class GenerativeEngine:
         _aot.maybe_warm_boot(self)
 
     # ------------------------------------------------------------------ keys
-    def _next_key(self):
-        """Split a fresh subkey off the root key — the ONLY way keys leave
-        the engine, so the audit trail sees every one exactly once."""
+    def _split_key(self):
         with observe.tracer().span("serving_next_key", category="serving"):
             self._key, sub = jax.random.split(self._key)
-            self.key_trail.append(
-                np.asarray(jax.random.key_data(sub)).tobytes())
         return sub
+
+    def _next_key(self):
+        """Hand out a fresh subkey of the root key — the ONLY way keys leave
+        the engine, so the audit trail sees every one exactly once. The
+        split (three eager dispatches, 0.8 ms on the v5e's host) is made
+        ahead by :meth:`_reserve_keys` where it can be; the chain of splits,
+        and so every key and their order, is what it is one at a time."""
+        sub = (self._key_reserve.popleft() if self._key_reserve
+               else self._split_key())
+        self._key_trail.append(sub)
+        return sub
+
+    def _reserve_keys(self) -> None:
+        """Split the next keys now. Called between a decode step's launch
+        and its read: the device is busy and the host would only wait."""
+        while len(self._key_reserve) < _KEY_RESERVE:
+            self._key_reserve.append(self._split_key())
+
+    def _resident(self, name: str, host: np.ndarray):
+        """The device's copy of a host argument that seldom changes from one
+        call to the next (a full bank's active mask, the sampling settings):
+        transferred again only when its values change (0.14 ms an argument
+        a call on the v5e's host, with the device idle)."""
+        held = self._resident_args.get(name)
+        if held is None or not np.array_equal(held[0], host):
+            held = self._resident_args[name] = (host, jax.device_put(host))
+        return held[1]
+
+    @property
+    def key_trail(self) -> List[bytes]:
+        """The raw key data of the newest keys handed out, oldest first."""
+        return [np.asarray(jax.random.key_data(k)).tobytes()
+                for k in self._key_trail]
 
     # ---------------------------------------------------------- compiled fns
     def _build_prefill(self):
-        cfg = self.cfg
+        model_prefill = self.programs.prefill
 
         @jax.jit
         def prefill(params, ids, prompt_len, key, temp, top_k, top_p):
-            mask = (jnp.arange(ids.shape[1]) < prompt_len)[None, :]
-            logits, kv = gpt_prefill(params, ids, cfg,
-                                     mask=mask.astype(jnp.int32))
-            last = logits[0, prompt_len - 1][None]  # (1, V)
+            last, rows, stats = model_prefill(params, ids, prompt_len)
             tok = sample_tokens(last, key, temp, top_k, top_p)[0]
-            return kv[:, :, 0], tok  # (L, 2, T, H*Dh), scalar
+            return rows, tok, stats  # (L, sides, T, width), scalar
 
         return prefill
 
@@ -341,15 +386,16 @@ class GenerativeEngine:
     def _build_suffix(self):
         """Suffix-only prefill for prefix-cache hits: gather the cached
         prefix K/V out of the slot's pages, run the (bucketed) suffix
-        through :func:`gpt_prefill_suffix`, sample the first token from
+        through the model's ``prefill_suffix`` program, sample the first token from
         the last suffix position, and scatter the suffix K/V back into
         the pages. Shapes depend only on server config (max_prompt,
         suffix_bucket, page geometry) — ONE first_compile, zero
         new_shape, same as the other three."""
-        cfg, cache = self.cfg, self.cache
+        cache, model_suffix = self.cache, self.programs.prefill_suffix
         page, trash = cache.page_size, cache.trash_page
         t_pre = self.max_prompt
         n_pre = cache.pages_for(t_pre)
+        sides = range(cache.sides)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def suffix_prefill(params, kv_pages, ids, prefix_len, suffix_len,
@@ -359,12 +405,12 @@ class GenerativeEngine:
             # reason): (L, 2, n, page, E) -> (L, 2, Tpre, E)
             run = jnp.stack([
                 jnp.stack([gather_pages(kv_pages, li, side, pt_row[:n_pre])
-                           for side in (0, 1)])
-                for li in range(cfg.layers)])
+                           for side in sides])
+                for li in range(cache.layers)])
             prefix_kv = run.reshape(run.shape[:2] + (n_pre * page, -1))
             prefix_kv = prefix_kv[:, :, :t_pre]
-            logits, kv_suf = gpt_prefill_suffix(
-                params, ids, prefix_kv, prefix_len, suffix_len, cfg)
+            logits, kv_suf = model_suffix(
+                params, ids, prefix_kv, prefix_len, suffix_len)
             last = logits[0, suffix_len - 1][None]  # (1, V)
             tok = sample_tokens(last, key, temp, top_k, top_p)[0]
             b = ids.shape[1]
@@ -375,8 +421,8 @@ class GenerativeEngine:
             # one scatter a layer and side, the form the decode step's
             # writes take: a scatter over the leading axes too makes the
             # TPU compiler copy the whole pool into another layout and back
-            for li in range(cfg.layers):
-                for side in (0, 1):
+            for li in range(cache.layers):
+                for side in sides:
                     kv_pages = kv_pages.at[li, side, wpage, apos % page].set(
                         kv_suf[li, side])
             return kv_pages, tok
@@ -384,7 +430,7 @@ class GenerativeEngine:
         return suffix_prefill
 
     def _build_decode(self):
-        return build_decode(self.cfg, self.cache.page_size,
+        return build_decode(self.programs.decode_step, self.cache.page_size,
                             self.cache.trash_page)
 
     def _build_verify(self):
@@ -396,7 +442,7 @@ class GenerativeEngine:
         their writes land on the trash page, their outputs are ignored.
         Shapes depend only on (max_slots, spec_k, page geometry): ONE
         first_compile, zero new_shape, same as the other four."""
-        cfg, cache = self.cfg, self.cache
+        cache, model_verify = self.cache, self.programs.verify
         page, trash = cache.page_size, cache.trash_page
 
         @functools.partial(jax.jit, donate_argnums=(1,))
@@ -408,9 +454,9 @@ class GenerativeEngine:
             wpage = jnp.where(
                 on[:, None],
                 page_table[jnp.arange(s_n)[:, None], row], trash)
-            return gpt_verify(params, kv_pages, tokens, seq_lens,
-                              page_table, wpage, pos % page, cfg,
-                              page_size=page)
+            return model_verify(params, kv_pages, tokens, seq_lens,
+                                page_table, wpage, pos % page,
+                                page_size=page)
 
         return verify
 
@@ -1134,9 +1180,17 @@ class GenerativeEngine:
                 temp[slot] = st.request.temperature
                 top_k[slot] = st.request.top_k
                 top_p[slot] = st.request.top_p
-            args = (jnp.asarray(cache.page_table),
-                    jnp.asarray(cache.seq_lens),
-                    jnp.asarray(tokens), jnp.asarray(act))
+            # what changes every step goes to the jitted call as host
+            # arrays: its own transfer of an argument costs half of a
+            # ``jnp.asarray`` (0.14 against 0.3 ms on the v5e's host), and
+            # the device idles while the host prepares a step. Copies,
+            # because the cache updates its tables in place. What seldom
+            # changes stays on the device
+            args = (cache.page_table.copy(), cache.seq_lens.copy(), tokens,
+                    self._resident("active", act))
+            sampling = (self._resident("temperature", temp),
+                        self._resident("top_k", top_k),
+                        self._resident("top_p", top_p))
             observe.note_jit_signature(
                 self._decode_fn, graph="serving", key="decode",
                 signature=observe.signature_of(
@@ -1144,14 +1198,16 @@ class GenerativeEngine:
                     tokens=tokens, active=act))
         t0 = time.perf_counter()
         with tracer.span("serving_decode", category="serving",
-                         slots=len(active)):
+                         slots=len(active)) as sp:
             with tracer.span("serving_decode_launch", category="serving"):
-                cache.kv, next_toks, _logits = self._decode_fn(
-                    self.model.params, cache.kv, *args, key,
-                    jnp.asarray(temp), jnp.asarray(top_k),
-                    jnp.asarray(top_p))
+                cache.kv, next_toks, _logits, stats = self._decode_fn(
+                    self.model.params, cache.kv, *args, key, *sampling)
+            self._reserve_keys()
             with tracer.span("serving_decode_read", category="serving"):
-                next_toks = np.asarray(next_toks)
+                # tokens and statistics in ONE blocking read
+                next_toks, stats = jax.device_get((next_toks, stats))
+            if stats is not None:
+                self.programs.note_stats(stats, sp, decode_step=True)
         dt = time.perf_counter() - t0
         self._obs["decode_h"].observe(dt)
         with tracer.span("serving_commit", category="serving"):
@@ -1285,19 +1341,24 @@ class GenerativeEngine:
             signature=observe.signature_of(ids=ids))
         tracer = observe.tracer()
         with tracer.span("serving_prefill", category="serving",
-                         prompt_len=p_len, request=req.request_id):
+                         prompt_len=p_len, request=req.request_id) as sp:
             with tracer.span("serving_prefill_launch", category="serving"):
-                kv_prompt, tok = self._prefill_fn(
-                    self.model.params, jnp.asarray(ids),
-                    jnp.asarray(p_len, jnp.int32), key,
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.top_k], jnp.int32),
-                    jnp.asarray([req.top_p], jnp.float32))
+                kv_prompt, tok, stats = self._prefill_fn(
+                    self.model.params, ids, np.int32(p_len), key,
+                    self._resident("prefill_temperature", np.asarray(
+                        [req.temperature], np.float32)),
+                    self._resident("prefill_top_k", np.asarray(
+                        [req.top_k], np.int32)),
+                    self._resident("prefill_top_p", np.asarray(
+                        [req.top_p], np.float32)))
                 cache.kv = self._write_fn(
-                    cache.kv, kv_prompt, jnp.asarray(cache.page_table[slot]),
-                    jnp.asarray(p_len, jnp.int32))
+                    cache.kv, kv_prompt, cache.page_table[slot].copy(),
+                    np.int32(p_len))
             with tracer.span("serving_prefill_read", category="serving"):
+                tok, stats = jax.device_get((tok, stats))
                 tok = int(tok)
+            if stats is not None:
+                self.programs.note_stats(stats, sp)
         return tok
 
     def _prefill_suffix_into(self, slot: int, req: GenerationRequest,

@@ -59,8 +59,7 @@ def assert_oracle(prompt, res, n=None):
 class TestRefcountAllocator:
     def make_cache(self, **kw):
         kw.setdefault("layers", 2)
-        kw.setdefault("heads", 2)
-        kw.setdefault("head_dim", 8)
+        kw.setdefault("row_width", 16)
         kw.setdefault("page_size", 4)
         kw.setdefault("num_pages", 8)
         kw.setdefault("max_slots", 3)
@@ -185,7 +184,7 @@ class TestRefcountAllocator:
 
 class TestRadixTree:
     def setup_tree(self, max_pages=8, num_pages=24):
-        cache = PagedKVCache(layers=1, heads=1, head_dim=8, page_size=4,
+        cache = PagedKVCache(layers=1, row_width=8, page_size=4,
                              num_pages=num_pages, max_slots=2,
                              max_pages_per_seq=6)
         return cache, RadixPrefixCache(cache, max_pages=max_pages)

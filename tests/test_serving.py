@@ -59,8 +59,7 @@ PROMPTS = [np.array([3, 5, 7, 9], np.int32),
 class TestPagedKVCache:
     def make_cache(self, **kw):
         kw.setdefault("layers", 2)
-        kw.setdefault("heads", 2)
-        kw.setdefault("head_dim", 8)
+        kw.setdefault("row_width", 16)
         kw.setdefault("page_size", 4)
         kw.setdefault("num_pages", 8)
         kw.setdefault("max_slots", 3)
@@ -549,6 +548,28 @@ class TestPrngHygiene:
         assert len(trail) >= len(PROMPTS) + 5
         assert len(set(trail)) == len(trail), (
             "a PRNG key value was issued twice across the scheduler loop")
+
+    def test_keys_split_ahead_are_the_chain_one_at_a_time(self):
+        """The engine splits keys while the device runs a step; what it
+        hands out is still split(split(...)) of the seed, in order."""
+        eng = make_engine(max_slots=2, seed=11)
+        eng.generate(PROMPTS, max_new_tokens=5)
+        assert eng._key_reserve, "no key was split ahead"
+        key, want = jax.random.key(11), []
+        for _ in eng.key_trail:
+            key, sub = jax.random.split(key)
+            want.append(np.asarray(jax.random.key_data(sub)).tobytes())
+        assert eng.key_trail == want
+
+    def test_an_unchanged_argument_is_sent_once(self):
+        eng = make_engine(max_slots=2)
+        eng.generate(PROMPTS[:2], max_new_tokens=4)
+        held = eng._resident_args["temperature"][1]
+        same = eng._resident("temperature", np.zeros((2,), np.float32))
+        assert same is held
+        other = eng._resident("temperature", np.ones((2,), np.float32))
+        assert other is not held
+        np.testing.assert_array_equal(np.asarray(other), np.ones((2,)))
 
     def test_sampling_differs_across_steps(self):
         """Same slot, same logits landscape, successive steps: sampled

@@ -16,6 +16,8 @@ file in every worker, so nothing here touches ``topologies`` at import.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +74,8 @@ def _pool_programs(one_chip, monkeypatch, dtype):
     TPU branch here, in the test: the process still sees the CPU."""
     import importlib
 
-    from deeplearning4j_tpu.models.gpt import GptConfig, init_gpt_params
+    from deeplearning4j_tpu.models.gpt import (
+        GptConfig, gpt_programs, init_gpt_params)
     from deeplearning4j_tpu.ops import tuning
     from deeplearning4j_tpu.serving.cache import PagedKVCache
     from deeplearning4j_tpu.serving.engine import build_decode, build_write
@@ -95,7 +98,8 @@ def _pool_programs(one_chip, monkeypatch, dtype):
     key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
     slot = lambda dt: sds((SLOTS,), dt)  # noqa: E731
     programs = {
-        "decode": build_decode(cfg, PAGE, N_PAGES).lower(
+        "decode": build_decode(gpt_programs(cfg).decode_step, PAGE,
+                               N_PAGES).lower(
             params, pool, sds((SLOTS, PAGES_PER_SEQ), i32), slot(i32),
             slot(i32), slot(i32), key, slot(f32), slot(i32), slot(f32)),
         "write_prompt": build_write(PAGE, N_PAGES).lower(
@@ -107,47 +111,113 @@ def _pool_programs(one_chip, monkeypatch, dtype):
     return pool, {k: v.compile() for k, v in programs.items()}
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_serving_programs_update_the_pool_in_place(one_chip, monkeypatch,
-                                                   dtype):
-    """No program that takes the KV pool copies it, re-lays it out or slices
-    a layer out of it (PERF.md, PR 26: two such copies in ``decode``, two in
-    ``write_prompt`` and twelve layer slices were 49 of a 70 ms decode step).
-    Held on the compiled text: every instruction whose result has the pool's
-    element count is the pool's parameter, an in-place scatter or
-    dynamic-update-slice (or the fusion around one), in the pool's row-major
-    layout; nothing has a layer's ``[2081,16,...]`` shape; and the program's
-    temporaries stay under a tenth of the pool."""
-    import math
-    import re
-
-    pool, compiled = _pool_programs(one_chip, monkeypatch, dtype)
+def _assert_pool_in_place(pool, compiled, n_pages):
+    """Every instruction whose result has the pool's element count is the
+    pool's parameter, an in-place scatter or dynamic-update-slice (or the
+    fusion around one), in the pool's row-major layout; nothing has one
+    layer's ``[pages + 1, page, ...]`` shape; the program's temporaries stay
+    under a tenth of the pool."""
     n_pool = math.prod(pool.shape)
-    pool_bytes = n_pool * jnp.dtype(dtype).itemsize
-    row_major = "{4,3,2,1,0:"
+    pool_bytes = n_pool * jnp.dtype(pool.dtype).itemsize
     in_place = ("parameter", "scatter", "dynamic-update-slice", "fusion",
                 "get-tuple-element", "tuple", "bitcast")
     result = re.compile(
         r"^\s*(?:ROOT )?%[\w.\-]+ = (\(?)\w+\[([\d,]*)\](\{[^ ]*)? "
         r"([\w\-]+)\(")
     for name, c in compiled.items():
-        text = c.as_text()
-        for line in text.splitlines():
+        for line in c.as_text().splitlines():
             m = result.match(line)
             if not m or m.group(1):
                 continue
             dims = [int(d) for d in m.group(2).split(",") if d]
-            assert dims[:2] != [N_PAGES + 1, PAGE], (name, line[:200])
+            assert dims[:2] != [n_pages + 1, PAGE], (name, line[:200])
             if math.prod(dims) != n_pool:
                 continue
             assert m.group(4) in in_place, (name, line[:200])
+            # row-major: the minor-to-major list counts down to 0
+            row_major = "{" + ",".join(
+                str(i) for i in reversed(range(len(dims)))) + ":"
             assert (m.group(3) or "").startswith(row_major), (name,
                                                               line[:200])
         temp = c.memory_analysis().temp_size_in_bytes
         assert temp < pool_bytes / 10, (name, temp)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_serving_programs_update_the_pool_in_place(one_chip, monkeypatch,
+                                                   dtype):
+    """No program that takes the KV pool copies it, re-lays it out or slices
+    a layer out of it (PERF.md, PR 26: two such copies in ``decode``, two in
+    ``write_prompt`` and twelve layer slices were 49 of a 70 ms decode step).
+    Held on the compiled text (``_assert_pool_in_place``)."""
+    pool, compiled = _pool_programs(one_chip, monkeypatch, dtype)
+    _assert_pool_in_place(pool, compiled, N_PAGES)
     decode = compiled["decode"].as_text()
     assert decode.count('custom_call_target="tpu_custom_call"') == 12
     assert decode.count("scatter(") >= 24
+
+
+# The latent cell's geometry (benchmarks/traffic/reason-closed.json):
+# LongCat-Flash at its published widths, 4 layers, 16 held experts, 128
+# slots x 96 pages of 16, prompts bucketed to 512.
+LC_SLOTS, LC_PAGES_PER_SEQ, LC_MAX_PROMPT = 128, 96, 512
+LC_N_PAGES = LC_SLOTS * LC_PAGES_PER_SEQ
+
+
+def test_latent_serving_programs_update_the_pool_in_place(one_chip,
+                                                          monkeypatch):
+    """LongCat-Flash's ``decode`` and ``write_prompt`` from shapes alone
+    (10.35 GB of weights and a 2.0 GB latent pool that this host never
+    makes): the pool of 640-lane rows (512 latent | 64 rotary key | 64 dead)
+    stays row-major and is updated in place, nothing pool-sized is made, the
+    latent kernel is there once an attention sub-layer under its own name,
+    and the programs fit the chip beside the weights."""
+    import importlib
+
+    from deeplearning4j_tpu.models.longcat import (
+        LongcatConfig, init_longcat_params, longcat_cache_rows,
+        longcat_programs)
+    from deeplearning4j_tpu.ops import tuning
+    from deeplearning4j_tpu.serving.engine import build_decode, build_write
+
+    registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(tuning, "current_device_kind", lambda: "tpu_v5_lite")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cfg = LongcatConfig(num_layers=4, vocab_size=16384, held_experts=(0, 16))
+    rows = longcat_cache_rows(cfg)
+    assert rows == (8, 1, 640)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    pool = sds((rows.layers, rows.sides, LC_N_PAGES + 1, PAGE, rows.width),
+               bf16)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_longcat_params(jax.random.key(0), cfg,
+                                                   bf16)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    slot = lambda dt: sds((LC_SLOTS,), dt)  # noqa: E731
+    compiled = {
+        "decode": build_decode(longcat_programs(cfg).decode_step, PAGE,
+                               LC_N_PAGES).lower(
+            params, pool, sds((LC_SLOTS, LC_PAGES_PER_SEQ), i32), slot(i32),
+            slot(i32), slot(i32), key, slot(f32), slot(i32),
+            slot(f32)).compile(),
+        "write_prompt": build_write(PAGE, LC_N_PAGES).lower(
+            pool, sds((rows.layers, rows.sides, LC_MAX_PROMPT, rows.width),
+                      bf16),
+            sds((LC_PAGES_PER_SEQ,), i32), sds((), i32)).compile(),
+    }
+    _assert_pool_in_place(pool, compiled, LC_N_PAGES)
+    decode = compiled["decode"].as_text()
+    assert len(re.findall(r"%latent_decode_attention[.\d]* = .*"
+                          r'custom_call_target="tpu_custom_call"',
+                          decode)) == rows.layers
+    mem = compiled["decode"].memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
 
 
 @pytest.mark.parametrize("bh,t,causal,rate", [
