@@ -20,7 +20,9 @@ admits/evicts (asserted in tests/test_serving.py):
   they write to the trash page and their outputs are ignored), paged
   attention via the registry's ``paged_decode_attention``, then the
   vectorized temperature/top-k/top-p sampler with per-slot keys split from
-  this step's fresh key.
+  this step's fresh key; inside the one program it runs only the body the
+  bank's knobs ask for (``serving/sampling.py``: the argmax alone while no
+  slot samples).
 
 With ``prefix_pages > 0`` a fourth compiled function joins them —
 **suffix-prefill**: on a radix-prefix-cache hit (``serving/prefix.py``)
@@ -85,7 +87,8 @@ from deeplearning4j_tpu.ops.pallas_attention import gather_pages
 from deeplearning4j_tpu.serving.cache import PagedKVCache
 from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
 from deeplearning4j_tpu.serving.speculative import SpeculativeDecoder
-from deeplearning4j_tpu.serving.sampling import sample_tokens
+from deeplearning4j_tpu.serving.sampling import (
+    SAMPLER_PATHS, sample_tokens, sampler_path)
 from deeplearning4j_tpu.serving.scheduler import (
     GenerationRequest, GenerationResult, SlotScheduler, count_terminal,
     note_terminal)
@@ -119,6 +122,20 @@ def build_write(page: int, trash: int):
         return kv_pages.at[:, :, page_idx].set(pages)
 
     return write_prompt
+
+
+def build_prefill(model_prefill):
+    """The jitted ``prefill``: the model's ``prefill`` program over one
+    padded prompt, and the sampler on its last position's logits. Needs no
+    engine, like :func:`build_write`."""
+
+    @jax.jit
+    def prefill(params, ids, prompt_len, key, temp, top_k, top_p):
+        last, rows, stats = model_prefill(params, ids, prompt_len)
+        tok = sample_tokens(last, key, temp, top_k, top_p)[0]
+        return rows, tok, stats  # (L, sides, T, width), scalar
+
+    return prefill
 
 
 def build_decode(decode_step, page: int, trash: int):
@@ -271,6 +288,10 @@ class GenerativeEngine:
         self._key_reserve: deque = deque()
         # name -> (host values, their copy on the device): _resident
         self._resident_args: dict = {}
+        # (the decode bank's sampling settings on the device, the sampler
+        # body they ask for): worked out again only when _resident has sent
+        # one of the three anew
+        self._sampler: tuple = ((None,) * 3, SAMPLER_PATHS[0])
         self._prefill_fn = None
         self._write_fn = None
         self._decode_fn = None
@@ -316,6 +337,9 @@ class GenerativeEngine:
                 "dl4j_tpu_serving_queue_wait_seconds"),
             "restarts": m.counter("dl4j_tpu_serving_engine_restarts_total"),
             "retries": m.counter("dl4j_tpu_serving_retries_total"),
+            "sampler": {path: m.counter(
+                "dl4j_tpu_serving_sampler_steps_total", path=path)
+                for path in SAMPLER_PATHS},
             # written ONLY by stop(): the gauge is process-global, and a
             # constructor write here would clobber a previous engine's
             # hung-stop indication while that engine is still wedged
@@ -370,15 +394,7 @@ class GenerativeEngine:
 
     # ---------------------------------------------------------- compiled fns
     def _build_prefill(self):
-        model_prefill = self.programs.prefill
-
-        @jax.jit
-        def prefill(params, ids, prompt_len, key, temp, top_k, top_p):
-            last, rows, stats = model_prefill(params, ids, prompt_len)
-            tok = sample_tokens(last, key, temp, top_k, top_p)[0]
-            return rows, tok, stats  # (L, sides, T, width), scalar
-
-        return prefill
+        return build_prefill(self.programs.prefill)
 
     def _build_write(self):
         return build_write(self.cache.page_size, self.cache.trash_page)
@@ -1191,6 +1207,10 @@ class GenerativeEngine:
             sampling = (self._resident("temperature", temp),
                         self._resident("top_k", top_k),
                         self._resident("top_p", top_p))
+            if any(a is not b for a, b in zip(sampling, self._sampler[0])):
+                # the device's own predicate, on the values it was sent
+                self._sampler = (sampling, SAMPLER_PATHS[
+                    int(sampler_path(temp, top_k, top_p))])
             observe.note_jit_signature(
                 self._decode_fn, graph="serving", key="decode",
                 signature=observe.signature_of(
@@ -1198,7 +1218,7 @@ class GenerativeEngine:
                     tokens=tokens, active=act))
         t0 = time.perf_counter()
         with tracer.span("serving_decode", category="serving",
-                         slots=len(active)) as sp:
+                         slots=len(active), sampler=self._sampler[1]) as sp:
             with tracer.span("serving_decode_launch", category="serving"):
                 cache.kv, next_toks, _logits, stats = self._decode_fn(
                     self.model.params, cache.kv, *args, key, *sampling)
@@ -1210,6 +1230,7 @@ class GenerativeEngine:
                 self.programs.note_stats(stats, sp, decode_step=True)
         dt = time.perf_counter() - t0
         self._obs["decode_h"].observe(dt)
+        self._obs["sampler"][self._sampler[1]].inc()
         with tracer.span("serving_commit", category="serving"):
             now = time.perf_counter()
             for slot in active:

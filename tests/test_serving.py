@@ -29,7 +29,8 @@ from deeplearning4j_tpu.ops.pallas_attention import (
 )
 from deeplearning4j_tpu.ops.registry import registry
 from deeplearning4j_tpu.serving import GenerativeEngine, PagedKVCache
-from deeplearning4j_tpu.serving.sampling import sample_tokens
+from deeplearning4j_tpu.serving.sampling import (
+    SAMPLER_PATHS, sample_tokens, sampler_path)
 
 CFG = GptConfig.tiny()
 MODEL = GptModel(CFG, seed=1)
@@ -120,6 +121,55 @@ class TestPagedKVCache:
 # ---------------------------------------------------------------------------
 
 
+def _oracle(logits, step_key, temperature, top_k, top_p):
+    """The sampler as it was before it chose a body (PR 28): everything
+    computed for every slot, the result picked by ``where``. With the one
+    repair the filter body has: a row with ``top_p >= 1`` takes no cut."""
+    s_n, vocab = logits.shape
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    k = jnp.clip(jnp.where(top_k > 0, top_k, vocab), 1, vocab)
+    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(desc, (k - 1)[:, None], axis=-1)
+    masked = jnp.where(scaled >= kth, scaled, -jnp.inf)
+    probs = jax.nn.softmax(masked, axis=-1)
+    sp = jnp.sort(probs, axis=-1)[:, ::-1]
+    cum = jnp.cumsum(sp, axis=-1)
+    keep_sorted = ((cum - sp) < top_p[:, None]) | (top_p >= 1.0)[:, None]
+    cutoff = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
+                     keepdims=True)
+    masked = jnp.where(probs >= cutoff, masked, -jnp.inf)
+    keys = jax.random.split(step_key, s_n)
+    sampled = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+_ORACLE = jax.jit(_oracle)
+_SAMPLE = jax.jit(sample_tokens)
+
+# a bank by its rows: g(reedy), p(lain: temperature alone), f(ilter: top-k
+# and/or top-p)
+_BANKS = {"all_greedy": "gggggg", "all_plain": "pppppp",
+          "all_filter": "ffffff", "greedy_plain": "gpgpgp",
+          "greedy_filter": "gfgfgf", "plain_filter": "pfpfpf",
+          "all_three": "gpfgpf"}
+
+
+def _knobs(rows):
+    """(temperature, top_k, top_p) for a bank given as a string of g/p/f;
+    the filter rows go through top-k alone, top-p alone and both."""
+    temp, top_k, top_p = [], [], []
+    filters = [(5, 1.0), (0, 0.7), (9, 0.9)]
+    for i, kind in enumerate(rows):
+        k, p = filters[i % 3] if kind == "f" else (0, 1.0)
+        temp.append(0.0 if kind == "g" else 0.7 + 0.2 * i)
+        top_k.append(k)
+        top_p.append(p)
+    return (np.asarray(temp, np.float32), np.asarray(top_k, np.int32),
+            np.asarray(top_p, np.float32))
+
+
 class TestSampling:
     def logits(self, s=4, v=32, seed=0):
         return jnp.asarray(np.random.RandomState(seed).randn(s, v)
@@ -176,6 +226,74 @@ class TestSampling:
                                         jnp.ones(4)))
         greedy = np.asarray(jnp.argmax(lg, -1))
         assert toks[0] == greedy[0] and toks[2] == greedy[2]
+
+    @pytest.mark.parametrize("seed", range(5))
+    @pytest.mark.parametrize("vocab", [32, 50257])
+    @pytest.mark.parametrize("bank", sorted(_BANKS))
+    def test_tokens_equal_the_oracles(self, bank, vocab, seed):
+        """Whichever body the bank's knobs choose, every slot's token is
+        the one the sampler that computes everything gives it."""
+        temp, top_k, top_p = _knobs(_BANKS[bank])
+        path = SAMPLER_PATHS[int(sampler_path(temp, top_k, top_p))]
+        assert path == ("filter" if "f" in _BANKS[bank] else
+                        "plain" if "p" in _BANKS[bank] else "greedy")
+        lg = self.logits(s=len(temp), v=vocab, seed=seed)
+        args = (lg, jax.random.key(100 + seed), jnp.asarray(temp),
+                jnp.asarray(top_k), jnp.asarray(top_p))
+        np.testing.assert_array_equal(np.asarray(_SAMPLE(*args)),
+                                      np.asarray(_ORACLE(*args)))
+
+    @pytest.mark.parametrize("row", ["g", "p", "f"])
+    def test_a_slots_token_does_not_depend_on_its_neighbours(self, row):
+        """One row (logits, key position, knobs) in a bank of greedy rows
+        and beside a top-k/top-p row: the same token, though the bank takes
+        another body."""
+        lg = self.logits(s=4, v=50257, seed=7)
+        alone, beside = _knobs(row + "ggg"), _knobs(row + "gfg")
+        assert (int(sampler_path(*alone)) != int(sampler_path(*beside))
+                or row == "f")
+        for seed in range(5):
+            key = jax.random.key(40 + seed)
+            a = np.asarray(_SAMPLE(lg, key, *map(jnp.asarray, alone)))
+            b = np.asarray(_SAMPLE(lg, key, *map(jnp.asarray, beside)))
+            assert a[0] == b[0]
+            greedy = np.asarray(jnp.argmax(lg, -1))
+            assert (a[[1, 3]] == greedy[[1, 3]]).all()
+            assert (b[[1, 3]] == greedy[[1, 3]]).all()
+
+    def test_top_p_one_keeps_the_tail(self):
+        """A row of 50257 near-uniform logits that asked for no nucleus cut
+        (``top_p = 1``) can draw ids from the last sorted thousandth, where
+        a float32 cumulative sum that rounds past 1 would have cut it. The
+        filter body is forced by a top-k row beside it; the keys are those
+        whose unfiltered draw lands in that tail (one in a thousand)."""
+        v = 50257
+        rng = np.random.RandomState(11)
+        lg = jnp.asarray(np.stack([rng.uniform(0.0, 1e-3, v),
+                                   rng.randn(v)]).astype(np.float32))
+        temp = jnp.asarray([1.0, 1.0], jnp.float32)
+        top_k = jnp.asarray([0, 5], jnp.int32)
+        top_p = jnp.asarray([1.0, 1.0], jnp.float32)
+        assert SAMPLER_PATHS[int(sampler_path(temp, top_k, top_p))] == \
+            "filter"
+        tail = np.argsort(-np.asarray(lg[0]), kind="stable")[-(v // 1000):]
+
+        @jax.jit
+        def unfiltered(seeds):  # row 0's draw under each seed's step key
+            return jax.vmap(lambda seed: jax.random.categorical(
+                jax.random.split(jax.random.key(seed), 2)[0], lg[0]))(seeds)
+
+        hits = []
+        for start in range(0, 8000, 1000):
+            toks = np.asarray(unfiltered(jnp.arange(start, start + 1000)))
+            hits += [(start + int(i), int(toks[i]))
+                     for i in np.flatnonzero(np.isin(toks, tail))]
+            if len(hits) >= 2:
+                break
+        assert len(hits) >= 2
+        for seed, tok in hits:
+            got = _SAMPLE(lg, jax.random.key(seed), temp, top_k, top_p)
+            assert int(got[0]) == tok
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ from deeplearning4j_tpu.models.bert import BertConfig, BertModel
 from deeplearning4j_tpu.models.gpt import GptConfig, GptModel
 from deeplearning4j_tpu.observe.tracing import SpanTracer
 from deeplearning4j_tpu.serving import GenerativeEngine
+from deeplearning4j_tpu.serving.sampling import SAMPLER_PATHS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODEL = GptModel(GptConfig.tiny(), seed=1)
@@ -167,6 +168,12 @@ STAGES = ("serving_schedule", "serving_admit", "serving_next_key",
           "serving_decode_read", "serving_commit")
 
 
+def sampler_steps():
+    m = observe.metrics()
+    return {p: m.counter("dl4j_tpu_serving_sampler_steps_total",
+                         path=p).value for p in SAMPLER_PATHS}
+
+
 @pytest.fixture
 def served():
     eng = make_engine()
@@ -209,6 +216,39 @@ class TestServingStageSpans:
         assert sorted(e["args"]["prompt_len"]
                       for e in spans("serving_prefill")) == sorted(
             p.size for p in PROMPTS)
+
+    def test_greedy_requests_count_only_the_greedy_sampler(self, served):
+        decodes = spans("serving_decode")
+        assert decodes
+        assert {e["args"]["sampler"] for e in decodes} == {"greedy"}
+        assert sampler_steps() == {"greedy": len(decodes), "plain": 0,
+                                   "filter": 0}
+
+    @pytest.mark.parametrize("knobs,path", [
+        ({"temperature": 0.8}, "plain"),
+        ({"temperature": 0.8, "top_k": 3}, "filter"),
+        ({"temperature": 0.8, "top_p": 0.9}, "filter"),
+    ])
+    def test_a_sampled_request_moves_the_steps_it_is_active_in(self, knobs,
+                                                               path):
+        """One sampled request beside a greedy one: the decode steps it is
+        active in (a token each after its prefill's) take the body its
+        knobs ask for, the others stay greedy, and the counter agrees with
+        the spans."""
+        eng = make_engine()
+        greedy = eng.submit(PROMPTS[0], max_new_tokens=8)
+        sampled = eng.submit(PROMPTS[1], max_new_tokens=3, **knobs)
+        while eng.scheduler.has_work():
+            eng.step()
+        assert len(greedy.result().tokens) > len(sampled.result().tokens)
+        by_path = {p: 0 for p in SAMPLER_PATHS}
+        for e in spans("serving_decode"):
+            by_path[e["args"]["sampler"]] += 1
+        active = len(sampled.result().tokens) - 1
+        assert active >= 1
+        assert by_path[path] == active
+        assert by_path["greedy"] == sum(by_path.values()) - active >= 1
+        assert sampler_steps() == by_path
 
     def test_one_request_shares_its_id_and_its_ttft_adds_up(self, served):
         _eng, _futs, results = served

@@ -67,18 +67,29 @@ SLOTS, PAGE, PAGES_PER_SEQ, MAX_PROMPT = 32, 16, 65, 896
 N_PAGES = SLOTS * PAGES_PER_SEQ
 
 
+# what one test compiled, for the next one that reads the same programs
+_COMPILED: dict = {}
+
+
 def _pool_programs(one_chip, monkeypatch, dtype):
     """The engine's own ``decode`` and ``write_prompt`` (and the cache's
     ``copy_page``) compiled from shapes alone: neither the pool (2.45 GB in
     float32) nor the weights exist on this host. Dispatch is steered to its
-    TPU branch here, in the test: the process still sees the CPU."""
+    TPU branch here, in the test: the process still sees the CPU. Returns
+    the pool, the programs that take it, and ``prefill`` lowered (whoever
+    reads it compiles it)."""
     import importlib
+
+    cached = ("gpt", jnp.dtype(dtype).name)
+    if cached in _COMPILED:
+        return _COMPILED[cached]
 
     from deeplearning4j_tpu.models.gpt import (
         GptConfig, gpt_programs, init_gpt_params)
     from deeplearning4j_tpu.ops import tuning
     from deeplearning4j_tpu.serving.cache import PagedKVCache
-    from deeplearning4j_tpu.serving.engine import build_decode, build_write
+    from deeplearning4j_tpu.serving.engine import (
+        build_decode, build_prefill, build_write)
 
     registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
     monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
@@ -108,7 +119,12 @@ def _pool_programs(one_chip, monkeypatch, dtype):
         "copy_page": PagedKVCache._build_copy(None).lower(
             pool, sds((), i32), sds((), i32)),
     }
-    return pool, {k: v.compile() for k, v in programs.items()}
+    prefill = build_prefill(gpt_programs(cfg).prefill).lower(
+        params, sds((1, MAX_PROMPT), i32), sds((), i32), key, sds((1,), f32),
+        sds((1,), i32), sds((1,), f32))
+    _COMPILED[cached] = (pool, {k: v.compile() for k, v in programs.items()},
+                         prefill)
+    return _COMPILED[cached]
 
 
 def _assert_pool_in_place(pool, compiled, n_pages):
@@ -150,7 +166,7 @@ def test_serving_programs_update_the_pool_in_place(one_chip, monkeypatch,
     a layer out of it (PERF.md, PR 26: two such copies in ``decode``, two in
     ``write_prompt`` and twelve layer slices were 49 of a 70 ms decode step).
     Held on the compiled text (``_assert_pool_in_place``)."""
-    pool, compiled = _pool_programs(one_chip, monkeypatch, dtype)
+    pool, compiled, _prefill = _pool_programs(one_chip, monkeypatch, dtype)
     _assert_pool_in_place(pool, compiled, N_PAGES)
     decode = compiled["decode"].as_text()
     assert decode.count('custom_call_target="tpu_custom_call"') == 12
@@ -164,21 +180,21 @@ LC_SLOTS, LC_PAGES_PER_SEQ, LC_MAX_PROMPT = 128, 96, 512
 LC_N_PAGES = LC_SLOTS * LC_PAGES_PER_SEQ
 
 
-def test_latent_serving_programs_update_the_pool_in_place(one_chip,
-                                                          monkeypatch):
-    """LongCat-Flash's ``decode`` and ``write_prompt`` from shapes alone
-    (10.35 GB of weights and a 2.0 GB latent pool that this host never
-    makes): the pool of 640-lane rows (512 latent | 64 rotary key | 64 dead)
-    stays row-major and is updated in place, nothing pool-sized is made, the
-    latent kernel is there once an attention sub-layer under its own name,
-    and the programs fit the chip beside the weights."""
+def _latent_programs(one_chip, monkeypatch):
+    """LongCat-Flash's ``decode``, ``write_prompt`` and ``prefill`` from
+    shapes alone (10.35 GB of weights and a 2.0 GB latent pool that this
+    host never makes), as :func:`_pool_programs` returns GPT-2's."""
     import importlib
+
+    if "latent" in _COMPILED:
+        return _COMPILED["latent"]
 
     from deeplearning4j_tpu.models.longcat import (
         LongcatConfig, init_longcat_params, longcat_cache_rows,
         longcat_programs)
     from deeplearning4j_tpu.ops import tuning
-    from deeplearning4j_tpu.serving.engine import build_decode, build_write
+    from deeplearning4j_tpu.serving.engine import (
+        build_decode, build_prefill, build_write)
 
     registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
     monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
@@ -211,13 +227,85 @@ def test_latent_serving_programs_update_the_pool_in_place(one_chip,
                       bf16),
             sds((LC_PAGES_PER_SEQ,), i32), sds((), i32)).compile(),
     }
+    prefill = build_prefill(longcat_programs(cfg).prefill).lower(
+        params, sds((1, LC_MAX_PROMPT), i32), sds((), i32), key,
+        sds((1,), f32), sds((1,), i32), sds((1,), f32))
+    _COMPILED["latent"] = (pool, compiled, prefill)
+    return _COMPILED["latent"]
+
+
+def test_latent_serving_programs_update_the_pool_in_place(one_chip,
+                                                          monkeypatch):
+    """LongCat-Flash's ``decode`` and ``write_prompt``: the pool of 640-lane
+    rows (512 latent | 64 rotary key | 64 dead) stays row-major and is
+    updated in place, nothing pool-sized is made, the latent kernel is there
+    once an attention sub-layer under its own name, and the programs fit the
+    chip beside the weights."""
+    pool, compiled, _prefill = _latent_programs(one_chip, monkeypatch)
     _assert_pool_in_place(pool, compiled, LC_N_PAGES)
     decode = compiled["decode"].as_text()
     assert len(re.findall(r"%latent_decode_attention[.\d]* = .*"
                           r'custom_call_target="tpu_custom_call"',
-                          decode)) == rows.layers
+                          decode)) == pool.shape[0]
     mem = compiled["decode"].memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
+
+
+def _unconditional(text):
+    """The lines of a compiled module that run whenever the program does:
+    the entry computation and every computation reached from it other than
+    as a ``conditional``'s branch."""
+    bodies, name, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(2)
+            bodies[name] = []
+            if head.group(1):
+                entry = name
+        elif name is not None:
+            bodies[name].append(line)
+    called = re.compile(r"\b(\w+)=\{?(%[\w.\-]+(?:, *%[\w.\-]+)*)\}?")
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in bodies[comp]:
+            for how, names in called.findall(line):
+                if how not in ("branch_computations", "true_computation",
+                               "false_computation"):
+                    todo += [n.strip(" %") for n in names.split(",")
+                             if n.strip(" %") in bodies]
+    return [line for comp in seen for line in bodies[comp]]
+
+
+@pytest.mark.parametrize("variant,vocab", [("gpt", 50257),
+                                           ("latent", 16384)])
+def test_the_samplers_sorts_stand_inside_a_conditional(one_chip, monkeypatch,
+                                                       variant, vocab):
+    """``decode`` and ``prefill`` sort the vocabulary (top-k, top-p) only in
+    a branch of the sampler's conditional, which greedy traffic does not
+    take: two such sorts, run whatever the knobs, were 3.7 of the 7.9 ms of
+    GPT-2's decode program and the largest thing the device did (PERF.md,
+    PR 28)."""
+    if variant == "gpt":
+        _pool, compiled, prefill = _pool_programs(one_chip, monkeypatch,
+                                                  jnp.float32)
+    else:
+        _pool, compiled, prefill = _latent_programs(one_chip, monkeypatch)
+    vocab_sort = re.compile(r"= \(?\w+\[\d+,%d\]\S* .*\bsort\(" % vocab)
+    for name, program in (("decode", compiled["decode"]),
+                          ("prefill", prefill.compile())):
+        text = program.as_text()
+        # the filter body's two, wherever they stand
+        assert len([ln for ln in text.splitlines()
+                    if vocab_sort.search(ln)]) == 2, name
+        always = _unconditional(text)
+        for ln in always:
+            assert not vocab_sort.search(ln), (name, ln[:200])
+        assert any(" conditional(" in ln for ln in always), name
 
 
 @pytest.mark.parametrize("bh,t,causal,rate", [
