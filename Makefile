@@ -1,6 +1,6 @@
 # Convenience targets (the CI-role entry points — SURVEY §3.4).
 
-.PHONY: test gate gate-fast bench native native-test lint lint-baseline shape-lint life-lint check check-baseline obs-smoke tune-smoke tune chaos-smoke train-chaos-smoke cluster-chaos-smoke slo-smoke prefix-smoke spec-smoke aot-smoke locktrace-smoke shapetrace-smoke lifetrace-smoke
+.PHONY: test gate gate-fast native native-test lint lint-baseline shape-lint life-lint check check-baseline obs-smoke tune-smoke tune chaos-smoke train-chaos-smoke cluster-chaos-smoke aot-smoke locktrace-smoke shapetrace-smoke lifetrace-smoke
 
 # graftlint: JAX-footgun static analysis (docs/LINT.md). Fails only on
 # findings NOT grandfathered in lint_baseline.json. JAX_PLATFORMS=cpu so
@@ -89,21 +89,12 @@ train-chaos-smoke:
 cluster-chaos-smoke:
 	JAX_PLATFORMS=cpu python tools/chaos.py --json --leg cluster
 
-# SLO smoke (docs/SERVING.md § SLO admission frontend): the goodput-
-# under-overload ramp, frontend on vs off with an identical offered
-# schedule — fails unless frontend-on goodput >= frontend-off, every
-# request reaches a terminal state on both legs, the degradation ladder
-# actually engaged, and zero new_shape ledger events were paid for it.
-# ONE JSON line like lint/check/obs/chaos.
-slo-smoke:
-	JAX_PLATFORMS=cpu python tools/slo.py --json
-
 # locktrace smoke (docs/LINT.md § graftlock): runtime shadow-lock
 # cross-validation of the static lock-order graph — fails unless the
 # static graph is acyclic, every lock-order edge observed under the
 # threaded serving + checkpoint workload is inside its transitive
 # closure, and the combined graph stays acyclic.
-# ONE JSON line like lint/check/obs/chaos/slo.
+# ONE JSON line like lint/check/obs/chaos.
 locktrace-smoke:
 	JAX_PLATFORMS=cpu python tools/locktrace.py
 
@@ -113,7 +104,7 @@ locktrace-smoke:
 # checkpoint-resumed training leg, then fails unless every recompile
 # event attributes to a statically ledgered callsite and every new_shape
 # event lands in a statically flagged hazard module.
-# ONE JSON line like lint/check/obs/chaos/slo/locktrace.
+# ONE JSON line like lint/check/obs/chaos/locktrace.
 shapetrace-smoke:
 	JAX_PLATFORMS=cpu python tools/shapetrace.py
 
@@ -125,29 +116,9 @@ shapetrace-smoke:
 # worker death MID-WRITE, then fails unless pages end rc-clean, every
 # request terminal counted exactly once, no thread leaked, and every
 # observed acquire/release callsite lies inside the static inventory.
-# ONE JSON line like lint/check/obs/chaos/slo/locktrace/shapetrace.
+# ONE JSON line like lint/check/obs/chaos/locktrace/shapetrace.
 lifetrace-smoke:
 	JAX_PLATFORMS=cpu python tools/lifetrace.py
-
-# prefix-cache smoke (docs/SERVING.md § Radix prefix cache): the shared-
-# prompt replay, cache on vs off with an identical request plan — fails
-# unless prefix hit tokens > 0, TTFT p50 is >= 30% better than cache-off
-# (median of paired trials), greedy outputs are bit-identical on both
-# legs, and zero new_shape ledger events were paid for it.
-# ONE JSON line like lint/check/obs/chaos/slo.
-prefix-smoke:
-	JAX_PLATFORMS=cpu python tools/prefix.py --json
-
-# speculative-decoding smoke (docs/SERVING.md § Speculative decoding):
-# the greedy replay, spec on vs off with an identical request plan under
-# the deterministic slow_decode target-step floor — fails unless draft
-# tokens were accepted, tokens/sec >= spec-off (median of paired
-# trials), greedy outputs are bit-identical on both legs, the ledger
-# shows exactly the expected first_compile events (draft decode +
-# verify join the family), and zero new_shape events were paid for it.
-# ONE JSON line like lint/check/obs/chaos/slo/prefix.
-spec-smoke:
-	JAX_PLATFORMS=cpu python tools/spec.py --json
 
 # AOT warm-boot smoke (docs/SERVING.md § AOT warm boot): three fresh
 # processes replay the identical randomized-shape request mix with the
@@ -155,7 +126,7 @@ spec-smoke:
 # warm restart pays ZERO serving first_compile ledger events (every
 # dispatched fn arrives as cache_hit), its greedy outputs are
 # bit-identical to the cache-off leg, and zero new_shape events were paid.
-# ONE JSON line like lint/check/obs/chaos/slo/prefix.
+# ONE JSON line like lint/check/obs/chaos.
 aot-smoke:
 	JAX_PLATFORMS=cpu python tools/aot.py --json
 
@@ -175,11 +146,6 @@ gate:
 
 gate-fast:
 	python tools/gate.py --fast
-
-# needs an attached TPU: without one it exits non-zero, naming the missing
-# device, and prints no metric
-bench:
-	python bench.py
 
 native:
 	cmake -S native -B native/build && cmake --build native/build -j

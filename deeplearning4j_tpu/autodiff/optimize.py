@@ -45,8 +45,7 @@ serde, ``summary()``, and later mutation all see the full recording.
 Instrumentation: :class:`OptimizeStats` carries per-pass node counts and, on
 the ``output()`` execution path (via :class:`CompiledGraph`), the measured
 trace seconds and XLA compile seconds — surfaced as
-``SameDiff.last_compile_stats`` and by ``bench.py`` (BENCH_MODEL=
-graph_compile / ``make bench-compile``).
+``SameDiff.last_compile_stats``.
 """
 
 from __future__ import annotations

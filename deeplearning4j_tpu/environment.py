@@ -137,8 +137,8 @@ def enable_compile_cache() -> str:
     directory from the environment and no code sets another; where it is
     not, the cache lives in ``.jax_cache/`` inside the checkout. Every
     program is cached, however small or fast to compile: a cold process
-    (``chip_smoke.py``, ``bench.py``, an AOT warm boot) pays for each one
-    again otherwise."""
+    (``chip_smoke.py``, ``benchmarks/run.py``, an AOT warm boot) pays for
+    each one again otherwise."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 

@@ -181,20 +181,12 @@ class ResNet50(ZooModel):
 
     def __init__(self, num_classes: int = 1000, seed: int = 123, updater=None,
                  input_shape: Tuple[int, int, int] = (224, 224, 3),
-                 dtype: str = "float32", fused_blocks: bool = False):
+                 dtype: str = "float32"):
         self.num_classes = num_classes
         self.seed = seed
         self.updater = updater or nn.Nesterovs(learning_rate=1e-1, momentum=0.9)
         self.input_shape = input_shape
         self.dtype = dtype
-        # True: build from the FusedBottleneck layer (nn/fused_blocks.py,
-        # Pallas conv+BN fusion on the 1×1 convs — same math, equality-
-        # tested). Measured on the v5e (docs/PERF_ANALYSIS.md round 5): the
-        # composed graph is FASTER there (XLA's own fusions beat both the
-        # Pallas kernel and the 2-D dot reformulation in situ), so the
-        # default stays False; the layer remains as the kernel-evidence
-        # prototype and for future TPU generations/toolchains.
-        self.fused_blocks = fused_blocks
 
     def _bottleneck(self, b: GraphBuilder, name: str, inp: str, filters: int,
                     stride: int, project: bool) -> str:
@@ -239,20 +231,12 @@ class ResNet50(ZooModel):
         b.add_layer("pool1", nn.SubsamplingLayer(
             kernel=(3, 3), stride=(2, 2), convolution_mode="same"), "bn1")
         node = "pool1"
-        fused = self.fused_blocks is True
         stages = [(64, 3, 1), (128, 4, 2), (256, 6, 2), (512, 3, 2)]
         for si, (filters, blocks, stride) in enumerate(stages):
             for bi in range(blocks):
-                if fused:
-                    name = f"res{si}_{bi}"
-                    b.add_layer(name, nn.FusedBottleneck(
-                        filters=filters, stride=stride if bi == 0 else 1,
-                        project=(bi == 0)), node)
-                    node = name
-                else:
-                    node = self._bottleneck(
-                        b, f"res{si}_{bi}", node, filters,
-                        stride if bi == 0 else 1, project=(bi == 0))
+                node = self._bottleneck(
+                    b, f"res{si}_{bi}", node, filters,
+                    stride if bi == 0 else 1, project=(bi == 0))
         b.add_layer("gap", nn.GlobalPoolingLayer(pooling_type="avg"), node)
         b.add_layer("fc", nn.OutputLayer(n_out=self.num_classes, activation="softmax",
                                          loss="mcxent"), "gap")

@@ -417,31 +417,6 @@ class MoELayer(LayerConf):
 
 
 @dataclasses.dataclass(frozen=True)
-class FusedBottleneck(LayerConf):
-    """TPU-fused ResNet v1 bottleneck block: 1×1 → BN+relu → 3×3 → BN+relu
-    → 1×1 → BN → (+shortcut) → relu as ONE layer, so the 1×1 convs can run
-    the Pallas conv+BN-fusion kernel (ops/pallas_convbn.py). Identical math
-    to the composed layers (zoo ResNet50's _bottleneck expansion); a pure
-    performance arrangement for HBM-bound conv/BN stacks.
-    """
-
-    n_in: int = 0
-    filters: int = 0
-    stride: int = 1
-    project: bool = False
-    decay: float = 0.9
-    eps: float = 1e-5
-
-    def output_type(self, itype):
-        s = self.stride
-        return InputType.convolutional(
-            -(-itype.height // s), -(-itype.width // s), 4 * self.filters)
-
-    def has_params(self):
-        return True
-
-
-@dataclasses.dataclass(frozen=True)
 class LocalResponseNormalization(LayerConf):
     """conf/layers/LocalResponseNormalization.java."""
 
@@ -1535,7 +1510,6 @@ LAYER_TYPES = {
         EinsumDenseLayer,
         DuelingQLayer,
         MoELayer,
-        FusedBottleneck,
         ResizeLayer,
         CenterCropLayer,
         SameDiffLayer,

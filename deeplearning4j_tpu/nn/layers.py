@@ -1845,11 +1845,6 @@ LAYER_IMPLS: Dict[Type[C.LayerConf], Type[Layer]] = {
 
 def build_layer(net_conf: C.MultiLayerConfiguration, lc: C.LayerConf, itype: C.InputType) -> Layer:
     impl = LAYER_IMPLS.get(type(lc))
-    if impl is None and type(lc) is C.FusedBottleneck:
-        # registered lazily: fused_blocks imports Layer from this module
-        from deeplearning4j_tpu.nn.fused_blocks import FusedBottleneckImpl
-        LAYER_IMPLS[C.FusedBottleneck] = FusedBottleneckImpl
-        impl = FusedBottleneckImpl
     if impl is None and type(lc) is C.MoELayer:
         from deeplearning4j_tpu.nn.moe_layer import MoELayerImpl
         LAYER_IMPLS[C.MoELayer] = MoELayerImpl

@@ -15,8 +15,7 @@ One metric model for the whole framework:
 * :class:`scanned_call` — the one record every scanned trainer keeps of a
   call: span ``fit_scanned`` with its ``fit_scanned_dispatch`` /
   ``fit_scanned_read`` children, and the step and example counters.
-* :func:`summary` — the compact snapshot ``bench.py`` embeds in its final
-  JSON line and ``tools/obsreport.py`` prints.
+* :func:`summary` — the compact snapshot ``tools/obsreport.py`` prints.
 
 This package imports neither jax nor the model runtimes — it is safe to
 import from any layer (including before backend selection).

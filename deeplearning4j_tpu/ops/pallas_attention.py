@@ -35,8 +35,8 @@ Kernel design (per pallas_guide.md):
     is accumulated un-dropped (dropout applies after normalization,
     matching the reference's post-softmax dropout semantics).
   * block sizes default to 512 (capped to T): fewer, fatter grid steps
-    amortize per-step overhead. Speedups vs the XLA generic are recorded
-    per-round in BENCH_HISTORY.json (attention entries), not claimed here.
+    amortize per-step overhead. No speed-up over the XLA generic is claimed
+    here: `flash_attn_roofline.train` (PERF.md) is what the chip measured.
 
 Runs in interpret mode off-TPU so CPU tests exercise the same code path.
 """
@@ -60,11 +60,12 @@ from deeplearning4j_tpu.ops.registry import pallas_interpret
 
 logger = logging.getLogger(__name__)
 
-# Shortest kv length at which the Pallas kernel beats the XLA fused /
-# generic materialized paths on-chip. BENCH_HISTORY.json 'attention_sweep'
-# shows flash at 0.65-0.99x vs XLA below t=4096 (grid overhead dominates),
-# so the fallback crossover is 4096; the measured per-device value lives in
-# the tuning table (ops/tuning.py, refreshed by tools/tune.py or
+# Shortest kv length at which the Pallas kernel is taken over the XLA fused /
+# generic materialized paths. The only evidence for 4096 was the 2026-07
+# rig's attention sweep (flash at 0.65-0.99x of XLA below it), and that
+# machine's numbers are void: the v5e has not re-measured it (ROADMAP.md
+# D15). A measured per-device value lives in the tuning table
+# (ops/tuning.py, refreshed by tools/tune.py or
 # tools/bench_attention_sweep.py) and DL4J_TPU_FLASH_MIN_T still wins.
 FLASH_MIN_T_DEFAULT = 4096
 
@@ -1116,16 +1117,14 @@ def register_platform_attention() -> None:
                                None, rate)
 
     def usable(q, k, v, mask=None, **kw):
-        # Measured crossover (BENCH_HISTORY.json 'attention_sweep'): below
-        # the flash_min_t() threshold the materialized paths are FASTER
-        # than the Pallas kernel (grid overhead dominates); above, Pallas
-        # wins 1.5-3.6x vs XLA fused (the 19-25x rows at T=8192 are an XLA
-        # shape pathology, not the typical win). Defer below the
-        # crossover — PlatformHelper::isUsable (SURVEY §3.1). EXCEPT with
-        # attention-prob dropout: the generic path materializes a (T, T)
-        # bernoulli mask in HBM while flash regenerates it in-kernel,
-        # which flips the crossover (BERT-base seq 512 w/ dropout 0.1:
-        # 108k tok/s flash vs 77k generic — BENCH_HISTORY bert, round 4).
+        # Below the flash_min_t() threshold defer to the materialized
+        # paths — PlatformHelper::isUsable (SURVEY §3.1). The crossover
+        # came from the void 2026-07 rig's sweep and waits for the v5e's
+        # own (ROADMAP.md D15, S4). EXCEPT with attention-prob dropout:
+        # the generic path materializes a (T, T) bernoulli mask in HBM
+        # while flash regenerates it in-kernel, so dropout takes the
+        # kernel at any length (`bert-base.mlm-train` runs it at T = 512:
+        # `flash_attn_roofline.train`, PERF.md).
         t_kv = k.shape[2] if q.ndim == 4 else k.shape[1]
         if t_kv < flash_min_t() and not kw.get("dropout_rate", 0.0):
             return False

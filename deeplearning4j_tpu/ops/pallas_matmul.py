@@ -9,8 +9,7 @@ pass when the consumer graph splits. This kernel makes the contract
 explicit and unconditional: one MXU matmul in the operands' NATIVE dtype
 with an f32 VMEM accumulator, bias and activation applied to the f32
 accumulator in VMEM, ONE HBM write of the finished tile — the cuDNN
-ScaleBiasActivation epilogue pattern (SURVEY §3.1), same design as
-``ops/pallas_convbn.py``.
+ScaleBiasActivation epilogue pattern (SURVEY §3.1).
 
 Forward runs Pallas; backward is the hand-derived two-matmul VJP (the same
 passes XLA emits for the unfused chain, computed via plain XLA dots —

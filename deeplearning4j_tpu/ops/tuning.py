@@ -1,10 +1,11 @@
 """Kernel autotuner + measured dispatch tables (docs/KERNELS.md).
 
 The platform-helper table (``ops/registry.py``) picks kernels by *backend*;
-the bench trajectory shows the right unit is *(device kind, op, shape
-bucket)*: BENCH_HISTORY's attention sweep has the Pallas flash kernel at 25×
-over XLA at t=8192 yet 0.65–0.99× below t=4096 — one hardcoded
-``FLASH_MIN_T_DEFAULT`` cannot serve both a v5e and a v5p. This module owns
+the right unit is *(device kind, op, shape bucket)*: where a Pallas kernel
+overtakes XLA depends on the chip and the length, so one hardcoded
+``FLASH_MIN_T_DEFAULT`` cannot serve both a v5e and a v5p (the sweep that
+first showed a crossover ran on the 2026-07 rig, whose numbers are void:
+``ROADMAP.md`` D15). This module owns
 
 * the **tuning table**: a JSON document keyed on device kind holding, per
   op, pallas-vs-XLA crossover thresholds and per-shape-bucket Pallas block
@@ -23,7 +24,7 @@ over XLA at t=8192 yet 0.65–0.99× below t=4096 — one hardcoded
   ``tools/tune.py`` is the CLI;
   ``make tune-smoke`` runs a tiny-shape pass that must exit 0 anywhere.
 * the **dispatch feed**: ``flash_min_t()``, the Pallas block pickers in
-  ``pallas_attention``/``pallas_matmul``/``pallas_convbn``/``quantized``,
+  ``pallas_attention``/``pallas_matmul``/``quantized``,
   and the ``usable()`` gates consult :func:`tuned` so resolve decisions are
   measured, not guessed. Decisions are visible in the
   ``dl4j_tpu_helper_dispatch_total{op,impl,reason}`` counter family.
@@ -41,9 +42,8 @@ Schema (one document per device kind)::
          "blocks": {"m512_k512_n512": {"block_m": 256, ...}}},
        ...}}
 
-Fragments emitted by ``tools/bench_attention_sweep.py`` /
-``tools/bench_convbn_fusion.py`` use the same schema and merge into the
-committed default table via :meth:`TuningTable.merge`.
+Fragments emitted by ``tools/bench_attention_sweep.py`` use the same schema
+and merge into the committed default table via :meth:`TuningTable.merge`.
 """
 
 from __future__ import annotations
@@ -629,40 +629,6 @@ def _tune_paged_decode(table: TuningTable, smoke: bool) -> int:
     return n
 
 
-def _tune_convbn(table: TuningTable, smoke: bool) -> int:
-    import jax.numpy as jnp
-    import numpy as np
-
-    from deeplearning4j_tpu.ops.pallas_convbn import fused_bn_matmul_stats
-
-    shapes = ((16, 128, 128),) if smoke else ((4096, 256, 256),)
-    cands = (8, 16) if smoke else (128, 256, 512)
-    r = np.random.RandomState(6)
-    n = 0
-    with _span("fused_bn_matmul_stats"):
-        for m, k, nn_ in shapes:
-            x = jnp.asarray(r.randn(m, k).astype(np.float32))
-            sc = jnp.asarray(r.rand(k).astype(np.float32) + 0.5)
-            sh = jnp.asarray(r.randn(k).astype(np.float32) * 0.1)
-            w = jnp.asarray((r.randn(k, nn_) * k ** -0.5).astype(np.float32))
-            ss = jnp.asarray(r.randn(nn_).astype(np.float32) * 0.1)
-            best = None
-            for bm in cands:
-                if m % bm:
-                    continue
-                sec = aot_time(
-                    lambda *a, _bm=bm: fused_bn_matmul_stats(
-                        *a, block_m=_bm),
-                    (x, sc, sh, w, ss))
-                n += 1
-                if best is None or sec < best[0]:
-                    best = (sec, bm)
-            if best is not None:
-                table.set_block("fused_bn_matmul_stats", bucket_mkn(m, k, nn_),
-                                "block_m", best[1])
-    return n
-
-
 _TUNERS: Tuple[Tuple[str, Callable[[TuningTable, bool], int]], ...] = (
     ("dot_product_attention", _tune_attention),
     ("fused_matmul_bias_act", _tune_fused_matmul),
@@ -670,7 +636,6 @@ _TUNERS: Tuple[Tuple[str, Callable[[TuningTable, bool], int]], ...] = (
     ("fused_updater_step", _tune_updater),
     ("matmul_int8", _tune_int8),
     ("paged_decode_attention", _tune_paged_decode),
-    ("fused_bn_matmul_stats", _tune_convbn),
 )
 
 

@@ -24,8 +24,8 @@ transformer (``models/gpt.py``) served through
   reference and only the uncached suffix prefills
   (copy-on-write for mid-page divergence, LRU leaf eviction under a page
   budget, per-class pre-warm + pinning via the frontend's
-  ``ClassPolicy.shared_prefix``; ``BENCH_PREFIX=1`` / ``make
-  prefix-smoke`` measure the TTFT win);
+  ``ClassPolicy.shared_prefix``; what it buys on the chip is not
+  measured: no cell runs it, ``ROADMAP.md`` W1);
 
 * :class:`SpeculativeDecoder` (``serving/speculative.py``) — speculative
   decoding (draft-then-verify, lossless): ``GenerativeEngine(spec_k=K,
@@ -34,8 +34,8 @@ transformer (``models/gpt.py``) served through
   target forward (``models.gpt.gpt_verify``, the fifth compiled fn), and
   commits the agreed prefix plus the target's correction token —
   bit-identical outputs at 1..K+1 tokens per target step, rollback as an
-  O(1) length rewind (``BENCH_SPEC=1`` / ``make spec-smoke`` measure the
-  tokens/sec win);
+  O(1) length rewind (not measured on the chip: no cell speculates,
+  ``ROADMAP.md`` W4);
 
 * :class:`SLOFrontend` (``serving/frontend.py``) — the SLO-driven
   admission layer: priority classes over a priority-ordered pending
@@ -43,7 +43,7 @@ transformer (``models/gpt.py``) served through
   per-request deadlines, an ``ok``/``degraded``/``shedding`` hysteresis
   ladder, and a circuit breaker on the supervisor's restart rate —
   overload becomes goodput management instead of a failure mode
-  (``serving/overload.py`` measures it; ``make slo-smoke`` gates it).
+  (``serving/overload.py`` is the ramp ``tools/chaos.py`` drives it with).
 
 * :class:`ClusterRouter` (``serving/cluster.py``) — N engines behind one
   health- and prefix-affinity-routed front: whole-engine death (restart
@@ -55,10 +55,9 @@ transformer (``models/gpt.py``) served through
   cluster-chaos-smoke`` gates it).
 
 Serve it directly or through the ``ParallelInference.generative`` facade
-(``parallel/mesh.py``). ``BENCH_MODEL=generate`` (bench.py) measures
-tokens/sec with p50/p99 TTFT and inter-token latency;
-``BENCH_OVERLOAD=1`` switches it to the overload ramp reporting goodput
-(completed-within-deadline tokens/sec) with vs without the frontend.
+(``parallel/mesh.py``). The serving cells of ``benchmarks/run.py``
+(``BENCHMARK.json``) measure tokens/s and the first-token tail on the chip;
+``PERF.md`` says what was found.
 """
 
 from deeplearning4j_tpu.serving.cache import PagedKVCache

@@ -53,9 +53,9 @@ The ``burst_arrival`` fault point (deeplearning4j_tpu/faults/) hooks
 :meth:`SLOFrontend.submit`: a fire injects a burst of lowest-class
 synthetic arrivals so the chaos harness can drive the ladder end-to-end
 (tools/chaos.py). Goodput under overload — completed-within-deadline
-tokens/sec, with vs without this frontend — is measured by
-``serving/overload.py`` (``BENCH_MODEL=generate`` + ``BENCH_OVERLOAD=1``,
-``tools/slo.py``, the ``slo`` gate stage).
+tokens/sec, with vs without this frontend — is what
+``serving/overload.py`` reports to ``tools/chaos.py`` on the CPU; it has
+not been measured on the chip.
 
 All timing uses ``time.perf_counter`` (graftlint GL010): wall-clock jumps
 must never expire a deadline or refill a bucket.

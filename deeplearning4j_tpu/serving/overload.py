@@ -1,9 +1,8 @@
 """Goodput-under-overload ramp — the ROADMAP 2(d) success metric.
 
-One harness, three consumers (``BENCH_MODEL=generate`` +
-``BENCH_OVERLOAD=1`` in bench.py, ``tools/slo.py`` / the ``slo`` gate
-stage, and the chaos harness's frontend leg): drive a fresh
-:class:`GenerativeEngine` with an OPEN-LOOP arrival stream past its
+One harness, one consumer (the frontend leg of ``tools/chaos.py``, on
+the CPU under injected faults: a correctness check, never a speed): drive
+a fresh :class:`GenerativeEngine` with an OPEN-LOOP arrival stream past its
 measured capacity and report **goodput** — tokens of requests that
 completed (``eos``/``length``) WITHIN their deadline, per second of wall
 time. Tokens decoded for a request that missed its deadline are real
@@ -26,8 +25,9 @@ this measures.
 Every request (including frontend burst injections) must reach a
 terminal state, and the RecompileLedger must show ZERO ``new_shape``
 serving events across all degradation transitions — overload management
-must never cost a recompile (asserted by ``tools/slo.py`` and the
-acceptance tests).
+must never cost a recompile (asserted by ``tools/chaos.py`` and the
+frontend's tests). Goodput on the chip is not measured: no cell of the
+benchmark offers load past capacity (``ROADMAP.md`` W1, ``chat-open-over``).
 """
 
 from __future__ import annotations

@@ -1,160 +1,30 @@
-"""Serving traffic replay — the prefix-cache AND speculative-decoding
-acceptance harnesses.
+"""Randomized-shape serving replay: a correctness substrate, not a bench.
 
-:func:`run_prefix_replay` (``BENCH_MODEL=generate BENCH_PREFIX=1`` in
-bench.py, ``tools/prefix.py`` / the ``prefix`` gate stage, and the
-prefix tests) drives a fresh :class:`GenerativeEngine` with the traffic
-shape the radix prefix cache exists for — a handful of shared "system
-prompts" each followed by a short unique tail — and measures what the
-cache buys:
+:func:`run_randomized_replay` drives a fresh inline
+:class:`GenerativeEngine` (no worker thread, one request at a time) with
+prompt and generation lengths drawn across the whole admissible range,
+some requests sharing a system prompt, the prefix cache and speculative
+decoding both armed, and reports what the RecompileLedger saw: the
+``first_compile`` and ``cache_hit`` keys, the ``new_shape`` count, the
+greedy outputs. It times nothing. Its callers make the assertions:
+``tools/shapetrace.py`` and ``tests/test_graftshape.py`` (every event
+attributes to the static jit-site inventory, zero ``new_shape``),
+``tools/aot.py`` and ``tests/test_export.py`` (a warm restart restores
+every program as a ``cache_hit`` with bit-identical outputs).
 
-* **TTFT** (submit -> first token): with the cache, admission prefills
-  only the suffix (``suffix_bucket`` tokens against the cached prefix)
-  instead of the whole ``max_prompt`` bucket — the p50 should drop hard;
-* **hit accounting**: ``GenerationResult.prefix_hit_tokens`` per request
-  plus the ``dl4j_tpu_prefix_*`` counters;
-* **correctness**: both legs run GREEDY, so the caller can assert the
-  cache-on outputs are token-for-token identical to cache-off;
-* **compile-once**: the RecompileLedger must show ZERO ``new_shape``
-  serving events — prefix hits ride a fourth compiled function, they
-  never change a jit signature.
-
-Requests run CLOSED-LOOP, one at a time on an inline engine (no worker
-thread): TTFT then measures prefill service time, not queueing — the
-queueing story under load belongs to ``serving/overload.py``. The warm
-rounds populate the tree AND compile every path (full prefill, suffix
-prefill, decode) on both legs, so the timed window is compile-free.
-
-The default model is deliberately bigger than ``GptConfig.tiny`` (hidden
-256, 4 layers): the TTFT comparison must be dominated by prefill compute,
-not by per-call dispatch overhead, to be meaningful on a CPU host.
-
-:func:`run_spec_replay` is the speculative-decoding sibling
-(``BENCH_SPEC=1``, ``tools/spec.py`` / the ``spec`` gate stage,
-tests/test_speculative.py): the SAME greedy request plan run spec-on and
-spec-off, measuring decode tokens/sec. Like the slo gate it is a
-MECHANISM bench, not a kernel bench: both legs arm the deterministic
-50ms ``slow_decode`` floor (one fire per engine step, i.e. per TARGET
-forward), standing in for the big model's memory-bound step time, while
-the draft's real compute rides on top — so "K accepted tokens amortize
-one target step" is measured against a reproducible service-time model
-instead of host-scheduling jitter. The default draft is
-:func:`~deeplearning4j_tpu.serving.speculative.perturbed_draft` (the
-target's params plus seeded noise — a deterministic distillation
-stand-in with high-but-not-total greedy agreement, so both accepts and
-rejections are exercised); pass ``draft_model`` to measure a real one.
-Outputs must be bit-identical across the legs — losslessness is part of
-the contract, asserted by every consumer.
+What the prefix cache and speculation do to a cell's tokens/s or first
+token is the benchmark's to say (``benchmarks/run.py``, ``PERF.md``); no
+cell runs either yet (``ROADMAP.md`` W1, W4).
 """
 
 from __future__ import annotations
 
-import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import numpy as np
 
 # one definition of "a serving recompile" for every gate harness
 from deeplearning4j_tpu.serving.overload import _serving_new_shape_count
-
-
-def _pct(sorted_xs: List[float], q: float) -> Optional[float]:
-    if not sorted_xs:
-        return None
-    return sorted_xs[min(len(sorted_xs) - 1, int(q * len(sorted_xs)))]
-
-
-def run_prefix_replay(*, prefix_on: bool, n_requests: int = 12,
-                      n_prefixes: int = 3, sys_len: int = 88,
-                      tail_max: int = 5, gen_tokens: int = 4,
-                      max_slots: int = 2, seed: int = 0, vocab: int = 512,
-                      max_prompt: int = 96, page_size: int = 8,
-                      suffix_bucket: int = 16,
-                      prefix_pages: Optional[int] = None,
-                      warm_rounds: int = 2,
-                      model=None) -> Dict[str, Any]:
-    """One replay leg on a fresh engine. Identical ``seed`` on both legs
-    yields an identical request plan, so outputs are comparable
-    token-for-token. Returns TTFT percentiles, per-request outputs, hit
-    accounting, and the serving ``new_shape`` delta."""
-    from deeplearning4j_tpu.models.gpt import GptConfig, GptModel
-    from deeplearning4j_tpu.serving import GenerativeEngine
-
-    if model is None:
-        cfg = GptConfig(vocab_size=vocab, hidden=256, layers=4, heads=8,
-                        intermediate=1024, max_position=2 * max_prompt,
-                        eos_token=0)
-        model = GptModel(cfg, seed=0)
-    cfg = model.cfg
-    if sys_len + tail_max > max_prompt:
-        raise ValueError("sys_len + tail_max must fit the max_prompt bucket")
-    pages_per_seq = -(-(max_prompt + gen_tokens + 1) // page_size) + 1
-    if prefix_pages is None:
-        # budget: every shared prefix fully resident plus a few tails
-        prefix_pages = n_prefixes * (-(-max_prompt // page_size))
-    num_pages = max_slots * pages_per_seq + (prefix_pages if prefix_on
-                                             else 0)
-    eng = GenerativeEngine(
-        model, max_slots=max_slots, page_size=page_size,
-        num_pages=num_pages, max_pages_per_seq=pages_per_seq,
-        max_prompt=max_prompt, seed=0,
-        prefix_pages=prefix_pages if prefix_on else 0,
-        suffix_bucket=suffix_bucket)
-    new_shape_before = _serving_new_shape_count()
-
-    r = np.random.RandomState(seed)
-    prefixes = [r.randint(1, cfg.vocab_size, size=sys_len).astype(np.int32)
-                for _ in range(n_prefixes)]
-    plan = []
-    for _ in range(n_requests):
-        pfx = prefixes[int(r.randint(n_prefixes))]
-        tail = r.randint(1, cfg.vocab_size,
-                         size=int(r.randint(1, tail_max + 1))) \
-            .astype(np.int32)
-        plan.append(np.concatenate([pfx, tail]))
-
-    def run_one(prompt):
-        fut = eng.submit(prompt, max_new_tokens=gen_tokens, eos_token=-1)
-        while eng.scheduler.has_work():
-            eng.step()
-        return fut.result(timeout=0)
-
-    # warm: round 0 inserts each shared prefix; round 1 HITS it on the
-    # cache-on leg, compiling the suffix-prefill path — so the timed
-    # window below pays zero XLA compiles on either leg
-    for round_ in range(warm_rounds):
-        for pfx in prefixes:
-            run_one(np.concatenate(
-                [pfx, np.asarray([1 + round_], np.int32)]))
-
-    results = [run_one(p) for p in plan]
-
-    ttfts = sorted(res.ttft_s for res in results if res.ttft_s is not None)
-    hit_tokens = sum(res.prefix_hit_tokens for res in results)
-    reasons: Dict[str, int] = {}
-    for res in results:
-        reasons[res.finish_reason] = reasons.get(res.finish_reason, 0) + 1
-    out: Dict[str, Any] = {
-        "prefix_on": prefix_on,
-        "requests": n_requests,
-        "outputs": [res.tokens.tolist() for res in results],
-        "prompts": [p.tolist() for p in plan],
-        "reasons": dict(sorted(reasons.items())),
-        "all_terminal": all(res.finish_reason in ("eos", "length")
-                            for res in results),
-        "ttft_p50_ms": round(_pct(ttfts, 0.50) * 1e3, 3) if ttfts else None,
-        "ttft_p99_ms": round(_pct(ttfts, 0.99) * 1e3, 3) if ttfts else None,
-        "prefix_hit_tokens": int(hit_tokens),
-        "hit_requests": sum(1 for res in results
-                            if res.prefix_hit_tokens > 0),
-        "new_shape_events": max(
-            0, _serving_new_shape_count() - new_shape_before),
-    }
-    if prefix_on and eng.prefix is not None:
-        eng.check_invariants()
-        out["tree_pages"] = eng.prefix.tree_pages
-        out["pinned_pages"] = eng.prefix.pinned_pages
-    return out
 
 
 def _serving_first_compile_keys(before: int) -> List[str]:
@@ -180,100 +50,6 @@ def _serving_cache_hit_keys(before: int) -> List[str]:
                    if e.graph == "serving" and e.cause == "cache_hit"})
 
 
-def run_spec_replay(*, spec_on: bool, n_requests: int = 6,
-                    prompt_len: int = 10, gen_tokens: int = 12,
-                    spec_k: int = 4, max_slots: int = 2, seed: int = 0,
-                    vocab: int = 512, page_size: int = 8,
-                    max_prompt: int = 16, draft_model=None,
-                    draft_noise: float = 1e-2, slow_decode: bool = True,
-                    warm_rounds: int = 2, model=None) -> Dict[str, Any]:
-    """One speculative-decoding replay leg on a fresh engine (module
-    docstring has the measurement model). Identical ``seed`` on both
-    legs yields an identical greedy request plan, so outputs are
-    comparable token-for-token. Returns decode tokens/sec over the timed
-    window, per-request outputs, proposal/acceptance accounting, the
-    serving ``new_shape`` delta, and the leg's ``first_compile`` key
-    set."""
-    from deeplearning4j_tpu import faults, observe
-    from deeplearning4j_tpu.models.gpt import GptConfig, GptModel
-    from deeplearning4j_tpu.serving import GenerativeEngine
-    from deeplearning4j_tpu.serving.speculative import perturbed_draft
-
-    if model is None:
-        cfg = GptConfig.tiny(vocab_size=vocab,
-                             max_position=4 * max_prompt)
-        model = GptModel(cfg, seed=0)
-    cfg = model.cfg
-    if spec_on and draft_model is None:
-        draft_model = perturbed_draft(model, scale=draft_noise, seed=1)
-    pages_per_seq = -(-(max_prompt + gen_tokens + spec_k + 1)
-                      // page_size) + 1
-    eng = GenerativeEngine(
-        model, max_slots=max_slots, page_size=page_size,
-        max_pages_per_seq=pages_per_seq, max_prompt=max_prompt, seed=0,
-        spec_k=spec_k if spec_on else 0,
-        draft_model=draft_model if spec_on else None)
-    led_before = len(observe.ledger().events())
-    new_shape_before = _serving_new_shape_count()
-
-    r = np.random.RandomState(seed)
-    plan = [r.randint(1, cfg.vocab_size, size=prompt_len).astype(np.int32)
-            for _ in range(n_requests)]
-
-    def run_one(prompt):
-        fut = eng.submit(prompt, max_new_tokens=gen_tokens, eos_token=-1)
-        while eng.scheduler.has_work():
-            eng.step()
-        return fut.result(timeout=0)
-
-    # warm: compile every path on this leg (prefill + decode or
-    # prefill + draft_prefill + draft_decode + verify) OUTSIDE the timed
-    # window, floor unarmed — the window below measures steps, not XLA
-    for round_ in range(warm_rounds):
-        run_one(r.randint(1, cfg.vocab_size,
-                          size=prompt_len).astype(np.int32))
-
-    if slow_decode:
-        # the deterministic per-target-step service floor (one fire per
-        # engine step — docs/SERVING.md § Speculative decoding)
-        faults.arm("slow_decode", prob=1.0, seed=0)
-    try:
-        t0 = time.perf_counter()
-        results = [run_one(p) for p in plan]
-        wall = time.perf_counter() - t0
-    finally:
-        if slow_decode:
-            faults.disarm("slow_decode")
-
-    eng.check_invariants()
-    n_tokens = sum(len(res.tokens) for res in results)
-    proposed = sum(res.spec_proposed_tokens for res in results)
-    accepted = sum(res.spec_accepted_tokens for res in results)
-    reasons: Dict[str, int] = {}
-    for res in results:
-        reasons[res.finish_reason] = reasons.get(res.finish_reason, 0) + 1
-    return {
-        "spec_on": spec_on,
-        "spec_k": spec_k if spec_on else 0,
-        "requests": n_requests,
-        "outputs": [res.tokens.tolist() for res in results],
-        "prompts": [p.tolist() for p in plan],
-        "reasons": dict(sorted(reasons.items())),
-        "all_terminal": all(res.finish_reason in ("eos", "length")
-                            for res in results),
-        "generated_tokens": int(n_tokens),
-        "tokens_per_sec": round(n_tokens / wall, 3) if wall else None,
-        "wall_s": round(wall, 3),
-        "proposed_tokens": int(proposed),
-        "accepted_tokens": int(accepted),
-        "acceptance_rate": round(accepted / proposed, 4) if proposed
-        else None,
-        "new_shape_events": max(
-            0, _serving_new_shape_count() - new_shape_before),
-        "first_compile_keys": _serving_first_compile_keys(led_before),
-    }
-
-
 def run_randomized_replay(*, n_requests: int = 16, seed: int = 0,
                           vocab: int = 256, max_prompt: int = 32,
                           page_size: int = 8, suffix_bucket: int = 8,
@@ -282,14 +58,12 @@ def run_randomized_replay(*, n_requests: int = 16, seed: int = 0,
                           draft_noise: float = 1e-2,
                           model=None) -> Dict[str, Any]:
     """Shape-DIVERSE replay — the graftshape cross-validation workload
-    (``BENCH_MODEL=generate BENCH_RANDOM_SHAPES=1`` in bench.py, and the
-    serving leg of ``tools/shapetrace.py`` / the ``shapetrace`` gate
-    stage).
+    (the serving leg of ``tools/shapetrace.py`` / the ``shapetrace`` gate
+    stage, and every leg of ``tools/aot.py``).
 
-    Where :func:`run_prefix_replay` fixes the traffic shape to measure
-    the cache, this leg does the opposite: prompt lengths are drawn from
-    the FULL ``1..max_prompt`` range (deliberately straddling page and
-    ``suffix_bucket`` boundaries), generation lengths vary per request,
+    Prompt lengths are drawn from the FULL ``1..max_prompt`` range
+    (deliberately straddling page and ``suffix_bucket`` boundaries),
+    generation lengths vary per request,
     and a fraction of requests share one of ``n_prefixes`` system
     prompts so both the full-prefill and suffix-prefill paths fire —
     with the prefix cache AND speculative decoding armed at once.  The
@@ -312,17 +86,14 @@ def run_randomized_replay(*, n_requests: int = 16, seed: int = 0,
     pages_per_seq = -(-(max_prompt + gen_max + spec_k + 1)
                       // page_size) + 1
     prefix_pages = n_prefixes * (-(-max_prompt // page_size))
-    # boot covers engine construction INCLUDING the AOT warm boot when
-    # $DL4J_TPU_COMPILE_CACHE is set (serving/aot.py) — the cold-restart
-    # TTFT the aot gate compares is boot_s + first-request TTFT
-    t_boot = time.perf_counter()
+    # construction includes the AOT warm boot when $DL4J_TPU_COMPILE_CACHE
+    # is set (serving/aot.py)
     eng = GenerativeEngine(
         model, max_slots=max_slots, page_size=page_size,
         num_pages=max_slots * pages_per_seq + prefix_pages,
         max_pages_per_seq=pages_per_seq, max_prompt=max_prompt, seed=0,
         prefix_pages=prefix_pages, suffix_bucket=suffix_bucket,
         spec_k=spec_k, draft_model=draft_model)
-    boot_s = time.perf_counter() - t_boot
     led_before = len(observe.ledger().events())
     new_shape_before = _serving_new_shape_count()
 
@@ -376,8 +147,4 @@ def run_randomized_replay(*, n_requests: int = 16, seed: int = 0,
             0, _serving_new_shape_count() - new_shape_before),
         "first_compile_keys": _serving_first_compile_keys(led_before),
         "cache_hit_keys": _serving_cache_hit_keys(led_before),
-        "boot_s": round(boot_s, 4),
-        "ttft_first_ms": (round(results[0].ttft_s * 1e3, 3)
-                          if results and results[0].ttft_s is not None
-                          else None),
     }

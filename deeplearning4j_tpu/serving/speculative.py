@@ -83,8 +83,7 @@ def perturbed_draft(model: GptModel, *, scale: float = 1e-2,
     agreement with the target is high but not total, so replay/gate legs
     exercise accepts AND rejections reproducibly — a real deployment
     pairs a trained GPT-tiny draft (``models.GPT(...).init_draft()``)
-    instead; the harness floor (``slow_decode``) stands in for the big
-    model's step time the same way the slo gate's does."""
+    instead."""
     leaves, treedef = jax.tree.flatten(model.params)
     keys = jax.random.split(jax.random.key(seed), len(leaves))
     noisy = [l + jnp.asarray(scale, l.dtype)

@@ -9,7 +9,7 @@ BERT-base-style encoder carrying the redundancy real per-module tracing
 exporters emit — re-inlined attention-mask expansion chains, Dropout and
 Identity no-ops, per-layer foldable scale chains, decomposed erf-gelu — the
 exact surface the graph optimizer's pass pipeline and fusion tier attack
-(docs/OPTIMIZER.md; BENCH_MODEL=bert_import).
+(docs/OPTIMIZER.md; tests/test_optimizer_bert_onnx.py).
 """
 
 from __future__ import annotations

@@ -381,7 +381,7 @@ class TestServingMetrics:
         occ = m.histogram("dl4j_tpu_serving_batch_occupancy")
         assert occ.count == batches
         assert 0 < occ.mean <= 1.0
-        # summary() carries the serving section bench.py embeds
+        # summary() carries the serving section
         s = observe.summary()
         assert s["serving"]["requests"] == 40
         assert s["serving"]["p99_ms"] is not None

@@ -20,8 +20,7 @@ from deeplearning4j_tpu.imports.onnx_import import (
 
 # ---------------------------------------------------------------------------
 # ModelProto assembly helpers — canonical home is
-# deeplearning4j_tpu/testing/onnx_builder.py (bench.py builds the
-# BENCH_MODEL=bert_import model with the same codec); re-exported here for
+# deeplearning4j_tpu/testing/onnx_builder.py; re-exported here for
 # the golden-test files that import them from this module.
 # ---------------------------------------------------------------------------
 

@@ -167,9 +167,9 @@ class TestFlashAttention:
 
 class TestShapeAwareDispatch:
     """The registry must route dot_product_attention by kv length: XLA below
-    the measured crossover (flash loses to the fused path at small T —
-    BENCH_HISTORY attention_sweep), the Pallas helper at/above it. The
-    threshold is DL4J_TPU_FLASH_MIN_T (default 4096), read at resolve time."""
+    the crossover, the Pallas helper at/above it. The threshold is
+    DL4J_TPU_FLASH_MIN_T (default 4096: from the void 2026-07 rig's sweep,
+    not re-measured on the v5e — ROADMAP.md D15), read at resolve time."""
 
     def _desc(self):
         from deeplearning4j_tpu.ops.registry import registry

@@ -1,6 +1,6 @@
-"""Round-5 perf levers: s2d stem exactness, fused conv+BN Pallas kernel.
+"""Round-5 perf lever: s2d stem exactness.
 
-The levers must be *mathematically exact* rewrites — every test here checks
+The lever must be a *mathematically exact* rewrite — every test here checks
 the optimized path against the canonical one, not against golden numbers.
 """
 
@@ -62,48 +62,3 @@ class TestS2DStem:
         d = lc.to_dict()
         back = C.LayerConf.from_dict(d)
         assert back.s2d_stem is True
-
-
-class TestFusedBnMatmulStats:
-    """Pallas fused BN-apply → matmul → shifted-stats kernel (interpret mode
-    on the CPU mesh; the real-chip timing lives in
-    tools/bench_convbn_fusion.py)."""
-
-    def test_matches_reference_chain(self):
-        from deeplearning4j_tpu.ops.pallas_convbn import (
-            fused_bn_matmul_stats, reference_bn_matmul_stats)
-        r = _rng(0)
-        m, k, n = 512, 128, 64
-        x = jnp.asarray(r.randn(m, k).astype(np.float32)).astype(jnp.bfloat16)
-        sc = jnp.asarray(r.rand(k).astype(np.float32) + 0.5)
-        sh = jnp.asarray(r.randn(k).astype(np.float32) * 0.1)
-        w = jnp.asarray((r.randn(k, n) * k ** -0.5).astype(np.float32)
-                        ).astype(jnp.bfloat16)
-        ss = jnp.asarray(r.randn(n).astype(np.float32) * 0.1)
-        z1, m1, v1 = fused_bn_matmul_stats(x, sc, sh, w, ss, interpret=True)
-        z2, m2, v2 = reference_bn_matmul_stats(x, sc, sh, w, ss)
-        np.testing.assert_allclose(np.asarray(z1, np.float32),
-                                   np.asarray(z2, np.float32), atol=1e-2)
-        np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), atol=1e-3)
-        np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-2,
-                                   atol=1e-3)
-
-    def test_no_prologue_no_relu(self):
-        from deeplearning4j_tpu.ops.pallas_convbn import (
-            fused_bn_matmul_stats, reference_bn_matmul_stats)
-        r = _rng(1)
-        m, k, n = 256, 64, 128
-        x = jnp.asarray(r.randn(m, k).astype(np.float32)).astype(jnp.bfloat16)
-        sc = jnp.ones((k,), jnp.float32)
-        sh = jnp.zeros((k,), jnp.float32)
-        w = jnp.asarray((r.randn(k, n) * k ** -0.5).astype(np.float32)
-                        ).astype(jnp.bfloat16)
-        ss = jnp.zeros((n,), jnp.float32)
-        z1, m1, v1 = fused_bn_matmul_stats(
-            x, sc, sh, w, ss, relu=False, fuse_prologue=False, interpret=True)
-        z2, m2, v2 = reference_bn_matmul_stats(
-            x, sc, sh, w, ss, relu=False, fuse_prologue=False)
-        np.testing.assert_allclose(np.asarray(z1, np.float32),
-                                   np.asarray(z2, np.float32), atol=1e-2)
-        np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-2,
-                                   atol=1e-3)

@@ -622,31 +622,3 @@ class TestFrontendSharedPrefix:
         fe = SLOFrontend(eng, classes=classes)  # must not raise
         assert eng.prefix is None
         assert fe.classes["standard"].shared_prefix == [1, 2, 3]
-
-
-# ---------------------------------------------------------------------------
-# replay harness (the bench/gate substrate)
-# ---------------------------------------------------------------------------
-
-
-class TestReplayHarness:
-    def test_replay_identical_outputs_and_hits(self):
-        from deeplearning4j_tpu.serving.replay import run_prefix_replay
-
-        kw = dict(n_requests=4, n_prefixes=2, sys_len=11, tail_max=3,
-                  gen_tokens=3, max_prompt=16, page_size=8,
-                  suffix_bucket=8, warm_rounds=2, model=MODEL)
-        on = run_prefix_replay(prefix_on=True, **kw)
-        off = run_prefix_replay(prefix_on=False, **kw)
-        assert on["prompts"] == off["prompts"]  # identical plan
-        assert on["outputs"] == off["outputs"]  # bit-identical greedy
-        assert on["prefix_hit_tokens"] > 0
-        assert off["prefix_hit_tokens"] == 0
-        assert on["all_terminal"] and off["all_terminal"]
-        assert on["new_shape_events"] == 0
-        # and the cache-on leg equals the REAL oracle, not just the twin
-        for prompt, out in zip(on["prompts"], on["outputs"]):
-            np.testing.assert_array_equal(
-                out, reference_generate(
-                    MODEL.params, CFG, np.asarray(prompt, np.int32),
-                    len(out)))

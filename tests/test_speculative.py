@@ -410,7 +410,7 @@ class TestSupervisedSpeculation:
 
 
 # ---------------------------------------------------------------------------
-# frontend knob, zoo pairing, replay harness
+# frontend knob, zoo pairing
 # ---------------------------------------------------------------------------
 
 
@@ -472,25 +472,6 @@ class TestZooPairing:
         r = eng.generate([p], max_new_tokens=5, eos_token=-1)[0]
         want = reference_generate(target.params, target.cfg, p, 5)
         assert r.tokens.tolist() == want.tolist()
-
-
-class TestReplayHarness:
-    def test_replay_identical_outputs_and_acceptance(self):
-        from deeplearning4j_tpu.serving.replay import run_spec_replay
-
-        kw = dict(n_requests=3, gen_tokens=8, spec_k=3, warm_rounds=1,
-                  slow_decode=False, seed=0)
-        on = run_spec_replay(spec_on=True, **kw)
-        off = run_spec_replay(spec_on=False, **kw)
-        assert on["outputs"] == off["outputs"]
-        assert on["all_terminal"] and off["all_terminal"]
-        assert on["accepted_tokens"] > 0
-        assert on["new_shape_events"] == off["new_shape_events"] == 0
-        assert on["first_compile_keys"] == ["draft_decode", "draft_prefill",
-                                            "prefill", "verify",
-                                            "write_prompt"]
-        assert off["first_compile_keys"] == ["decode", "prefill",
-                                             "write_prompt"]
 
 
 # ---------------------------------------------------------------------------

@@ -371,15 +371,14 @@ class TestSweepFragments:
     def test_fragment_merges_into_default(self, tuning_sandbox):
         frag = tuning.TuningTable(device_kind="cpu")
         frag.set("dot_product_attention", "flash_min_t", 2048)
-        frag.set_block("fused_bn_matmul_stats", "m4096_k256_n256",
-                       "block_m", 512)
+        frag.set_block("fused_layer_norm", "r4096", "block_rows", 512)
         path = frag.save(str(tuning_sandbox / "fragment.json"))
         base = tuning.active_table("cpu")
         merged = tuning.TuningTable(base.device_kind,
                                     json.loads(json.dumps(base.entries)))
         merged.merge(tuning.TuningTable.load(path))
         assert merged.get("dot_product_attention", "flash_min_t") == 2048
-        assert merged.get_block("fused_bn_matmul_stats", "m4096_k256_n256",
-                                "block_m") == 512
+        assert merged.get_block("fused_layer_norm", "r4096",
+                                "block_rows") == 512
         # untouched entries survive the merge
         assert merged.get("fused_updater_step", "min_size") == 65536

@@ -30,7 +30,7 @@ a chip has one owner, and three restarting children cannot share it), and
 the record carries no time. What a warm boot saves on the chip is not
 measured here.
 
-Contract (same as lint/check/spec/prefix/...): ONE JSON summary line on
+Contract (same as lint/check/obs/chaos/...): ONE JSON summary line on
 stdout with ``"tool": "aot"``; exit 0 iff ``ok``. ``tools/gate.py``'s
 ``aot`` stage parses the line. ``--child`` runs a single leg in-process
 (the mode the parent spawns). The exported artifacts live under a

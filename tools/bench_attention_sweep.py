@@ -7,8 +7,7 @@ T x {causal, full}, fwd+bwd in bf16:
   * our own generic composition (_reference_attention) — the historical
     baseline the 1.95x claim was measured against.
 
-Records the full table in BENCH_HISTORY.json under 'attention_sweep',
-prints one row per shape, and emits a TUNING-TABLE FRAGMENT (the
+Prints one row per shape and emits a TUNING-TABLE FRAGMENT (the
 ops/tuning.py dl4j_tpu_tuning_v1 schema) with the measured flash-vs-XLA
 crossover for this device kind. Fragments are NOT loaded automatically:
 merge one into the committed default table
@@ -21,8 +20,6 @@ still overrides everything. Fragment path: SWEEP_TABLE_OUT env, default
 
 from __future__ import annotations
 
-import json
-import math
 import os
 import sys
 import time
@@ -35,7 +32,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def bench_shape(t: int, causal: bool, iters: int = None):
     if iters is None:
         iters = int(os.environ.get("SWEEP_ITERS", "50"))
-    bench_shape.last_iters = iters  # recorded into the history rows
     import jax
     import jax.numpy as jnp
 
@@ -114,39 +110,19 @@ def main() -> None:
 
     seqs = [int(s) for s in os.environ.get(
         "SWEEP_T", "1024,2048,4096,8192,16384").split(",")]
-    rows = []
+    wins = {}  # T -> flash beat XLA in BOTH causal modes
     print(f"device: {jax.devices()[0].device_kind}  (bh=8, d=64, bf16, "
           f"fwd+bwd, ms per call)")
     print(f"{'T':>6} {'causal':>6} {'flash':>9} {'xla':>9} {'generic':>9} "
           f"{'flash/xla':>9}")
     for t in seqs:
         for causal in (True, False):
-            (f_min, f_mean, f_std), (x_min, x_mean, x_std), \
-                (g_min, g_mean, g_std) = bench_shape(t, causal)
-            rows.append({"t": t, "causal": causal, "bh": 8, "d": 64,
-                         "iters": bench_shape.last_iters,
-                         "flash_ms": round(f_min, 3),
-                         "flash_ms_std": round(f_std, 3),
-                         "xla_ms": round(x_min, 3),
-                         "xla_ms_std": round(x_std, 3),
-                         "generic_ms": round(g_min, 3),
-                         "generic_ms_std": round(g_std, 3),
-                         "speedup_vs_xla": round(x_min / f_min, 3)})
+            (f_min, _, f_std), (x_min, _, x_std), (g_min, _, _) = \
+                bench_shape(t, causal)
+            wins[t] = wins.get(t, True) and x_min >= f_min
             print(f"{t:>6} {str(causal):>6} {f_min:>9.3f} {x_min:>9.3f} "
                   f"{g_min:>9.3f} {x_min / f_min:>9.2f}x  "
                   f"(std f={f_std:.3f} x={x_std:.3f})")
-
-    hist_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "..", "BENCH_HISTORY.json")
-    hist_path = os.path.abspath(hist_path)
-    hist = {}
-    if os.path.exists(hist_path):
-        hist = json.load(open(hist_path))
-    hist["attention_sweep"] = {
-        "device": jax.devices()[0].device_kind,
-        "rows": rows}
-    json.dump(hist, open(hist_path, "w"), indent=1)
-    print(f"recorded {len(rows)} rows to {hist_path}")
 
     # tuning-table fragment (ops/tuning.py schema): the measured crossover
     # is the smallest swept T where flash beats XLA in BOTH causal modes;
@@ -155,10 +131,6 @@ def main() -> None:
 
     kind = tuning.normalize_device_kind(jax.devices()[0].device_kind)
     frag = tuning.TuningTable(device_kind=kind)
-    wins = {}
-    for row in rows:
-        wins.setdefault(row["t"], True)
-        wins[row["t"]] &= row["speedup_vs_xla"] >= 1.0
     crossover = next((t for t in sorted(wins) if wins[t]), 2 * max(seqs))
     frag.set("dot_product_attention", "flash_min_t", int(crossover))
     out_path = os.environ.get(

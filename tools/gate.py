@@ -30,42 +30,30 @@ Stages:
      request must reach a terminal finish reason, the supervisor must
      restart within its cap with zero new_shape ledger events, and
      restore() must fall back past a torn checkpoint (docs/ROBUSTNESS.md)
-  7. slo smoke: tools/slo.py goodput-under-overload ramp — frontend-on
-     goodput must be >= frontend-off under an identical past-capacity
-     schedule, with every request terminal and zero new_shape events
-     (docs/SERVING.md § SLO admission frontend)
-  8. prefix smoke: tools/prefix.py shared-prompt replay — prefix hit
-     tokens > 0, TTFT p50 >= 30% better than cache-off, greedy outputs
-     bit-identical both legs, zero new_shape events
-     (docs/SERVING.md § Radix prefix cache)
-  9. spec smoke: tools/spec.py speculative-decoding replay — accepted
-     draft tokens > 0, tokens/sec >= spec-off, greedy outputs
-     bit-identical both legs, exactly the expected first_compile events
-     and zero new_shape (docs/SERVING.md § Speculative decoding)
- 10. trainchaos smoke: tools/chaos.py --leg training — training killed
+  7. trainchaos smoke: tools/chaos.py --leg training — training killed
      mid-fit by injected faults must resume BIT-EXACT vs the
      uninterrupted oracle with zero new_shape, and async checkpointing's
      per-step overhead must be < 10% of the synchronous-save baseline
      (docs/ROBUSTNESS.md § Preemption-proof training)
- 11. locktrace smoke: tools/locktrace.py shadow-lock cross-validation —
+  8. locktrace smoke: tools/locktrace.py shadow-lock cross-validation —
      the graftlock static lock-order graph must be acyclic, every
      lock-order edge observed under the threaded serving + checkpoint
      workload must lie inside its transitive closure, and the combined
      graph must stay acyclic (docs/LINT.md § graftlock)
- 12. shapetrace smoke: tools/shapetrace.py recompile-ledger
+  9. shapetrace smoke: tools/shapetrace.py recompile-ledger
      cross-validation — every CompileEvent recorded under the
      randomized-shape serving replay + checkpoint-resumed training
      workload must attribute to a statically known registration span,
      every new_shape must land in a statically flagged hazard module,
      and both legs must themselves observe zero new_shape
      (docs/LINT.md § graftshape)
- 13. lifetrace smoke: tools/lifetrace.py runtime resource-lifecycle
+ 10. lifetrace smoke: tools/lifetrace.py runtime resource-lifecycle
      cross-validation — the faults-armed prefix cluster + async
      checkpoint workload must end with rc-clean pages, exactly one
      terminal count per request, zero leaked threads, every observed
      acquire/release callsite inside graftlife's static ownership
      inventory, and zero new_shape (docs/LINT.md § graftlife)
- 14. aot smoke: tools/aot.py cold-restart warm boot — a fresh process
+ 11. aot smoke: tools/aot.py cold-restart warm boot — a fresh process
      restoring from the persistent export cache must pay zero serving
      first_compile events (cache_hit only) and emit outputs bit-identical
      to the cache-off leg (docs/SERVING.md § AOT warm boot)
@@ -280,122 +268,6 @@ def chaos_stage() -> bool:
     return bool(ok)
 
 
-def slo_stage() -> bool:
-    """Goodput smoke (docs/SERVING.md § SLO admission frontend): the
-    overload ramp must report frontend-on goodput >= frontend-off with
-    every request terminal on both legs, the ladder engaged, and zero
-    new_shape events. One JSON line, like lint/check/obs/chaos."""
-    print("== gate: slo-smoke (goodput under overload, frontend on/off) ==",
-          flush=True)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("DL4J_TPU_FAULTS", None)  # an ambient schedule would distort
-    try:                              # the measured legs
-        proc = subprocess.run(
-            [sys.executable, "tools/slo.py", "--json"],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
-    except subprocess.TimeoutExpired:
-        print("   FAIL (slo-smoke timeout)")
-        return False
-    line = next((l for l in proc.stdout.splitlines()
-                 if l.startswith("{") and '"tool"' in l), None)
-    if line:
-        print(f"   {line}")
-    if proc.returncode != 0 or line is None:
-        tail = "\n".join((proc.stdout + proc.stderr).splitlines()[-15:])
-        print(f"   FAIL (slo-smoke exit {proc.returncode})\n{tail}")
-        return False
-    rec = json.loads(line)
-    on = rec.get("frontend_on") or {}
-    off = rec.get("frontend_off") or {}
-    ok = (bool(rec.get("ok"))
-          and (rec.get("goodput_on") or 0) >= (rec.get("goodput_off") or 0)
-          and on.get("all_terminal") and off.get("all_terminal"))
-    print(f"   {'ok' if ok else 'FAIL'} (slo-smoke: goodput on/off "
-          f"{rec.get('goodput_on')}/{rec.get('goodput_off')} tok/s "
-          f"(x{rec.get('goodput_ratio')}), states "
-          f"{on.get('states_visited')}, reasons on={on.get('reasons')} "
-          f"off={off.get('reasons')})")
-    return bool(ok)
-
-
-def prefix_stage() -> bool:
-    """Prefix-cache smoke (docs/SERVING.md § Radix prefix cache): the
-    shared-prompt replay must report ok — prefix hit tokens > 0, TTFT p50
-    >= 30% better than cache-off (median of paired trials), greedy
-    outputs bit-identical on both legs, zero new_shape events. One JSON
-    line, like lint/check/obs/chaos/slo."""
-    print("== gate: prefix-smoke (shared-prompt replay, cache on/off) ==",
-          flush=True)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("DL4J_TPU_FAULTS", None)  # an ambient schedule would distort
-    try:                              # the paired TTFT comparison
-        proc = subprocess.run(
-            [sys.executable, "tools/prefix.py", "--json"],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
-    except subprocess.TimeoutExpired:
-        print("   FAIL (prefix-smoke timeout)")
-        return False
-    line = next((l for l in proc.stdout.splitlines()
-                 if l.startswith("{") and '"tool"' in l), None)
-    if line:
-        print(f"   {line}")
-    if proc.returncode != 0 or line is None:
-        tail = "\n".join((proc.stdout + proc.stderr).splitlines()[-15:])
-        print(f"   FAIL (prefix-smoke exit {proc.returncode})\n{tail}")
-        return False
-    rec = json.loads(line)
-    ok = (bool(rec.get("ok"))
-          and (rec.get("prefix_hit_tokens") or 0) > 0
-          and rec.get("outputs_identical")
-          and rec.get("new_shape_events") == 0)
-    print(f"   {'ok' if ok else 'FAIL'} (prefix-smoke: TTFT p50 "
-          f"{rec.get('ttft_p50_ms_on')}/{rec.get('ttft_p50_ms_off')} ms "
-          f"on/off (x{rec.get('ttft_speedup')}), "
-          f"{rec.get('prefix_hit_tokens')} hit tokens, identical="
-          f"{rec.get('outputs_identical')})")
-    return bool(ok)
-
-
-def spec_stage() -> bool:
-    """Speculative-decoding smoke (docs/SERVING.md § Speculative
-    decoding): the greedy replay must report ok — accepted draft tokens
-    > 0, tokens/sec >= spec-off (median of paired trials), greedy
-    outputs bit-identical on both legs, exactly the expected
-    first_compile ledger events, zero new_shape. One JSON line, like
-    lint/check/obs/chaos/slo/prefix."""
-    print("== gate: spec-smoke (speculative replay, spec on/off) ==",
-          flush=True)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("DL4J_TPU_FAULTS", None)  # an ambient schedule would distort
-    try:                              # the paired throughput comparison
-        proc = subprocess.run(
-            [sys.executable, "tools/spec.py", "--json"],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
-    except subprocess.TimeoutExpired:
-        print("   FAIL (spec-smoke timeout)")
-        return False
-    line = next((l for l in proc.stdout.splitlines()
-                 if l.startswith("{") and '"tool"' in l), None)
-    if line:
-        print(f"   {line}")
-    if proc.returncode != 0 or line is None:
-        tail = "\n".join((proc.stdout + proc.stderr).splitlines()[-15:])
-        print(f"   FAIL (spec-smoke exit {proc.returncode})\n{tail}")
-        return False
-    rec = json.loads(line)
-    ok = (bool(rec.get("ok"))
-          and (rec.get("accepted_tokens") or 0) > 0
-          and rec.get("outputs_identical")
-          and rec.get("new_shape_events") == 0
-          and rec.get("first_compiles_ok"))
-    print(f"   {'ok' if ok else 'FAIL'} (spec-smoke: "
-          f"{rec.get('tokens_per_sec_on')}/{rec.get('tokens_per_sec_off')} "
-          f"tok/s on/off (x{rec.get('speedup')}), "
-          f"{rec.get('accepted_tokens')}/{rec.get('proposed_tokens')} "
-          f"accepted, identical={rec.get('outputs_identical')})")
-    return bool(ok)
-
-
 def aot_stage() -> bool:
     """AOT warm-boot smoke (docs/SERVING.md § AOT warm boot): three
     fresh processes replay the identical randomized-shape request mix —
@@ -403,7 +275,7 @@ def aot_stage() -> bool:
     ZERO serving first_compile ledger events (everything it dispatches
     arrives as cache_hit), produce outputs bit-identical to the
     cache-off leg and observe zero new_shape. One JSON line, like
-    lint/check/obs/chaos/slo/prefix/spec."""
+    lint/check/obs/chaos."""
     print("== gate: aot-smoke (cold-restart warm boot, cache off/on) ==",
           flush=True)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -706,9 +578,6 @@ def main() -> int:
         results["locktrace"] = locktrace_stage()
         results["shapetrace"] = shapetrace_stage()
         results["lifetrace"] = lifetrace_stage()
-        results["slo"] = slo_stage()
-        results["prefix"] = prefix_stage()
-        results["spec"] = spec_stage()
         results["aot"] = aot_stage()
         results["multichip"] = multichip_stage()
 
