@@ -40,10 +40,14 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 # A reader that finds ``dropped > 0`` holds half a window and reads nothing, so
-# the bound has to hold what a reader's window writes: a serving engine at
-# 1660 tokens/s (GPT-2 small, 32 slots, one v5e) writes some 28 thousand
-# events in 40 s, about 13 a step. Some 30 MB when full.
-_MAX_EVENTS = 65536
+# the bound has to hold what a reader's window writes. Sized for a serving
+# engine at 1.3 times 5000 tokens/s on 32 slots (GPT-2 small on one v5e once
+# the loop runs a step ahead of the tokens' read): a 40 s window and 10 s of
+# drain are some 10 thousand decode steps of 9-10 events and 5 thousand
+# requests of 64 tokens at 9 events, near 140 thousand
+# (tests/test_serving_ahead.py holds the arithmetic to the engine's own
+# counts). Some 120 MB when full.
+_MAX_EVENTS = 262144
 
 # one running number for every span of every tracer in the process
 _IDS = itertools.count(1)
@@ -76,6 +80,9 @@ class Span:
         self.args = args
 
     def set(self, **args) -> None:
+        """Add ``args``. The event of a closed span holds this same dict, so
+        what is known only after the span closed (the statistics of a decode
+        step that is read a step late) still reaches it."""
         self.args.update(args)
 
     def __enter__(self) -> "Span":

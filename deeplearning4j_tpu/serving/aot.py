@@ -111,7 +111,8 @@ def _fn_table(engine) -> List[Dict[str, Any]]:
         dict(key="prefill", owner=engine, attr="_prefill_fn",
              build=engine._build_prefill, key_idx=3, donate=(),
              specs=(PARAMS, SDS((1, engine.max_prompt), i32), SDS((), i32),
-                    KD, SDS((1,), f32), SDS((1,), i32), SDS((1,), f32))),
+                    KD, SDS((1,), f32), SDS((1,), i32), SDS((1,), f32),
+                    SDS((S,), i32), SDS((), i32))),
         dict(key="write_prompt", owner=engine, attr="_write_fn",
              build=engine._build_write, key_idx=None,
              specs=(KV, kv_prompt, SDS((P,), i32), SDS((), i32))),
@@ -127,7 +128,8 @@ def _fn_table(engine) -> List[Dict[str, Any]]:
                  build=engine._build_suffix, key_idx=6, donate=(1,),
                  specs=(PARAMS, KV, SDS((1, engine.suffix_bucket), i32),
                         SDS((), i32), SDS((), i32), SDS((P,), i32), KD,
-                        SDS((1,), f32), SDS((1,), i32), SDS((1,), f32))),
+                        SDS((1,), f32), SDS((1,), i32), SDS((1,), f32),
+                        SDS((S,), i32), SDS((), i32))),
             dict(key="copy_page", owner=cache, attr="_copy_fn",
                  build=cache._build_copy, key_idx=None,
                  specs=(KV, SDS((), i32), SDS((), i32))),

@@ -11,7 +11,7 @@ admits/evicts (asserted in tests/test_serving.py):
 
 * **prefill** — the whole (padded) prompt through one causal pass of the
   MODEL's prefill program + first-token sampling; returns the per-layer
-  cache rows for the scatter. TTFT is measured across this call.
+  cache rows for the scatter. TTFT is measured to this token's read.
 * **write-prompt** — write the prefill K/V into the slot's pages, whole
   pages at a time and in place (donated pool; pages past the prompt land
   on the trash page, the tail of the prompt's last page is masked garbage
@@ -23,6 +23,17 @@ admits/evicts (asserted in tests/test_serving.py):
   this step's fresh key; inside the one program it runs only the body the
   bank's knobs ask for (``serving/sampling.py``: the argmax alone while no
   slot samples).
+
+The loop (docs/SERVING.md § The loop): no launch needs the VALUE of a
+token — the bank's token vector is ``decode``'s own result, with an
+admission's first token set in it by ``prefill``, and never leaves the
+device; lengths advance at the launch. ``step()`` reads what it launches
+before it returns; the worker that ``start()`` runs keeps one decode step in
+flight and reads it after it has launched the next one, so that the host's
+part of a step runs under the device's time. A sequence complete by its
+count leaves its slot with its last token in flight
+(``SlotScheduler.leaving``); an ``eos`` read a step late costs one dropped
+token of one slot.
 
 With ``prefix_pages > 0`` a fourth compiled function joins them —
 **suffix-prefill**: on a radix-prefix-cache hit (``serving/prefix.py``)
@@ -76,7 +87,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
-from typing import Callable, List, Optional, Sequence
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +109,26 @@ logger = logging.getLogger(__name__)
 # keys split ahead of their use, while the device runs a decode step: one for
 # the next step and a few for the admissions before it
 _KEY_RESERVE = 4
+
+
+class _Admission(NamedTuple):
+    """A launched prefill whose first token the host has not read yet."""
+
+    st: Any                # the scheduler's state of the sequence
+    parent: Optional[int]  # the ``serving_admit`` span it was launched under
+    tok: Any               # the sampled token, on the device
+    stats: Any             # the model's statistics, on the device (or None)
+    launch: tuple          # perf_counter readings around the launch
+    args: dict             # what ``serving_prefill`` carries
+
+
+class _DecodeStep(NamedTuple):
+    """A launched decode step whose tokens the host has not read yet."""
+
+    slots: list            # (slot, its state) the step was launched for
+    toks: Any              # the bank's token vector after the step, device
+    stats: Any
+    span: Any              # the step's ``serving_decode`` span
 
 
 def build_write(page: int, trash: int):
@@ -126,14 +157,18 @@ def build_write(page: int, trash: int):
 
 def build_prefill(model_prefill):
     """The jitted ``prefill``: the model's ``prefill`` program over one
-    padded prompt, and the sampler on its last position's logits. Needs no
-    engine, like :func:`build_write`."""
+    padded prompt, and the sampler on its last position's logits. The
+    sampled token also joins the bank's token vector at ``slot`` on the
+    device, where the next ``decode`` finds it: no launch waits for the host
+    to have read it. Needs no engine, like :func:`build_write`."""
 
     @jax.jit
-    def prefill(params, ids, prompt_len, key, temp, top_k, top_p):
+    def prefill(params, ids, prompt_len, key, temp, top_k, top_p, bank_toks,
+                slot):
         last, rows, stats = model_prefill(params, ids, prompt_len)
         tok = sample_tokens(last, key, temp, top_k, top_p)[0]
-        return rows, tok, stats  # (L, sides, T, width), scalar
+        # (L, sides, T, width), scalar, statistics, (S,)
+        return rows, tok, stats, bank_toks.at[slot].set(tok)
 
     return prefill
 
@@ -142,8 +177,11 @@ def build_decode(decode_step, page: int, trash: int):
     """The jitted ``decode``: one token for every slot against the donated
     pool (the model's ``decode_step`` program) and the sampler. Like
     :func:`build_write`, a function of configuration and page geometry
-    alone. The model's statistics (``None`` for a model without an expert
-    layer) ride out beside the tokens."""
+    alone. ``tokens`` is the bank's token vector as the step before (or an
+    admission's ``prefill``) left it on the device, and the result takes its
+    place: a slot that sat this step out keeps its token. The model's
+    statistics (``None`` for a model without an expert layer) ride out
+    beside the tokens."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, kv_pages, page_table, seq_lens, tokens, active,
@@ -158,7 +196,7 @@ def build_decode(decode_step, page: int, trash: int):
             params, kv_pages, tokens, seq_lens, page_table, seq_incl,
             write_page, write_off)
         toks = sample_tokens(logits, key, temp, top_k, top_p)
-        return kv_pages, toks, logits, stats
+        return kv_pages, jnp.where(on, toks, tokens), logits, stats
 
     return decode
 
@@ -292,6 +330,15 @@ class GenerativeEngine:
         # body they ask for): worked out again only when _resident has sent
         # one of the three anew
         self._sampler: tuple = ((None,) * 3, SAMPLER_PATHS[0])
+        # the bank's token vector: every slot's newest token, where the
+        # launches leave it on the device (decode's result, with an
+        # admission's first token set by its prefill); a host array only
+        # until the first launch
+        self._toks: Any = np.zeros((max_slots,), np.int32)
+        # launched and not read yet (docs/SERVING.md § The loop): the decode
+        # step the worker keeps in flight, and this iteration's admissions
+        self._flying: Optional[_DecodeStep] = None
+        self._landing: List[_Admission] = []
         self._prefill_fn = None
         self._write_fn = None
         self._decode_fn = None
@@ -340,6 +387,9 @@ class GenerativeEngine:
             "sampler": {path: m.counter(
                 "dl4j_tpu_serving_sampler_steps_total", path=path)
                 for path in SAMPLER_PATHS},
+            "launches": [m.counter(
+                "dl4j_tpu_serving_decode_launches_total", ahead=str(a))
+                for a in (0, 1)],
             # written ONLY by stop(): the gauge is process-global, and a
             # constructor write here would clobber a previous engine's
             # hung-stop indication while that engine is still wedged
@@ -415,7 +465,7 @@ class GenerativeEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def suffix_prefill(params, kv_pages, ids, prefix_len, suffix_len,
-                           pt_row, key, temp, top_k, top_p):
+                           pt_row, key, temp, top_k, top_p, bank_toks, slot):
             # the prompt bucket's whole pages, gathered a layer and side at
             # a time (as the pool is written below, and for the same
             # reason): (L, 2, n, page, E) -> (L, 2, Tpre, E)
@@ -441,7 +491,7 @@ class GenerativeEngine:
                 for side in sides:
                     kv_pages = kv_pages.at[li, side, wpage, apos % page].set(
                         kv_suf[li, side])
-            return kv_pages, tok
+            return kv_pages, tok, bank_toks.at[slot].set(tok)
 
         return suffix_prefill
 
@@ -626,6 +676,16 @@ class GenerativeEngine:
                 # the flag — loop again and join the replacement too
         self.stopped_cleanly = True
         self._obs["stopped_g"].set(1.0)
+        try:
+            # the tokens of what the worker had launched belong to the
+            # partial results (and complete the sequences that only waited
+            # for their last one)
+            self._land()
+        except Exception:
+            logger.exception("the step in flight at stop() could not be "
+                             "read; its tokens are dropped")
+            self._flying = None
+            self._landing.clear()
         # in-flight sequences retire with their partial output and the
         # documented "stopped" reason (the worker is joined — no race);
         # queued-but-never-admitted requests fail
@@ -665,7 +725,9 @@ class GenerativeEngine:
                     self.restarts = self.max_restarts
                     raise faults.InjectedFault("engine_death")
                 faults.maybe_fail("worker_death")
-                self.step()
+                # one decode step ahead of the tokens' read, unless the
+                # next lengths wait for them (speculation)
+                self._iterate(ahead=self.spec is None)
             except Exception as e:
                 if self._recover(e):
                     # this worker retires; a REPLACEMENT thread owns the
@@ -696,6 +758,7 @@ class GenerativeEngine:
         self._error = exc
         observe.log_event("engine_dead", engine=self.engine_id,
                           restarts=self.restarts, error=repr(exc))
+        self._drop_unlanded()
         hook = self.on_unrecoverable
         if hook is not None:
             try:
@@ -745,6 +808,33 @@ class GenerativeEngine:
         observe.log_event("serving_terminal", reason=reason,
                           slo_class=req.slo_class)
 
+    def _requeue_or_fail(self, st) -> None:
+        """A sequence the crash took: back to the FRONT of the queue with
+        its original submit time while it has retry budget left (the
+        deadline keeps counting across the crash; generation restarts from
+        the prompt), else terminally ``error``."""
+        req = st.request
+        if req.retries_used < req.max_retries:
+            req.retries_used += 1
+            self._obs["retries"].inc()
+            with self.scheduler._plock:
+                self.scheduler.pending.appendleft(
+                    (req, st.future, st.submit_t))
+        else:
+            self._finish_unslotted(req, st.future, "error", st.submit_t)
+
+    def _drop_unlanded(self) -> None:
+        """Forget what was launched and not read (a crash: its values may
+        never come), and re-queue the sequences that had left their slots
+        and only waited for their last token, oldest first."""
+        sched = self.scheduler
+        self._flying = None
+        self._landing.clear()
+        self._toks = np.zeros((self.cache.max_slots,), np.int32)
+        leaving, sched.leaving = sched.leaving, []
+        for st in reversed(leaving):
+            self._requeue_or_fail(st)
+
     def _recover(self, exc: Exception) -> bool:
         """Crash recovery (docs/ROBUSTNESS.md state machine): free every
         slot, re-queue requests with retry budget left (front of queue,
@@ -764,19 +854,11 @@ class GenerativeEngine:
         # assigned lowest-free-first, so reverse slot order restores the
         # requests' original arrival order at the front of the queue
         for slot in reversed(sched.active_slots()):
-            st = sched.slots.pop(slot)
             cache.free_slot(slot)
-            req = st.request
-            if req.retries_used < req.max_retries:
-                # retryable: back to the FRONT of the queue with its
-                # original submit time (deadline keeps counting across
-                # the crash) — generation restarts from the prompt
-                req.retries_used += 1
-                self._obs["retries"].inc()
-                with sched._plock:
-                    sched.pending.appendleft((req, st.future, st.submit_t))
-            else:
-                self._finish_unslotted(req, st.future, "error", st.submit_t)
+            self._requeue_or_fail(sched.slots.pop(slot))
+        # ... ahead of which go the sequences that had left their slots: a
+        # step that was launched and not read is dropped with the slots
+        self._drop_unlanded()
         if self.prefix is not None:
             # reset_kv is about to zero the device pages, so every cached
             # prefix is garbage: drop the tree wholesale (pin intents
@@ -916,6 +998,20 @@ class GenerativeEngine:
             self.cache.check_invariants(tree_refs=self.prefix.page_refs())
         else:
             self.cache.check_invariants()
+        sched = self.scheduler
+        flying = ([st for _slot, st in self._flying.slots]
+                  if self._flying is not None else [])
+        waited = flying + [adm.st for adm in self._landing]
+        for st in list(sched.slots.values()) + sched.leaving:
+            n = sum(w is st for w in waited)
+            assert st.unlanded == n <= 1, (
+                f"request {st.request.request_id}: {st.unlanded} tokens "
+                f"counted unlanded, {n} launches waiting to be read")
+        for st in sched.leaving:
+            assert st.unlanded == 1 and len(st.tokens) + 1 >= \
+                st.request.max_new_tokens, (
+                    f"request {st.request.request_id} left its slot with "
+                    f"{len(st.tokens)} tokens and {st.unlanded} in flight")
         if self.spec is not None:
             assert self._spec_slots <= set(self.scheduler.slots), (
                 f"speculating slots {self._spec_slots} outside the active "
@@ -923,43 +1019,94 @@ class GenerativeEngine:
             self.spec.check_invariants(self._spec_slots, self.cache.seq_lens)
 
     # ------------------------------------------------------------ scheduling
-    def _retire(self, slot: int, reason: str) -> None:
-        if self.prefix is not None and reason in ("eos", "length"):
+    def _free_slot(self, slot: int, completed: bool) -> None:
+        """Give back what ``slot`` holds beside its scheduler state: its
+        pages and its draft row."""
+        if self.prefix is not None and completed:
             # a COMPLETED sequence donates its prompt's pages to the
             # radix tree (insert or LRU-refresh) before the slot lets go
-            st = self.scheduler.slots.get(slot)
-            if st is not None:
-                n = self.cache.pages_for(st.prompt_len)
-                self.prefix.insert(st.request.prompt,
-                                   list(self.cache.owned[slot][:n]))
-        self.scheduler.retire(slot, reason)
+            st = self.scheduler.slots[slot]
+            n = self.cache.pages_for(st.prompt_len)
+            self.prefix.insert(st.request.prompt,
+                               list(self.cache.owned[slot][:n]))
         self.cache.free_slot(slot)
         if self.spec is not None:
             self._spec_slots.discard(slot)
             self.spec.free(slot)
+
+    def _retire(self, slot: int, reason: str) -> None:
+        self._free_slot(slot, reason in ("eos", "length"))
+        self.scheduler.retire(slot, reason)
         count_terminal(reason)
+
+    def _retire_or_hold(self, slot: int, reason: str, hold: set) -> None:
+        """Retire ``slot`` for a reason that is not its own completion
+        (``deadline``, ``overflow``, ``oom``), unless a token of its is
+        still in flight: that token may be the one that completes it, so
+        the slot sits out this launch and the question is asked again once
+        the token has landed."""
+        if self.scheduler.slots[slot].unlanded:
+            hold.add(slot)
+        else:
+            self._retire(slot, reason)
+
+    def _finish_complete(self) -> None:
+        """Retire the sequences that their landed tokens complete; one that
+        is complete by its count, its last token in flight, frees its slot
+        and pages now and gets its result when that token lands
+        (:meth:`_finish_leaving`). The device runs programs in launch order
+        over the one donated pool, so a prefill into those pages is queued
+        behind the decode step that last used them."""
+        sched = self.scheduler
+        for slot in sched.active_slots():
+            reason = sched.should_finish(slot)
+            if reason:
+                self._retire(slot, reason)
+            elif sched.last_in_flight(slot):
+                self._free_slot(slot, True)
+                sched.detach(slot)
+
+    def _finish_leaving(self, st) -> None:
+        """A token landed for ``st``: if it had left its slot, that was its
+        last and its result is complete."""
+        sched = self.scheduler
+        if any(s is st for s in sched.leaving):
+            reason = sched.finish_reason(st)
+            sched.finish(st, reason)
+            count_terminal(reason)
 
     def step(self) -> int:
         """ONE scheduler iteration: capacity-evict, admit, retire finished,
-        then one decode step for the whole slot bank. Returns the number of
-        tokens generated (0 when idle). The whole of it is one
-        ``serving_step`` span whose children are the stages
-        (docs/OBSERVABILITY.md § Span catalogue)."""
+        then one decode step for the whole slot bank, whose tokens are read
+        and committed before it returns. Returns the number of tokens
+        generated (0 when idle). The whole of it is one ``serving_step``
+        span whose children are the stages (docs/OBSERVABILITY.md § Span
+        catalogue)."""
+        return self._iterate(ahead=False)
+
+    def _iterate(self, ahead: bool) -> int:
+        """One iteration of the loop, in one of two orders of the same
+        halves (docs/SERVING.md § The loop). Inline (:meth:`step`) it reads
+        every token where it was launched. The worker runs it ``ahead``:
+        the decode step it launches stays in flight, and what it reads is
+        the step before (then this iteration's admissions, in launch
+        order), so the device always has its next program queued."""
         sched = self.scheduler
         # graftlock: justified(GL012): single-writer — only the (one) worker/inline step thread steps
         self._step_count += 1
         with observe.tracer().span(
                 "serving_step", category="serving", step=self._step_count,
                 pending=len(sched.pending), active=len(sched.slots)) as sp:
-            admitted, produced = self._step()
+            admitted, produced = self._step(ahead)
             sp.set(admitted=admitted, produced=produced)
         return produced
 
-    def _step(self) -> tuple:
-        """The stages of :meth:`step`; returns (admitted, produced)."""
+    def _step(self, ahead: bool) -> tuple:
+        """The stages of :meth:`_iterate`; returns (admitted, produced)."""
         cache, sched = self.cache, self.scheduler
         tracer = observe.tracer()
         admitted = 0
+        hold: set = set()  # slots that sit this launch out
 
         with tracer.span("serving_schedule", category="serving") as stage:
             held = len(sched.slots)
@@ -967,10 +1114,7 @@ class GenerativeEngine:
             #    a finished slot must neither grab capacity pages it will never
             #    write nor be mis-retired as oom/overflow (which would skip the
             #    eos trim and steal pages a live neighbour needed)
-            for slot in sched.active_slots():
-                reason = sched.should_finish(slot)
-                if reason:
-                    self._retire(slot, reason)
+            self._finish_complete()
 
             # 1b. deadlines — AFTER completion so a finished sequence keeps its
             #     honest eos/length reason; overdue work retires as "deadline"
@@ -979,7 +1123,7 @@ class GenerativeEngine:
             for slot in sched.active_slots():
                 dl = sched.slots[slot].request.deadline_s
                 if dl is not None and now - sched.slots[slot].submit_t > dl:
-                    self._retire(slot, "deadline")
+                    self._retire_or_hold(slot, "deadline", hold)
             expired = []
             with sched._plock:
                 for _ in range(len(sched.pending)):
@@ -997,11 +1141,11 @@ class GenerativeEngine:
             for slot in sched.active_slots():
                 need = int(cache.seq_lens[slot]) + 1
                 if need > self.cfg.max_position:
-                    self._retire(slot, "overflow")
+                    self._retire_or_hold(slot, "overflow", hold)
                     continue
                 status = cache.ensure_capacity(slot, need)
                 if status != "ok":
-                    self._retire(slot, status)
+                    self._retire_or_hold(slot, status, hold)
             stage.set(retired=held - len(sched.slots) + len(expired))
 
         # 3. admissions into free slots, highest-priority first (FIFO
@@ -1081,7 +1225,7 @@ class GenerativeEngine:
                     continue
                 self._slot_match[slot] = match if hit_tokens else None
                 try:
-                    first_tok = self._prefill_into(slot, req)
+                    launched = self._prefill_into(slot, req)
                 except BaseException:
                     # the request sits in neither pending nor a slot right
                     # now — put it back at the queue FRONT (original submit
@@ -1092,12 +1236,12 @@ class GenerativeEngine:
                         sched.pending.appendleft(item)
                     raise
                 cache.seq_lens[slot] = p_len
-                now = time.perf_counter()
-                sched.admit(slot, req, fut, t_sub, first_tok, now,
-                            prefix_hit_tokens=hit_tokens)
+                st = sched.admit(slot, req, fut, t_sub,
+                                 prefix_hit_tokens=hit_tokens)
+                self._landing.append(_Admission(st, adm.id, *launched))
+                if not ahead:
+                    self._land_admissions()
                 self._obs["admitted"].inc()
-                self._obs["generated"].inc()
-                self._obs["ttft_h"].observe(now - t_sub)
                 # the wait in the queue: submit -> the start of the admission
                 # that took the request (a retried request waits twice)
                 tracer.async_between(
@@ -1123,16 +1267,14 @@ class GenerativeEngine:
             held = len(sched.slots)
             # 4. a just-admitted sequence can already be done (first token was
             #    its eos, or max_new_tokens == 1) — retire before decoding
-            for slot in sched.active_slots():
-                reason = sched.should_finish(slot)
-                if reason:
-                    self._retire(slot, reason)
+            self._finish_complete()
             stage.set(retired=held - len(sched.slots))
 
         self._obs["occupancy"].set(sched.occupancy())
-        active = sched.active_slots()
+        active = [slot for slot in sched.active_slots() if slot not in hold]
         if not active:
-            return admitted, 0
+            # nothing to launch: what is in flight lands
+            return admitted, self._land()
 
         # 5. one decode iteration over the whole slot bank. With
         #    speculation on, the bank splits: slots that can take a
@@ -1154,9 +1296,14 @@ class GenerativeEngine:
                 # a slot that cannot host the verify window finishes its
                 # sequence NON-speculatively: one plain step would advance
                 # the target past the draft cache (length drift), so the
-                # draft row is abandoned rather than resynced
+                # draft row is abandoned rather than resynced. The verify
+                # passes committed its tokens on the host: the newest joins
+                # the bank's vector for the plain steps that follow
                 self._spec_slots.discard(slot)
                 self.spec.free(slot)
+                toks = np.array(self._toks)
+                toks[slot] = sched.slots[slot].tokens[-1]
+                self._toks = toks
             plain.append(slot)
 
         # chaos hooks (docs/ROBUSTNESS.md): both fire BEFORE any dispatch
@@ -1169,14 +1316,21 @@ class GenerativeEngine:
 
         produced = 0
         if plain:
-            produced += self._step_decode(plain)
+            produced += self._step_decode(plain, ahead)
         if spec_now:
             produced += self._step_speculative(spec_now)
+        if self._landing:
+            # the worker's loop: this iteration's admissions, read behind
+            # the decode step's launch
+            with tracer.span("serving_first_tokens", category="serving"):
+                self._land_admissions()
         return admitted, produced
 
-    def _step_decode(self, active: List[int]) -> int:
+    def _step_decode(self, active: List[int], ahead: bool) -> int:
         """The plain one-token decode iteration over ``active`` (the
-        whole bank when speculation is off)."""
+        whole bank when speculation is off): launch this step, then read
+        and commit the step that is due — the one before where the loop
+        runs ``ahead`` (this one stays in flight), else this one."""
         cache, sched = self.cache, self.scheduler
         tracer = observe.tracer()
         if self._decode_fn is None:
@@ -1184,26 +1338,24 @@ class GenerativeEngine:
         key = self._next_key()
         with tracer.span("serving_decode_upload", category="serving"):
             s_n = cache.max_slots
-            tokens = np.zeros((s_n,), np.int32)
             act = np.zeros((s_n,), np.int32)
             temp = np.zeros((s_n,), np.float32)
             top_k = np.zeros((s_n,), np.int32)
             top_p = np.ones((s_n,), np.float32)
             for slot in active:
                 st = sched.slots[slot]
-                tokens[slot] = st.tokens[-1]
                 act[slot] = 1
                 temp[slot] = st.request.temperature
                 top_k[slot] = st.request.top_k
                 top_p[slot] = st.request.top_p
             # what changes every step goes to the jitted call as host
             # arrays: its own transfer of an argument costs half of a
-            # ``jnp.asarray`` (0.14 against 0.3 ms on the v5e's host), and
-            # the device idles while the host prepares a step. Copies,
-            # because the cache updates its tables in place. What seldom
-            # changes stays on the device
-            args = (cache.page_table.copy(), cache.seq_lens.copy(), tokens,
-                    self._resident("active", act))
+            # ``jnp.asarray`` (0.14 against 0.3 ms on the v5e's host).
+            # Copies, because the cache updates its tables in place. What
+            # seldom changes stays on the device, and the tokens never
+            # leave it: the bank's vector is the launches' own result
+            args = (cache.page_table.copy(), cache.seq_lens.copy(),
+                    self._toks, self._resident("active", act))
             sampling = (self._resident("temperature", temp),
                         self._resident("top_k", top_k),
                         self._resident("top_p", top_p))
@@ -1215,34 +1367,112 @@ class GenerativeEngine:
                 self._decode_fn, graph="serving", key="decode",
                 signature=observe.signature_of(
                     page_table=cache.page_table, seq_lens=cache.seq_lens,
-                    tokens=tokens, active=act))
+                    tokens=self._toks, active=act))
+        # None in a synchronous iteration: it reads what it launches
+        before = self._flying
         t0 = time.perf_counter()
         with tracer.span("serving_decode", category="serving",
-                         slots=len(active), sampler=self._sampler[1]) as sp:
+                         slots=len(active), sampler=self._sampler[1],
+                         ahead=int(before is not None)) as sp:
             with tracer.span("serving_decode_launch", category="serving"):
-                cache.kv, next_toks, _logits, stats = self._decode_fn(
+                cache.kv, self._toks, _logits, stats = self._decode_fn(
                     self.model.params, cache.kv, *args, key, *sampling)
+            step = _DecodeStep([(slot, sched.slots[slot]) for slot in active],
+                               self._toks, stats, sp)
+            for slot, st in step.slots:
+                # known without the token's value: the fed token is cached
+                # by the time any later program runs
+                cache.seq_lens[slot] += 1
+                st.unlanded += 1
+            self._flying = step if ahead else None
+            due = before if ahead else step
             self._reserve_keys()
-            with tracer.span("serving_decode_read", category="serving"):
-                # tokens and statistics in ONE blocking read
-                next_toks, stats = jax.device_get((next_toks, stats))
-            if stats is not None:
-                self.programs.note_stats(stats, sp, decode_step=True)
+            toks = self._read_decode(due) if due is not None else None
         dt = time.perf_counter() - t0
         self._obs["decode_h"].observe(dt)
         self._obs["sampler"][self._sampler[1]].inc()
-        with tracer.span("serving_commit", category="serving"):
+        self._obs["launches"][int(before is not None)].inc()
+        return self._commit_decode(due, toks, dt) if due is not None else 0
+
+    def _read_decode(self, step: _DecodeStep) -> np.ndarray:
+        """The blocking read of a launched decode step: its tokens and the
+        model's statistics in ONE transfer. The statistics go to the step's
+        own ``serving_decode`` span, open or closed."""
+        with observe.tracer().span("serving_decode_read",
+                                   category="serving"):
+            toks, stats = jax.device_get((step.toks, step.stats))
+        if stats is not None:
+            self.programs.note_stats(stats, step.span, decode_step=True)
+        return toks
+
+    def _commit_decode(self, step: _DecodeStep, toks: np.ndarray,
+                       step_seconds: float) -> int:
+        """Hand a read step's tokens to their sequences, stamped now: when
+        they reached the host is what a caller can see. A sequence that is
+        gone, or complete by what landed before (its ``eos`` was read a
+        step late), takes none: that token is dropped, its K/V row lies
+        past the length the result keeps."""
+        sched = self.scheduler
+        with observe.tracer().span("serving_commit", category="serving"):
             now = time.perf_counter()
-            for slot in active:
-                cache.seq_lens[slot] += 1  # the fed token is cached now
-                st = sched.slots[slot]
+            produced = 0
+            for slot, st in step.slots:
+                if not sched.holds(slot, st) or sched.finish_reason(st):
+                    st.unlanded -= 1
+                    continue
                 if st.last_token_t is not None:
                     self._obs["itl_h"].observe(now - st.last_token_t)
-                sched.on_decode_token(slot, int(next_toks[slot]), now)
-            self._obs["generated"].inc(len(active))
-            observe.log_event("serving_decode", slots=len(active),
-                              step_seconds=round(dt, 6))
-        return len(active)
+                sched.on_decode_token(st, int(toks[slot]), now)
+                produced += 1
+                self._finish_leaving(st)
+            self._obs["generated"].inc(produced)
+            observe.log_event("serving_decode", slots=len(step.slots),
+                              step_seconds=round(step_seconds, 6))
+        return produced
+
+    def _land(self) -> int:
+        """Read and commit everything launched and not read yet: the decode
+        step in flight, then the admissions, in launch order. Returns the
+        decode tokens committed."""
+        step, self._flying = self._flying, None
+        produced = 0
+        if step is not None:
+            t0 = time.perf_counter()
+            toks = self._read_decode(step)
+            produced = self._commit_decode(step, toks,
+                                           time.perf_counter() - t0)
+        self._land_admissions()
+        return produced
+
+    def _land_admissions(self) -> None:
+        """Read the first token of every launched admission, in launch
+        order, each stamped when its own read returns: a request's first
+        token does not wait for the admissions behind it. This is where
+        ``serving_prefill`` ends: it runs from the prefill's launch to its
+        token on the host, whatever the loop launched in between."""
+        sched, tracer = self.scheduler, observe.tracer()
+        while self._landing:
+            adm = self._landing.pop(0)
+            t0 = time.perf_counter()
+            tok, stats = jax.device_get((adm.tok, adm.stats))
+            now = time.perf_counter()
+            # never opened: it collects the args of a span whose extent is
+            # known only now
+            sp = tracer.span("serving_prefill", category="serving",
+                             **adm.args)
+            if stats is not None:
+                self.programs.note_stats(stats, sp)
+            pid = tracer.complete_between(
+                sp.name, adm.launch[0], now, category=sp.category,
+                parent=adm.parent, **sp.args)
+            tracer.complete_between("serving_prefill_launch", *adm.launch,
+                                    category="serving", parent=pid)
+            tracer.complete_between("serving_prefill_read", t0, now,
+                                    category="serving", parent=pid)
+            sched.on_first_token(adm.st, int(tok), now)
+            self._obs["generated"].inc()
+            self._obs["ttft_h"].observe(now - adm.st.submit_t)
+            self._finish_leaving(adm.st)
 
     def _step_speculative(self, spec_now: List[int]) -> int:
         """One speculative iteration for ``spec_now`` (docs/SERVING.md
@@ -1337,11 +1567,15 @@ class GenerativeEngine:
             committed=committed_total, step_seconds=round(dt, 6))
         return committed_total
 
-    def _prefill_into(self, slot: int, req: GenerationRequest) -> int:
-        """Run the (bucketed) prefill, scatter K/V into the slot's pages,
-        return the first sampled token. With a prefix-cache match staged
-        for this slot the shared pages are already mapped and only the
-        SUFFIX runs — TTFT is measured across this (much shorter) pass."""
+    def _prefill_into(self, slot: int, req: GenerationRequest) -> tuple:
+        """Launch the (bucketed) prefill and the scatter of its K/V into the
+        slot's pages. Returns what :meth:`_land_admissions` reads later, the
+        tail of an :class:`_Admission`: the sampled token and the model's
+        statistics (both still on the device), the launch's two clock
+        readings and ``serving_prefill``'s args. With a prefix-cache match
+        staged for this slot the shared pages are already mapped and only
+        the SUFFIX runs — TTFT is measured across this (much shorter)
+        pass."""
         match = self._slot_match.pop(slot, None)
         if match is not None:
             return self._prefill_suffix_into(slot, req, match)
@@ -1360,30 +1594,24 @@ class GenerativeEngine:
         observe.note_jit_signature(
             self._write_fn, graph="serving", key="write_prompt",
             signature=observe.signature_of(ids=ids))
-        tracer = observe.tracer()
-        with tracer.span("serving_prefill", category="serving",
-                         prompt_len=p_len, request=req.request_id) as sp:
-            with tracer.span("serving_prefill_launch", category="serving"):
-                kv_prompt, tok, stats = self._prefill_fn(
-                    self.model.params, ids, np.int32(p_len), key,
-                    self._resident("prefill_temperature", np.asarray(
-                        [req.temperature], np.float32)),
-                    self._resident("prefill_top_k", np.asarray(
-                        [req.top_k], np.int32)),
-                    self._resident("prefill_top_p", np.asarray(
-                        [req.top_p], np.float32)))
-                cache.kv = self._write_fn(
-                    cache.kv, kv_prompt, cache.page_table[slot].copy(),
-                    np.int32(p_len))
-            with tracer.span("serving_prefill_read", category="serving"):
-                tok, stats = jax.device_get((tok, stats))
-                tok = int(tok)
-            if stats is not None:
-                self.programs.note_stats(stats, sp)
-        return tok
+        t0 = time.perf_counter()
+        kv_prompt, tok, stats, self._toks = self._prefill_fn(
+            self.model.params, ids, np.int32(p_len), key,
+            self._resident("prefill_temperature", np.asarray(
+                [req.temperature], np.float32)),
+            self._resident("prefill_top_k", np.asarray(
+                [req.top_k], np.int32)),
+            self._resident("prefill_top_p", np.asarray(
+                [req.top_p], np.float32)),
+            self._toks, np.int32(slot))
+        cache.kv = self._write_fn(
+            cache.kv, kv_prompt, cache.page_table[slot].copy(),
+            np.int32(p_len))
+        return (tok, stats, (t0, time.perf_counter()),
+                dict(prompt_len=p_len, request=req.request_id))
 
     def _prefill_suffix_into(self, slot: int, req: GenerationRequest,
-                             match: PrefixMatch) -> int:
+                             match: PrefixMatch) -> tuple:
         """Prefix-hit admission: prefill ONLY the uncached suffix against
         the cached prefix pages already mapped into the slot's row."""
         cache = self.cache
@@ -1397,19 +1625,16 @@ class GenerativeEngine:
         observe.note_jit_signature(
             self._suffix_fn, graph="serving", key="suffix_prefill",
             signature=observe.signature_of(ids=ids))
-        tracer = observe.tracer()
-        with tracer.span("serving_prefill", category="serving",
-                         prompt_len=p_len, prefix_hit=match.matched,
-                         request=req.request_id):
-            with tracer.span("serving_prefill_launch", category="serving"):
-                cache.kv, tok = self._suffix_fn(
-                    self.model.params, cache.kv, jnp.asarray(ids),
-                    jnp.asarray(match.matched, jnp.int32),
-                    jnp.asarray(suffix.size, jnp.int32),
-                    jnp.asarray(cache.page_table[slot]), key,
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.top_k], jnp.int32),
-                    jnp.asarray([req.top_p], jnp.float32))
-            with tracer.span("serving_prefill_read", category="serving"):
-                tok = int(tok)
-        return tok
+        t0 = time.perf_counter()
+        cache.kv, tok, self._toks = self._suffix_fn(
+            self.model.params, cache.kv, jnp.asarray(ids),
+            jnp.asarray(match.matched, jnp.int32),
+            jnp.asarray(suffix.size, jnp.int32),
+            jnp.asarray(cache.page_table[slot]), key,
+            jnp.asarray([req.temperature], jnp.float32),
+            jnp.asarray([req.top_k], jnp.int32),
+            jnp.asarray([req.top_p], jnp.float32),
+            self._toks, np.int32(slot))
+        return (tok, None, (t0, time.perf_counter()),
+                dict(prompt_len=p_len, prefix_hit=match.matched,
+                     request=req.request_id))

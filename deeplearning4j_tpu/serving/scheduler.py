@@ -17,12 +17,17 @@ iterations. Timing fields use ``time.perf_counter`` only (graftlint GL010).
 Slot lifecycle::
 
     FREE --admit(prefill ok)--> ACTIVE --finish(eos|length)--> FREE
+                                   \\--detach(last token in flight)--> FREE
                                    \\--evict(overflow|oom|stopped)--> FREE
                                    \\--expire(deadline)--> FREE
                                    \\--crash(retryable)--> PENDING (retry)
                                    \\--crash(budget spent: error)--> FREE
 
 ``GenerationResult.finish_reason`` records which arc retired the request.
+The engine may read a launched step's tokens a step late (docs/SERVING.md
+§ The loop): a slot state counts the tokens launched for it and not read yet
+(``unlanded``), and a sequence that is complete by that count gives its slot
+up at once and waits in ``leaving`` for its last token (``detach``).
 ``shed`` never reaches a slot: the engine's bounded-queue admission gate
 completes over-capacity submissions immediately (docs/ROBUSTNESS.md).
 """
@@ -170,7 +175,7 @@ class GenerationResult:
     spec_disabled: bool = False
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class _Slot:
     request: GenerationRequest
     future: "Future[GenerationResult]"
@@ -183,6 +188,9 @@ class _Slot:
     prefix_hit_tokens: int = 0
     spec_proposed_tokens: int = 0
     spec_accepted_tokens: int = 0
+    # tokens launched for this sequence (its prefill's, a decode step's)
+    # that the host has not read yet
+    unlanded: int = 0
 
 
 class SlotScheduler:
@@ -197,6 +205,8 @@ class SlotScheduler:
         self.max_slots = int(max_slots)
         self.pending: Deque[tuple] = deque()
         self.slots: Dict[int, _Slot] = {}
+        # sequences that gave their slot up with their last token in flight
+        self.leaving: List[_Slot] = []
         self._plock = threading.Lock()
 
     # ------------------------------------------------------------ submission
@@ -215,7 +225,13 @@ class SlotScheduler:
         return [s for s in range(self.max_slots) if s not in self.slots]
 
     def has_work(self) -> bool:
-        return bool(self.slots) or bool(self.pending)
+        return bool(self.slots) or bool(self.pending) or bool(self.leaving)
+
+    def holds(self, slot: int, st: _Slot) -> bool:
+        """``st`` is still a live sequence (in ``slot``, or leaving): what a
+        step launched for it may still be committed to it."""
+        return self.slots.get(slot) is st or any(
+            s is st for s in self.leaving)
 
     def occupancy(self) -> float:
         return len(self.slots) / self.max_slots if self.max_slots else 0.0
@@ -271,23 +287,33 @@ class SlotScheduler:
     # ------------------------------------------------------------- lifecycle
     def admit(self, slot: int, request: GenerationRequest,
               future: "Future[GenerationResult]", submit_t: float,
-              first_token: int, now: float,
-              prefix_hit_tokens: int = 0) -> None:
-        """Install a prefilled request into ``slot`` with its first sampled
-        token (TTFT is measured here: prefill produced a token).
+              first_token: Optional[int] = None,
+              now: Optional[float] = None,
+              prefix_hit_tokens: int = 0) -> _Slot:
+        """Install a request whose prefill was launched into ``slot``. Its
+        first sampled token is in flight (``unlanded``) until
+        :meth:`on_first_token` lands it; given here, it lands at once.
         ``prefix_hit_tokens`` records how much of the prompt the radix
         prefix cache served — it rides into the GenerationResult so
         callers and the replay bench can account hits per request."""
         st = _Slot(request=request, future=future, submit_t=submit_t,
                    prompt_len=int(request.prompt.size),
-                   prefix_hit_tokens=int(prefix_hit_tokens))
-        st.tokens.append(int(first_token))
-        st.ttft_s = now - submit_t
-        st.last_token_t = now
+                   prefix_hit_tokens=int(prefix_hit_tokens), unlanded=1)
         self.slots[slot] = st
+        if first_token is not None:
+            self.on_first_token(st, first_token, now)
+        return st
 
-    def on_decode_token(self, slot: int, token: int, now: float) -> None:
-        st = self.slots[slot]
+    def on_first_token(self, st: _Slot, token: int, now: float) -> None:
+        """The prefill's token reached the host: TTFT is measured here."""
+        st.unlanded -= 1
+        st.tokens.append(int(token))
+        st.ttft_s = now - st.submit_t
+        st.last_token_t = now
+
+    def on_decode_token(self, st: _Slot, token: int, now: float) -> None:
+        """A decode step's token for ``st`` reached the host."""
+        st.unlanded -= 1
         st.tokens.append(int(token))
         if st.last_token_t is not None:
             st.intertoken_s.append(now - st.last_token_t)
@@ -317,21 +343,44 @@ class SlotScheduler:
         st.spec_accepted_tokens += int(accepted)
         return gap
 
-    def should_finish(self, slot: int) -> Optional[str]:
-        """``"eos"``/``"length"`` when the slot's sequence is complete."""
-        st = self.slots[slot]
+    @staticmethod
+    def finish_reason(st: _Slot) -> Optional[str]:
+        """``"eos"``/``"length"`` when the tokens that have landed complete
+        the sequence."""
         if st.tokens and st.tokens[-1] == st.request.eos_token:
             return "eos"
         if len(st.tokens) >= st.request.max_new_tokens:
             return "length"
         return None
 
+    def should_finish(self, slot: int) -> Optional[str]:
+        """``"eos"``/``"length"`` when the slot's sequence is complete."""
+        return self.finish_reason(self.slots[slot])
+
+    def last_in_flight(self, slot: int) -> bool:
+        """The slot's sequence is complete by its count and its last token
+        has not landed: nothing more is launched for it."""
+        st = self.slots[slot]
+        return bool(st.unlanded) and (
+            len(st.tokens) + st.unlanded >= st.request.max_new_tokens)
+
+    def detach(self, slot: int) -> _Slot:
+        """Free ``slot`` for the next request while its sequence waits in
+        ``leaving`` for its last token; :meth:`finish` completes it."""
+        st = self.slots.pop(slot)
+        self.leaving.append(st)
+        return st
+
     def retire(self, slot: int, reason: str) -> GenerationResult:
         """Remove ``slot`` and complete its future. The caller frees the
         slot's cache pages (the scheduler never touches device state)."""
+        return self.finish(self.slots.pop(slot), reason)
+
+    def finish(self, st: _Slot, reason: str) -> GenerationResult:
+        """Complete the future of a sequence that holds no slot (any more)."""
         if reason not in FINISH_REASONS:
             raise ValueError(f"unknown finish reason {reason!r}")
-        st = self.slots.pop(slot)
+        self.leaving = [s for s in self.leaving if s is not st]
         toks = st.tokens
         if reason == "eos" and toks and toks[-1] == st.request.eos_token:
             toks = toks[:-1]
@@ -358,8 +407,10 @@ class SlotScheduler:
         contract). Each future actually failed here counts ONCE under
         ``dl4j_tpu_serving_evicted_total{reason}`` — exception exits share
         the terminal-reason vocabulary with result exits."""
+        leaving, self.leaving = self.leaving, []
         for slot in list(self.slots):
-            st = self.slots.pop(slot, None)  # tolerate a concurrent caller
+            leaving.append(self.slots.pop(slot, None))  # None: a concurrent caller
+        for st in leaving:
             if st is not None and not st.future.done():
                 st.future.set_exception(exc)
                 count_terminal(reason)

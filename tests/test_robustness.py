@@ -314,11 +314,12 @@ class TestDeathPaths:
         eng = make_engine().start()
         release = threading.Event()
 
-        def stuck_step():
+        def stuck_step(ahead):
             release.wait(5.0)
             return 0
 
-        eng.step = stuck_step  # the loop picks it up on the next iteration
+        # the loop picks it up on the next iteration
+        eng._iterate = stuck_step
         fut = eng.submit(PROMPTS[0], max_new_tokens=4)
         time.sleep(0.05)  # let the loop enter the stuck step
         eng.stop(timeout=0.2)

@@ -274,16 +274,25 @@ class TestServingStageSpans:
             # queue wait + the admission up to the first token = TTFT, from
             # the spans' own clock (microseconds; EPS for their rounding):
             # the wait ends where the admission that took the request
-            # starts, the prefill lies inside that admission, and the first
-            # token's time is read after the prefill closes and before the
-            # admission does. No wall-clock tolerance: under other workers
-            # any stretch between two of these reads can take milliseconds.
+            # starts, the prefill is launched inside that admission, and
+            # the first token lands where ``serving_prefill`` ends: one
+            # clock reading is the span's end and the token's stamp. No
+            # wall-clock tolerance: under other workers any stretch between
+            # two reads of the clock can take milliseconds.
             wait, adm, pre = waits[rid], admits[rid], prefills[rid]
             end = lambda e: e["ts"] + e["dur"]  # noqa: E731
             assert abs(end(wait) - adm["ts"]) <= EPS
-            assert adm["ts"] <= pre["ts"] and end(pre) <= end(adm) + EPS
-            assert (end(pre) - wait["ts"] - EPS <= res.ttft_s * 1e6
-                    <= end(adm) - wait["ts"] + EPS)
+            assert adm["ts"] <= pre["ts"]
+            assert abs(end(pre) - wait["ts"] - res.ttft_s * 1e6) <= EPS
+            # inline, the admission reads its token before it closes (a
+            # started engine reads it after its decode step's launch)
+            assert end(pre) <= end(adm) + EPS
+            launch, read = (
+                next(e for e in spans("serving_prefill" + part)
+                     if e["args"]["parent"] == pre["args"]["id"])
+                for part in ("_launch", "_read"))
+            assert launch["ts"] == pre["ts"] and end(launch) <= read["ts"]
+            assert abs(end(read) - end(pre)) <= EPS
         wait_h = observe.metrics().histogram(
             "dl4j_tpu_serving_queue_wait_seconds")
         assert wait_h.count == len(PROMPTS)

@@ -121,7 +121,7 @@ def _pool_programs(one_chip, monkeypatch, dtype):
     }
     prefill = build_prefill(gpt_programs(cfg).prefill).lower(
         params, sds((1, MAX_PROMPT), i32), sds((), i32), key, sds((1,), f32),
-        sds((1,), i32), sds((1,), f32))
+        sds((1,), i32), sds((1,), f32), slot(i32), sds((), i32))
     _COMPILED[cached] = (pool, {k: v.compile() for k, v in programs.items()},
                          prefill)
     return _COMPILED[cached]
@@ -229,7 +229,8 @@ def _latent_programs(one_chip, monkeypatch):
     }
     prefill = build_prefill(longcat_programs(cfg).prefill).lower(
         params, sds((1, LC_MAX_PROMPT), i32), sds((), i32), key,
-        sds((1,), f32), sds((1,), i32), sds((1,), f32))
+        sds((1,), f32), sds((1,), i32), sds((1,), f32), slot(i32),
+        sds((), i32))
     _COMPILED["latent"] = (pool, compiled, prefill)
     return _COMPILED["latent"]
 
