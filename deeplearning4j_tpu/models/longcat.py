@@ -13,7 +13,8 @@ experts)::
   values through ONE shared latent of ``kv_lora_rank`` values a token plus a
   rotary key of ``qk_rope_head_dim`` shared by all heads. What a token leaves
   in the cache is one row an attention sub-layer: ``[RMS(c) * s_kv | rotated
-  k_r]`` (:func:`cache_row_width` pads it to whole 128-lane tiles).
+  k_r]`` (:func:`cache_row_width` pads it to whole 128-lane tiles). The
+  pieces are ``models/mla.py``'s, which every latent-attention model calls:
   :func:`longcat_prefill` materialises K and V from the latent and runs the
   registry's ``dot_product_attention``; :func:`longcat_decode_step` absorbs
   the up-projections into the query and the output and runs the registry's
@@ -43,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu import observe
+from deeplearning4j_tpu.models import mla
+from deeplearning4j_tpu.models.mla import rms as _rms, swiglu as _swiglu
 from deeplearning4j_tpu.models.served import CacheRows, ServingPrograms
 from deeplearning4j_tpu.parallel.moe import moe_topk_share
 
@@ -84,6 +87,11 @@ class LongcatConfig:
     def held(self) -> Tuple[int, int]:
         return self.held_experts or (0, self.n_routed_experts)
 
+    @property
+    def mla(self) -> mla.MlaDims:
+        return mla.dims_of(self, scale_q=self.mla_scale_q_lora,
+                           scale_kv=self.mla_scale_kv_lora)
+
     @staticmethod
     def tiny(**kw) -> "LongcatConfig":
         """Test-sized, every mechanism kept: two MLA sub-layers a layer,
@@ -99,10 +107,8 @@ class LongcatConfig:
 
 
 def cache_row_width(cfg: LongcatConfig) -> int:
-    """Latent and rotary key, rounded up to whole 128-lane tiles: 576 values
-    are 4.5 tiles, which the device would pad (and copy the pool to do so);
-    640 with 64 dead lanes it keeps row-major (tests/test_tpu_compile.py)."""
-    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+    """The cache row of this model's attention (``mla.cache_row_width``)."""
+    return mla.cache_row_width(cfg.mla)
 
 
 def init_longcat_params(key, cfg: LongcatConfig, dtype=jnp.float32
@@ -148,55 +154,6 @@ def init_longcat_params(key, cfg: LongcatConfig, dtype=jnp.float32
 # ------------------------------------------------------------------ pieces
 
 
-def _rms(x, gain, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
-    return (y * gain.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta):
-    """Rotate the interleaved pairs (x[2i], x[2i+1]) of the last axis by
-    ``pos * theta**(-2i/n)``, in float32. x: (..., n) with leading axes those
-    of ``pos`` and then any others."""
-    n = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
-    ang = pos.astype(jnp.float32)[..., None] * inv
-    ang = ang.reshape(pos.shape + (1,) * (x.ndim - pos.ndim - 1) + (n // 2,))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    pair = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // 2, 2))
-    even, odd = pair[..., 0], pair[..., 1]
-    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
-                     axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def _swiglu(f, x):
-    with jax.named_scope("dense_ffn"):
-        return (jax.nn.silu(x @ f["Wg"]) * (x @ f["Wu"])) @ f["Wd"]
-
-
-def _mla_inputs(a, x, pos, cfg: LongcatConfig):
-    """What both attention paths share. x: (..., d) normalised, pos: (...).
-    Returns the heads' queries ``q_nope (..., H, nope)``, ``q_rope (..., H,
-    rope)`` (rotated) and the token's cache row ``(..., W)``: the normalised,
-    scaled latent, the rotated shared key, dead lanes."""
-    h, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                     cfg.qk_rope_head_dim)
-    rkv, d = cfg.kv_lora_rank, cfg.hidden_size
-    s_q = math.sqrt(d / cfg.q_lora_rank) if cfg.mla_scale_q_lora else 1.0
-    s_kv = math.sqrt(d / rkv) if cfg.mla_scale_kv_lora else 1.0
-    c_q = _rms(x @ a["W_qa"], a["q_norm"], cfg.rms_norm_eps)
-    q = (c_q @ a["W_qb"]).reshape(x.shape[:-1] + (h, nope + rope)) * s_q
-    q = q.astype(x.dtype)
-    ckr = x @ a["W_kva"]
-    c = (_rms(ckr[..., :rkv], a["kv_norm"], cfg.rms_norm_eps).astype(
-        jnp.float32) * s_kv).astype(x.dtype)
-    k_r = _rope(ckr[..., rkv:], pos, cfg.rope_theta)
-    dead = cache_row_width(cfg) - rkv - rope
-    row = jnp.concatenate(
-        [c, k_r, jnp.zeros(x.shape[:-1] + (dead,), x.dtype)], axis=-1)
-    return q[..., :nope], _rope(q[..., nope:], pos, cfg.rope_theta), row
-
-
 def _moe(m, u, cfg: LongcatConfig, valid):
     return moe_topk_share(
         m, u, top_k=cfg.moe_topk, n_routed=cfg.n_routed_experts,
@@ -228,13 +185,9 @@ def longcat_prefill(params, ids, cfg: LongcatConfig, *, mask=None,
     else all ``(1, T, V)``. Returns ``(logits, rows (2L, 1, 1, T, W),
     stats (L, count + 2))``: the cache rows of every attention sub-layer and
     the expert layers' statistics over the real tokens."""
-    from deeplearning4j_tpu.ops import exec_op
-
     n, t = ids.shape
     if n != 1:
         raise ValueError("longcat_prefill takes one prompt a call")
-    h, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
-    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     pos = jnp.arange(t)
     valid = jnp.ones((t,), bool) if mask is None else mask[0].astype(bool)
     m4 = valid[None, None, None, :]
@@ -242,22 +195,9 @@ def longcat_prefill(params, ids, cfg: LongcatConfig, *, mask=None,
     rows, stats = [], []
 
     def attend(sub, a, xn):
-        q_nope, q_rope, row = _mla_inputs(a, xn, pos, cfg)
+        out, row = mla.prefill_attention(a, xn, pos, m4, cfg.mla)
         rows.append(row)
-        with jax.named_scope("mla_prefill_attention"):
-            kv = (row[:, :rkv] @ a["W_kvb"]).reshape(t, h, nope + dv)
-            k_r = jnp.broadcast_to(
-                row[:, None, rkv:rkv + cfg.qk_rope_head_dim],
-                (t, h, cfg.qk_rope_head_dim))
-            q = jnp.concatenate([q_nope, q_rope], axis=-1)
-            k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
-            heads_first = lambda z: z.astype(jnp.float32).transpose(  # noqa: E731
-                1, 0, 2)[None]
-            out = exec_op("dot_product_attention", heads_first(q),
-                          heads_first(k), heads_first(kv[..., nope:]), m4,
-                          scaled=True, causal=True)   # softmax in float32
-            out = out[0].transpose(1, 0, 2).reshape(t, h * dv)
-        return out.astype(xn.dtype) @ a["W_o"]
+        return out
 
     for lp in params["layers"]:
         x, st = _layer(lp, x, cfg, attend, valid)
@@ -279,12 +219,6 @@ def longcat_decode_step(params, kv_pages, tokens, positions, page_table,
     against the latents, ``o_h = (sum p c) W_V,h``. Returns ``(kv_pages,
     logits (S, V), stats (L, count + 2))``; the statistics count the active
     slots' tokens only."""
-    from deeplearning4j_tpu.ops import exec_op
-
-    s_n = tokens.shape[0]
-    h, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
-    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(nope + cfg.qk_rope_head_dim)
     valid = seq_lens_incl > positions
     x = params["embed"][tokens]
     stats = []
@@ -292,17 +226,10 @@ def longcat_decode_step(params, kv_pages, tokens, positions, page_table,
 
         def attend(sub, a, xn, li=li):
             nonlocal kv_pages
-            q_nope, q_rope, row = _mla_inputs(a, xn, positions, cfg)
-            sub_layer = 2 * li + sub
-            kv_pages = kv_pages.at[sub_layer, 0, write_page,
-                                   write_offset].set(row)
-            w_kvb = a["W_kvb"].reshape(rkv, h, nope + dv)
-            q_abs = jnp.einsum("shn,rhn->shr", q_nope, w_kvb[..., :nope])
-            lat = exec_op("latent_decode_attention", q_abs, q_rope, kv_pages,
-                          page_table, seq_lens_incl, layer=sub_layer,
-                          scale=scale, value_width=rkv)
-            out = jnp.einsum("shr,rhv->shv", lat, w_kvb[..., nope:])
-            return out.reshape(s_n, h * dv) @ a["W_o"]
+            kv_pages, out = mla.decode_attention(
+                a, xn, positions, kv_pages, page_table, seq_lens_incl,
+                write_page, write_offset, 2 * li + sub, cfg.mla)
+            return out
 
         x, st = _layer(lp, x, cfg, attend, valid)
         stats.append(st)

@@ -35,11 +35,15 @@ class ServingPrograms(NamedTuple):
       write_offsets, page_size=)`` -> ``(kv_pages, greedy (S, B))``,
       optional: speculative decoding needs it.
 
-    ``stats`` is ``None`` or a small integer array ``(expert layers, held
-    experts + 2)`` (tokens a held expert, picks to zero experts, picks to
-    absent experts) that reaches the host in the read the step already makes
-    and is handed to ``note_stats(stats, span, decode_step=)`` (a model that
-    gives statistics says what they mean: ``observe.note_moe``)."""
+    ``stats`` is ``None`` or a small pytree of device arrays that reaches the
+    host in the read the step already makes and is handed, as numpy arrays,
+    to ``note_stats(stats, span, decode_step=)``: the engine never looks
+    inside it, the model that gives statistics says what each part means.
+    ``LongcatModel``: one integer array ``(expert layers, held experts + 2)``
+    (tokens a held expert, picks to zero experts, picks to absent experts)
+    for ``observe.note_moe``. ``XingModel``: a dict of that array (``"moe"``)
+    and two scalars of its hyper-connected residual (``"hc_residual"``,
+    ``"hc_clamped"``) for ``observe.note_hyper_connection``."""
 
     prefill: Callable
     decode_step: Callable

@@ -53,6 +53,7 @@ from deeplearning4j_tpu.observe.ledger import (
     signature_of,
 )
 
+from deeplearning4j_tpu.observe.hyper import note_hyper_connection
 from deeplearning4j_tpu.observe.moe import note_moe
 
 # short accessors — the names call sites use
@@ -299,5 +300,6 @@ __all__ = [
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
     "install_xla_listener", "scanned_call", "note_moe",
+    "note_hyper_connection",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

@@ -126,7 +126,8 @@ def moe_forward(mesh: Mesh, *, n_experts: int, capacity_factor: float = 1.25,
 
 
 def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
-                   scale: float, held, bias=None, valid=None):
+                   scale: float, held, bias=None, valid=None,
+                   score: str = "softmax", renormalise: bool = False):
     """One chip's share of a top-``k`` expert layer with zero-compute
     (identity) experts, for serving: no capacity and no dropped token,
     whatever the imbalance.
@@ -134,9 +135,11 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
     params: ``router`` (d, n_routed + n_zero) and the HELD experts' SwiGLU
     weights ``Wg``/``Wu`` (count, d, w) and ``Wd`` (count, w, d); u: (T, d);
     ``held = (first, count)``: the routed experts ``first .. first+count-1``
-    live here. The router's softmax (float32) runs over ALL outputs; the
+    live here. The router's scores ``s`` (float32) run over ALL outputs:
+    ``score="softmax"`` over them, or ``"sigmoid"`` of each logit alone; the
     chosen are the top ``k`` of ``s + bias`` (the bias moves the choice, not
-    the weight); a chosen output's weight is ``scale * s``, not renormalised.
+    the weight); a chosen output's weight is ``scale * s``, and with
+    ``renormalise`` ``scale * s / sum of the chosen s``, whoever holds them.
     The result is the partial sum this chip can give: its held experts' terms
     and the zero experts' ``w * u`` (an identity needs no owner); the absent
     experts' terms are left out. The (token, pick) rows of held experts are
@@ -146,22 +149,32 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
     runs over the head of the sorted rows where the held rows fit it (twice
     what even routing gives this rank) and over all of them where not.
 
+    ``u`` may be wider than the experts' weights (float32 beside bfloat16):
+    the router reads it as given, the experts read it in their weights' type,
+    and the result has ``u``'s type.
+
     ``valid``: (T,) bool, the tokens that count: padding and idle slots take
     no row of the grouped product (nobody reads their result: a prompt's 300
     padded positions are one token over and over, route alike and would
     swamp one expert) and stay out of the statistics. Returns ``(y (T, d), stats (count + 2,) int32)``:
     tokens a held expert, then the picks that went to zero experts and to
     absent experts."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score {score!r}: 'softmax' or 'sigmoid'")
     first, count = held
     t, d = u.shape
     with jax.named_scope("moe_route"):
         logits = jnp.dot(u.astype(jnp.float32),
                          params["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.softmax(logits, axis=-1)
+        s = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+             else jax.nn.sigmoid(logits))
         ranked = s if bias is None else s + bias.astype(jnp.float32)
         _, chosen = jax.lax.top_k(ranked, top_k)                  # (T, k)
-        weight = scale * jnp.take_along_axis(s, chosen, axis=-1)  # (T, k)
+        weight = jnp.take_along_axis(s, chosen, axis=-1)          # (T, k)
+        if renormalise:
+            weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+        weight = scale * weight
         is_zero = chosen >= n_routed
         local = chosen - first
         is_held = (local >= 0) & (local < count) & ~is_zero
@@ -188,12 +201,12 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
         def experts(n):
             """The first ``n`` sorted rows through the grouped products."""
             token, w_n = order[:n] // top_k, row_w[:n, None]
-            rows = u[token]
+            rows = u[token].astype(params["Wg"].dtype)
             hidden = (jax.nn.silu(jax.lax.ragged_dot(
                 rows, params["Wg"], sizes, preferred_element_type=jnp.float32))
                 * jax.lax.ragged_dot(rows, params["Wu"], sizes,
                                      preferred_element_type=jnp.float32))
-            out = jax.lax.ragged_dot(hidden.astype(u.dtype), params["Wd"],
+            out = jax.lax.ragged_dot(hidden.astype(rows.dtype), params["Wd"],
                                      sizes, preferred_element_type=jnp.float32)
             # a row past the groups holds whatever the product left there
             out = jnp.where(w_n != 0.0, out * w_n, 0.0)
