@@ -47,7 +47,7 @@ from deeplearning4j_tpu import observe
 from deeplearning4j_tpu.models import mla
 from deeplearning4j_tpu.models.mla import rms as _rms, swiglu as _swiglu
 from deeplearning4j_tpu.models.served import CacheRows, ServingPrograms
-from deeplearning4j_tpu.parallel.moe import moe_topk_share
+from deeplearning4j_tpu.parallel.moe import grouped_path, moe_topk_share
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,6 +258,17 @@ class LongcatModel:
         return longcat_programs(self.cfg)
 
 
+def note_longcat_stats(cfg: LongcatConfig, stats, span=None, *,
+                       decode_step: bool = False, tokens=None) -> None:
+    """The expert layers' integers go to ``observe.note_moe``, with how the
+    grouped products of a program over ``tokens`` token rows engaged."""
+    observe.note_moe(
+        stats, span, first_expert=cfg.held[0], decode_step=decode_step,
+        grouped=tokens and grouped_path(
+            stats, tokens, top_k=cfg.moe_topk,
+            outputs=cfg.n_routed_experts + cfg.zero_expert_num))
+
+
 def longcat_cache_rows(cfg: LongcatConfig) -> CacheRows:
     """One row a token an attention sub-layer, one side."""
     return CacheRows(layers=2 * cfg.num_layers, sides=1,
@@ -282,5 +293,4 @@ def longcat_programs(cfg: LongcatConfig) -> ServingPrograms:
 
     return ServingPrograms(
         prefill=prefill, decode_step=decode_step,
-        note_stats=functools.partial(observe.note_moe,
-                                     first_expert=cfg.held[0]))
+        note_stats=functools.partial(note_longcat_stats, cfg))

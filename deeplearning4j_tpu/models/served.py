@@ -37,8 +37,10 @@ class ServingPrograms(NamedTuple):
 
     ``stats`` is ``None`` or a small pytree of device arrays that reaches the
     host in the read the step already makes and is handed, as numpy arrays,
-    to ``note_stats(stats, span, decode_step=)``: the engine never looks
-    inside it, the model that gives statistics says what each part means.
+    to ``note_stats(stats, span, decode_step=, tokens=)`` (``tokens``: the
+    token rows the program ran over, the bank's slots or the padded prompt's
+    positions): the engine never looks inside it, the model that gives
+    statistics says what each part means.
     ``LongcatModel``: one integer array ``(expert layers, held experts + 2)``
     (tokens a held expert, picks to zero experts, picks to absent experts)
     for ``observe.note_moe``. ``XingModel``: a dict of that array (``"moe"``)

@@ -40,6 +40,7 @@ held (docs/SERVING.md).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -51,7 +52,7 @@ from deeplearning4j_tpu import observe
 from deeplearning4j_tpu.models import mla
 from deeplearning4j_tpu.models.mla import rms as _rms, swiglu as _swiglu
 from deeplearning4j_tpu.models.served import CacheRows, ServingPrograms
-from deeplearning4j_tpu.parallel.moe import moe_topk_share
+from deeplearning4j_tpu.parallel.moe import grouped_path, moe_topk_share
 
 _YARN = mla.Yarn(factor=64.0, original_max_position_embeddings=4096,
                  beta_fast=32.0, beta_slow=1.0, mscale=1.0,
@@ -360,13 +361,18 @@ def xing_decode_step(params, kv_pages, tokens, positions, page_table,
     return kv_pages, _logits(params, x, cfg), _statistics(moe, hc)
 
 
-def note_xing_stats(stats, span=None, *, decode_step: bool = False) -> None:
+def note_xing_stats(cfg: XingConfig, stats, span=None, *,
+                    decode_step: bool = False, tokens=None) -> None:
     """What the parts of a program's statistics mean: the expert layers'
     integers go to ``observe.note_moe`` (every expert is held: the first is
-    0 and no pick is absent), the hyper-connection's two numbers to
+    0 and no pick is absent) with how the grouped products of a program over
+    ``tokens`` token rows engaged, the hyper-connection's two numbers to
     ``observe.note_hyper_connection``."""
-    observe.note_moe(stats["moe"], span, first_expert=0,
-                     decode_step=decode_step)
+    observe.note_moe(
+        stats["moe"], span, first_expert=0, decode_step=decode_step,
+        grouped=tokens and grouped_path(
+            stats["moe"], tokens, top_k=cfg.num_experts_per_tok,
+            outputs=cfg.n_routed_experts))
     observe.note_hyper_connection(stats["hc_residual"], stats["hc_clamped"],
                                   span)
 
@@ -415,4 +421,4 @@ def xing_programs(cfg: XingConfig) -> ServingPrograms:
             write_page, write_offset, cfg)
 
     return ServingPrograms(prefill=prefill, decode_step=decode_step,
-                           note_stats=note_xing_stats)
+                           note_stats=functools.partial(note_xing_stats, cfg))

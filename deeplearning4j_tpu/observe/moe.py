@@ -17,14 +17,17 @@ _LOAD_BOUNDS = (1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0,
 
 
 def note_moe(stats, span=None, *, first_expert: int = 0,
-             decode_step: bool = False) -> dict:
+             decode_step: bool = False, grouped=None) -> dict:
     """``stats``: (expert layers, held experts + 2) integers — tokens a held
     expert, then the picks to zero experts and to absent experts, a layer.
     Counts them (``dl4j_tpu_moe_picks_total{kind}``,
     ``dl4j_tpu_moe_expert_tokens_total{layer, expert}``), observes the load
     ratio of a decode step (``dl4j_tpu_moe_load_max_over_mean``) and sets
     ``moe_held``/``moe_zero``/``moe_absent``/``moe_max_over_mean`` on
-    ``span``. Returns those four."""
+    ``span``. ``grouped``: ``(path, tile)`` as ``parallel.moe.grouped_path``
+    gives them, how the program's grouped products engaged: counted
+    (``dl4j_tpu_moe_grouped_steps_total{path, tile}``) and set on ``span`` as
+    ``moe_path``/``moe_tile``. Returns what it set."""
     stats = np.asarray(stats)
     per_expert = stats[:, :-2]
     held, zero, absent = (int(per_expert.sum()), int(stats[:, -2].sum()),
@@ -45,6 +48,11 @@ def note_moe(stats, span=None, *, first_expert: int = 0,
                     bounds=_LOAD_BOUNDS).observe(ratio)
     out = {"moe_held": held, "moe_zero": zero, "moe_absent": absent,
            "moe_max_over_mean": round(ratio, 4)}
+    if grouped is not None:
+        path, tile = grouped
+        m.counter("dl4j_tpu_moe_grouped_steps_total", path=path,
+                  tile=str(tile)).inc()
+        out.update(moe_path=path, moe_tile=int(tile))
     if span is not None:
         span.set(**out)
     return out

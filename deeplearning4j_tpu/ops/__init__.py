@@ -22,6 +22,7 @@ from deeplearning4j_tpu.ops.weight_init import init_weights
 # OpRegistrator static init). Deferred import keeps pallas optional.
 from deeplearning4j_tpu.ops import tuning
 from deeplearning4j_tpu.ops.pallas_attention import register_platform_attention
+from deeplearning4j_tpu.ops.pallas_grouped import register_platform_grouped
 from deeplearning4j_tpu.ops.pallas_matmul import register_platform_fused_matmul
 from deeplearning4j_tpu.ops.pallas_layernorm import (
     register_platform_fused_layernorm)
@@ -30,6 +31,7 @@ from deeplearning4j_tpu.ops.pallas_updater import (
 from deeplearning4j_tpu.ops.quantized import register_platform_quantized
 
 register_platform_attention()
+register_platform_grouped()
 register_platform_fused_matmul()
 register_platform_fused_layernorm()
 register_platform_fused_updater()

@@ -17,6 +17,9 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from deeplearning4j_tpu.ops import exec_op
+from deeplearning4j_tpu.ops.pallas_grouped import grouped_row_tile
+
 
 def init_moe_params(key, n_experts: int, d_model: int, d_hidden: int,
                     dtype=jnp.float32):
@@ -125,6 +128,32 @@ def moe_forward(mesh: Mesh, *, n_experts: int, capacity_factor: float = 1.25,
 # ---------------------------------------------------------------------------
 
 
+def grouped_rows(t: int, top_k: int, count: int, outputs: int):
+    """``(fit, n_rows)`` of a step of ``t`` tokens: all its (token, pick)
+    rows, and the head of the sorted rows that the grouped products take
+    while the held rows fit it: twice what even routing over ``outputs``
+    router outputs gives the ``count`` held experts, in whole 128-row
+    tiles. ``fit >= n_rows``: there is no head, every step takes all rows."""
+    n_rows = t * top_k
+    twice = -(-2 * n_rows * count // outputs)
+    return 128 * -(-twice // 128), n_rows
+
+
+def grouped_path(stats, t: int, *, top_k: int, outputs: int):
+    """``(path, tile)`` of a program's grouped products, on the host, from
+    its expert layers' statistics (``stats``: (layers, held experts + 2), the
+    tokens a held expert first: what the step's read already brings) and the
+    shapes ``moe_topk_share`` saw (``t`` tokens, ``top_k`` of ``outputs``
+    router outputs): ``"head"`` where every layer's held rows fitted the
+    head of the sorted rows, ``"all"`` where a layer took all ``t * top_k``
+    (or there is no head), and the row tile the products of that many rows
+    ran with (``grouped_row_tile``)."""
+    held = np.asarray(stats)[:, :-2]
+    fit, n_rows = grouped_rows(t, top_k, held.shape[1], outputs)
+    head = fit < n_rows and int(held.sum(axis=1).max(initial=0)) <= fit
+    return "head" if head else "all", grouped_row_tile(fit if head else n_rows)
+
+
 def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
                    scale: float, held, bias=None, valid=None,
                    score: str = "softmax", renormalise: bool = False):
@@ -143,9 +172,10 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
     The result is the partial sum this chip can give: its held experts' terms
     and the zero experts' ``w * u`` (an identity needs no owner); the absent
     experts' terms are left out. The (token, pick) rows of held experts are
-    sorted by expert and go through ONE grouped product a matrix
-    (``jax.lax.ragged_dot``): every shape is static, so the caller's program
-    compiles once, and a row is computed by its own expert only. The product
+    sorted by expert and go through the registry's ``grouped_swiglu``
+    (``ops/pallas_grouped.py``: row tiles that fit the rows a group holds):
+    every shape is static, so the caller's program compiles once, and a row
+    is computed by its own expert only. The product
     runs over the head of the sorted rows where the held rows fit it (twice
     what even routing gives this rank) and over all of them where not.
 
@@ -202,12 +232,8 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
             """The first ``n`` sorted rows through the grouped products."""
             token, w_n = order[:n] // top_k, row_w[:n, None]
             rows = u[token].astype(params["Wg"].dtype)
-            hidden = (jax.nn.silu(jax.lax.ragged_dot(
-                rows, params["Wg"], sizes, preferred_element_type=jnp.float32))
-                * jax.lax.ragged_dot(rows, params["Wu"], sizes,
-                                     preferred_element_type=jnp.float32))
-            out = jax.lax.ragged_dot(hidden.astype(rows.dtype), params["Wd"],
-                                     sizes, preferred_element_type=jnp.float32)
+            out = exec_op("grouped_swiglu", rows, params["Wg"], params["Wu"],
+                          params["Wd"], sizes)
             # a row past the groups holds whatever the product left there
             out = jnp.where(w_n != 0.0, out * w_n, 0.0)
             return jnp.zeros((t, d), jnp.float32).at[token].add(out)
@@ -218,9 +244,7 @@ def moe_topk_share(params, u, *, top_k: int, n_routed: int, n_zero: int,
         # so it is given twice the expected rows (whole 128-row tiles) where
         # the held rows fit, and all t * k rows where they do not: nothing is
         # dropped whatever the imbalance, and every shape is static.
-        n_rows = t * top_k
-        twice = -(-2 * n_rows * count // (n_routed + n_zero))
-        fit = 128 * -(-twice // 128)
+        fit, n_rows = grouped_rows(t, top_k, count, n_routed + n_zero)
         if fit < n_rows:
             y = y + jax.lax.cond(jnp.sum(sizes) <= fit,
                                  lambda: experts(fit), lambda: experts(n_rows))

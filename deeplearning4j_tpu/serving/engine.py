@@ -1402,7 +1402,8 @@ class GenerativeEngine:
                                    category="serving"):
             toks, stats = jax.device_get((step.toks, step.stats))
         if stats is not None:
-            self.programs.note_stats(stats, step.span, decode_step=True)
+            self.programs.note_stats(stats, step.span, decode_step=True,
+                                     tokens=len(toks))
         return toks
 
     def _commit_decode(self, step: _DecodeStep, toks: np.ndarray,
@@ -1461,7 +1462,7 @@ class GenerativeEngine:
             sp = tracer.span("serving_prefill", category="serving",
                              **adm.args)
             if stats is not None:
-                self.programs.note_stats(stats, sp)
+                self.programs.note_stats(stats, sp, tokens=self.max_prompt)
             pid = tracer.complete_between(
                 sp.name, adm.launch[0], now, category=sp.category,
                 parent=adm.parent, **sp.args)

@@ -274,8 +274,14 @@ def test_counters_and_compile_once(shared):
     spans = [e for e in observe.tracer().to_dict()["traceEvents"]
              if e["name"] in ("serving_decode", "serving_prefill")]
     assert spans and all({"moe_held", "moe_zero", "moe_absent",
-                          "moe_max_over_mean"} <= set(e["args"])
-                         for e in spans)
+                          "moe_max_over_mean", "moe_path", "moe_tile"}
+                         <= set(e["args"]) for e in spans)
+    # how the grouped products engaged, a program: at these sizes every
+    # (token, pick) row fits one chunk and there is no head to fit
+    grouped = {k: v["value"] for k, v in snap.items()
+               if k.startswith("dl4j_tpu_moe_grouped_steps_total")}
+    assert sum(grouped.values()) == len(spans)
+    assert all('path="all"' in k for k in grouped), grouped
     events = observe.ledger().events()
     assert not [e for e in events if e.cause == "new_shape"], events
     first = [e.key for e in events if e.cause == "first_compile"

@@ -291,6 +291,32 @@ def test_xing_serving_programs_update_the_pool_in_place(one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
 
 
+def test_the_grouped_products_are_the_kernels_at_both_cells(one_chip,
+                                                           monkeypatch):
+    """The expert layers' grouped products in the compiled ``decode`` and
+    ``prefill`` of both latent models are the registry's Pallas kernels
+    under their own names, a gate-and-up and a down call an expert layer and
+    branch (LongCat's conditional holds the head's and all rows'), over the
+    rows the shape rule says; XLA's ``ragged_dot``, which pays a 512-row
+    tile for every expert that holds a row, is in neither (PERF.md, PR 32:
+    15 such calls were 22 of a 31 ms decode program)."""
+    for which, rows in (("xing", {"decode": [512] * 5, "prefill": [2048] * 5}),
+                        ("latent", {"decode": [128, 1536] * 4,
+                                    "prefill": [256, 6144] * 4})):
+        _pool, compiled, prefill = _latent_programs(one_chip, monkeypatch,
+                                                    which)
+        for name, text in (("decode", compiled["decode"].as_text()),
+                           ("prefill", prefill.compile().as_text())):
+            assert "ragged-dot" not in text, (which, name)
+            for kernel, dt in (("grouped_gate_up", "bf16"),
+                               ("grouped_down", "f32")):
+                got = re.findall(
+                    rf"%{kernel}[.\d]* = {dt}\[(\d+),\d+\]\S* custom-call\(.*"
+                    r'custom_call_target="tpu_custom_call"', text)
+                assert sorted(map(int, got)) == sorted(rows[name]), (
+                    which, name, kernel, got)
+
+
 def _unconditional(text):
     """The lines of a compiled module that run whenever the program does:
     the entry computation and every computation reached from it other than

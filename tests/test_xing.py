@@ -402,6 +402,13 @@ def test_counters_span_arguments_and_compile_once(model):
     assert all({"moe_held", "moe_zero", "moe_absent", "moe_max_over_mean",
                 "hc_residual", "hc_clamped"} <= set(e["args"]) for e in steps)
     assert all(0 <= e["args"]["hc_residual"] < 0.1 for e in steps)
+    # every expert is held, so no program has a head to fit: all rows, in
+    # one chunk at these sizes (a decode step: 3 slots x 4 picks)
+    assert all(e["args"]["moe_path"] == "all" for e in steps)
+    assert {e["args"]["moe_tile"] for e in steps
+            if e["name"] == "serving_decode"} == {12}
+    assert sum(v["value"] for k, v in snap.items() if k.startswith(
+        "dl4j_tpu_moe_grouped_steps_total")) == len(steps)
     assert snap["dl4j_tpu_hc_sinkhorn_residual"]["count"] == len(steps)
     assert snap["dl4j_tpu_hc_clamped_total"]["value"] == sum(
         e["args"]["hc_clamped"] for e in steps) == 0
