@@ -1,5 +1,6 @@
 """Latent attention (MLA): the pieces every served latent-attention model
-calls (``models/longcat.py``, ``models/xing.py``).
+calls (``models/longcat.py``, ``models/xing.py``); its norm, rotation and
+gated feed-forward also serve ``models/brumby.py``.
 
 Queries go through a low-rank ``q_rank`` bottleneck, keys and values through
 ONE shared latent of ``kv_rank`` values a token plus a rotary key of ``rope``
@@ -128,12 +129,15 @@ def inv_freq(n: int, theta: float, yarn: Optional[Yarn] = None):
     return inv / yarn.factor * (1.0 - keep) + inv * keep
 
 
-def rope(x, pos, theta, yarn: Optional[Yarn] = None):
-    """Rotate the interleaved pairs (x[2i], x[2i+1]) of the last axis by
-    ``pos * inv_freq[i]``, in float32. x: (..., n) with leading axes those
-    of ``pos`` and then any others. With ``yarn`` the frequencies are YaRN's
-    and the cosines and sines take its ``mscale(factor, mscale) /
-    mscale(factor, mscale_all_dim)``."""
+def rope(x, pos, theta, yarn: Optional[Yarn] = None,
+         pairing: str = "interleaved"):
+    """Rotate pairs of the last axis by ``pos * inv_freq[i]``, in float32:
+    the interleaved pairs (x[2i], x[2i+1]) (the DeepSeek convention), or
+    with ``pairing="half"`` the pairs (x[i], x[i + n/2]) (the Llama and Qwen
+    convention). x: (..., n) with leading axes those of ``pos`` and then any
+    others. With ``yarn`` the frequencies are YaRN's and the cosines and
+    sines take its ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
     n = x.shape[-1]
     inv = inv_freq(n, theta, yarn)
     ang = pos.astype(jnp.float32)[..., None] * inv
@@ -144,6 +148,13 @@ def rope(x, pos, theta, yarn: Optional[Yarn] = None):
                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
         if size != 1.0:
             cos, sin = cos * size, sin * size
+    if pairing == "half":
+        x32 = x.astype(jnp.float32)
+        lo, hi = x32[..., :n // 2], x32[..., n // 2:]
+        return jnp.concatenate([lo * cos - hi * sin, lo * sin + hi * cos],
+                               axis=-1).astype(x.dtype)
+    if pairing != "interleaved":
+        raise ValueError(f"unknown rotary pairing {pairing!r}")
     pair = x.astype(jnp.float32).reshape(x.shape[:-1] + (n // 2, 2))
     even, odd = pair[..., 0], pair[..., 1]
     return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],

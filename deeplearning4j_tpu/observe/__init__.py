@@ -55,6 +55,7 @@ from deeplearning4j_tpu.observe.ledger import (
 
 from deeplearning4j_tpu.observe.hyper import note_hyper_connection
 from deeplearning4j_tpu.observe.moe import note_moe
+from deeplearning4j_tpu.observe.retention import note_retention
 
 # short accessors — the names call sites use
 metrics = default_registry
@@ -300,6 +301,6 @@ __all__ = [
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
     "install_xla_listener", "scanned_call", "note_moe",
-    "note_hyper_connection",
+    "note_hyper_connection", "note_retention",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

@@ -23,6 +23,7 @@ from deeplearning4j_tpu.ops.weight_init import init_weights
 from deeplearning4j_tpu.ops import tuning
 from deeplearning4j_tpu.ops.pallas_attention import register_platform_attention
 from deeplearning4j_tpu.ops.pallas_grouped import register_platform_grouped
+from deeplearning4j_tpu.ops.pallas_retention import register_platform_retention
 from deeplearning4j_tpu.ops.pallas_matmul import register_platform_fused_matmul
 from deeplearning4j_tpu.ops.pallas_layernorm import (
     register_platform_fused_layernorm)
@@ -32,6 +33,7 @@ from deeplearning4j_tpu.ops.quantized import register_platform_quantized
 
 register_platform_attention()
 register_platform_grouped()
+register_platform_retention()
 register_platform_fused_matmul()
 register_platform_fused_layernorm()
 register_platform_fused_updater()

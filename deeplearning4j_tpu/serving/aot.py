@@ -205,7 +205,10 @@ def warm_boot(engine, cache: Optional[ExportCache] = None) -> Dict[str, Any]:
 def maybe_warm_boot(engine) -> Dict[str, Any]:
     """Env-gated :func:`warm_boot` — inert unless
     ``$DL4J_TPU_COMPILE_CACHE`` is set, so default construction (tests,
-    unconfigured deployments) pays nothing."""
-    if not os.environ.get(ENV_DIR):
+    unconfigured deployments) pays nothing; inert too for an engine whose
+    cache is a state a slot (``models/served.py`` ``SlotState``)."""
+    if not os.environ.get(ENV_DIR) or not hasattr(engine.cache, "page_table"):
+        # the export table describes the paged programs: an engine over a
+        # pool of slot states compiles as it goes
         return {"restored": [], "exported": [], "fingerprint": None}
     return warm_boot(engine)

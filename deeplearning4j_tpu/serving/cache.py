@@ -222,6 +222,15 @@ class PagedKVCache:
         self.seq_lens[slot] = 0
         return released
 
+    def decode_args(self) -> tuple:
+        """What a decode launch takes of the host's tables (copies: they
+        change in place while the launch is in flight)."""
+        return (self.page_table.copy(), self.seq_lens.copy())
+
+    def write_args(self, slot: int, prompt_len: int) -> tuple:
+        """Where an admission's write puts the prefill's rows."""
+        return (self.page_table[slot].copy(), np.int32(prompt_len))
+
     def reset_kv(self) -> None:
         """Reallocate the device page pool (supervised crash recovery): a
         decode step that died mid-call may have consumed the DONATED kv
@@ -266,3 +275,101 @@ class PagedKVCache:
                     f"page {p}: refcount {self.refcount[p]} != "
                     f"{holders.get(p, 0)} slot holders + "
                     f"{tree_refs.get(p, 0)} tree refs")
+
+
+class SlotStatePool:
+    """The cache of a model whose state is a fixed size a SLOT
+    (``models/served.py`` ``SlotState``: a recurrence, not attention over a
+    context): a dict of device arrays, each ``(max_slots,) + shape`` in the
+    dtype the MODEL names, allocated once, donated through every serving
+    program and updated where it lies. There are no pages: a slot IS where
+    its state lies, so admission needs a slot and nothing else, and a
+    sequence's only limit is ``page_size * max_pages_per_seq`` positions,
+    the same two constructor arguments that bound a paged sequence.
+
+    A freed slot's state is dead: nothing reads it (a decode step leaves an
+    inactive slot's state as it is and drops its output), and the next
+    admission's write replaces every value of it, which is the reset.
+
+    The host-side surface is what the engine asks of ``PagedKVCache``:
+    ``kv`` (the pool), ``seq_lens``, ``ensure_capacity``, ``free_slot``,
+    ``reset_kv``, ``check_invariants``, ``max_context``; the page counts
+    read 0 (nothing to allocate, nothing to run out of).
+
+    Invariants: ``kv`` holds exactly the geometry's arrays at
+    ``(max_slots,) + shape`` in their dtypes; ``0 <= seq_lens[slot] <=
+    max_context()``; a freed slot's length is 0."""
+
+    # no page is allocated, shared or written: what the engine reads of a
+    # paged pool's accounting
+    num_pages = 0
+    free_pages = 0
+
+    def __init__(self, *, state, page_size: int = 16, max_slots: int = 4,
+                 max_pages_per_seq: int = 8):
+        if page_size <= 0 or max_pages_per_seq <= 0 or max_slots <= 0:
+            raise ValueError("page_size, max_pages_per_seq and max_slots "
+                             "must be positive")
+        if not state.arrays:
+            raise ValueError("a slot's state needs at least one array")
+        self.arrays = {name: (tuple(int(n) for n in shape), jnp.dtype(dtype))
+                       for name, (shape, dtype) in state.arrays.items()}
+        self.page_size = int(page_size)
+        self.max_slots = int(max_slots)
+        self.max_pages_per_seq = int(max_pages_per_seq)
+        self.seq_lens = np.zeros((self.max_slots,), np.int32)
+        self.reset_kv()
+
+    def pages_for(self, n_tokens: int) -> int:
+        """A sequence of any length takes no page."""
+        return 0
+
+    def max_context(self) -> int:
+        """Longest sequence one slot may hold: the paged pool's arithmetic
+        from the same arguments (no page is allocated for it)."""
+        return self.max_pages_per_seq * self.page_size
+
+    def ensure_capacity(self, slot: int, n_tokens: int) -> str:
+        """``"ok"``, or ``"overflow"`` past :meth:`max_context`; a state
+        does not grow, so there is no ``"oom"`` but the injected one."""
+        if faults.should_fire("page_oom"):
+            return "oom"
+        return "overflow" if n_tokens > self.max_context() else "ok"
+
+    def free_slot(self, slot: int) -> int:
+        """The slot's state is dead from here (see the class docstring).
+        Returns 0: no page reference to release."""
+        self.seq_lens[slot] = 0
+        return 0
+
+    def reset_kv(self) -> None:
+        """Reallocate the pool (at start, and after a crash that may have
+        consumed the DONATED buffers): same shapes and dtypes, so the
+        engine's compiled programs stay valid."""
+        self.kv = {name: jnp.zeros((self.max_slots,) + shape, dtype)
+                   for name, (shape, dtype) in self.arrays.items()}
+
+    def decode_args(self) -> tuple:
+        """What a decode launch takes of the host's tables (a copy: they
+        change in place while the launch is in flight)."""
+        return (self.seq_lens.copy(),)
+
+    def write_args(self, slot: int, prompt_len: int) -> tuple:
+        """Where an admission's write puts the prefill's state."""
+        return (np.int32(slot),)
+
+    def check_invariants(self, tree_refs=None) -> None:
+        assert tree_refs is None, "a slot-state pool shares no pages"
+        assert set(self.kv) == set(self.arrays), (
+            f"pool holds {sorted(self.kv)}, the geometry names "
+            f"{sorted(self.arrays)}")
+        for name, (shape, dtype) in self.arrays.items():
+            got = self.kv[name]
+            assert got.shape == (self.max_slots,) + shape and \
+                got.dtype == dtype, (
+                    f"pool array {name!r} is {got.dtype}{got.shape}, the "
+                    f"geometry says {dtype}{(self.max_slots,) + shape}")
+        assert np.all((self.seq_lens >= 0)
+                      & (self.seq_lens <= self.max_context())), (
+            f"sequence lengths {self.seq_lens} outside "
+            f"[0, {self.max_context()}]")

@@ -95,7 +95,8 @@ import numpy as np
 
 from deeplearning4j_tpu import faults, observe
 from deeplearning4j_tpu.ops.pallas_attention import gather_pages
-from deeplearning4j_tpu.serving.cache import PagedKVCache
+from deeplearning4j_tpu.models.served import SlotState
+from deeplearning4j_tpu.serving.cache import PagedKVCache, SlotStatePool
 from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
 from deeplearning4j_tpu.serving.speculative import SpeculativeDecoder
 from deeplearning4j_tpu.serving.sampling import (
@@ -201,10 +202,41 @@ def build_decode(decode_step, page: int, trash: int):
     return decode
 
 
+def build_state_write():
+    """The jitted ``write_prompt`` of a model whose cache is a state a slot
+    (``models/served.py`` ``SlotState``): a prefill's state, every array of
+    it whole, takes slot ``slot``'s place in the donated pool, in place.
+    Whatever the slot held before is gone: this write is its reset."""
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def write_prompt(pool, state, slot):
+        return {name: jax.lax.dynamic_update_index_in_dim(
+            pool[name], state[name].astype(pool[name].dtype), slot, 0)
+            for name in pool}
+
+    return write_prompt
+
+
+def build_state_decode(decode_step):
+    """The jitted ``decode`` over a pool of slot states: :func:`build_decode`
+    without the page table. A slot that is not active keeps its state (the
+    model's ``decode_step`` sees to it) and its token."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode(params, pool, seq_lens, tokens, active, key, temp, top_k,
+               top_p):
+        on = active > 0
+        pool, logits, stats = decode_step(params, pool, tokens, seq_lens, on)
+        toks = sample_tokens(logits, key, temp, top_k, top_p)
+        return pool, jnp.where(on, toks, tokens), logits, stats
+
+    return decode
+
+
 class GenerativeEngine:
     """Continuous-batching text generation over any model handle that
-    speaks the protocol of ``models/served.py`` (``GptModel``,
-    ``LongcatModel``).
+    speaks the protocol of ``models/served.py``, with rows a token in pages
+    or a state a slot, as its ``cache_rows()`` says.
 
     Synchronous use (tests, batch jobs)::
 
@@ -258,12 +290,19 @@ class GenerativeEngine:
             # the slot bank by default.
             num_pages = max_slots * max_pages_per_seq + max(0, prefix_pages)
         rows = model.cache_rows()
-        self.cache = PagedKVCache(
-            layers=rows.layers, sides=rows.sides, row_width=rows.width,
-            page_size=page_size,
-            num_pages=num_pages, max_slots=max_slots,
-            max_pages_per_seq=max_pages_per_seq,
-            dtype=jax.tree.leaves(model.params)[0].dtype)
+        if isinstance(rows, SlotState):
+            # a state a slot: no page to hand out, every array in the dtype
+            # the model names; the context limit is the paged arithmetic
+            self.cache = SlotStatePool(
+                state=rows, page_size=page_size, max_slots=max_slots,
+                max_pages_per_seq=max_pages_per_seq)
+        else:
+            self.cache = PagedKVCache(
+                layers=rows.layers, sides=rows.sides, row_width=rows.width,
+                page_size=page_size,
+                num_pages=num_pages, max_slots=max_slots,
+                max_pages_per_seq=max_pages_per_seq,
+                dtype=jax.tree.leaves(model.params)[0].dtype)
         if self.max_prompt + 1 > self.cache.max_context():
             raise ValueError(
                 f"max_prompt={max_prompt} + 1 exceeds per-slot context "
@@ -447,6 +486,8 @@ class GenerativeEngine:
         return build_prefill(self.programs.prefill)
 
     def _build_write(self):
+        if isinstance(self.cache, SlotStatePool):
+            return build_state_write()
         return build_write(self.cache.page_size, self.cache.trash_page)
 
     def _build_suffix(self):
@@ -496,6 +537,8 @@ class GenerativeEngine:
         return suffix_prefill
 
     def _build_decode(self):
+        if isinstance(self.cache, SlotStatePool):
+            return build_state_decode(self.programs.decode_step)
         return build_decode(self.programs.decode_step, self.cache.page_size,
                             self.cache.trash_page)
 
@@ -1354,8 +1397,8 @@ class GenerativeEngine:
             # Copies, because the cache updates its tables in place. What
             # seldom changes stays on the device, and the tokens never
             # leave it: the bank's vector is the launches' own result
-            args = (cache.page_table.copy(), cache.seq_lens.copy(),
-                    self._toks, self._resident("active", act))
+            args = (*cache.decode_args(), self._toks,
+                    self._resident("active", act))
             sampling = (self._resident("temperature", temp),
                         self._resident("top_k", top_k),
                         self._resident("top_p", top_p))
@@ -1366,8 +1409,8 @@ class GenerativeEngine:
             observe.note_jit_signature(
                 self._decode_fn, graph="serving", key="decode",
                 signature=observe.signature_of(
-                    page_table=cache.page_table, seq_lens=cache.seq_lens,
-                    tokens=self._toks, active=act))
+                    page_table=getattr(cache, "page_table", None),
+                    seq_lens=cache.seq_lens, tokens=self._toks, active=act))
         # None in a synchronous iteration: it reads what it launches
         before = self._flying
         t0 = time.perf_counter()
@@ -1605,9 +1648,8 @@ class GenerativeEngine:
             self._resident("prefill_top_p", np.asarray(
                 [req.top_p], np.float32)),
             self._toks, np.int32(slot))
-        cache.kv = self._write_fn(
-            cache.kv, kv_prompt, cache.page_table[slot].copy(),
-            np.int32(p_len))
+        cache.kv = self._write_fn(cache.kv, kv_prompt,
+                                  *cache.write_args(slot, p_len))
         return (tok, stats, (t0, time.perf_counter()),
                 dict(prompt_len=p_len, request=req.request_id))
 

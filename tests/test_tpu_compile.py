@@ -291,6 +291,102 @@ def test_xing_serving_programs_update_the_pool_in_place(one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
 
 
+# Brumby's cell (benchmarks/traffic/reason-closed-32.json): five layers at
+# the published widths, the whole vocabulary, 32 slots, prompts bucketed to
+# 512.
+BR_SLOTS, BR_MAX_PROMPT = 32, 512
+
+
+def _state_programs(one_chip, monkeypatch):
+    """Brumby's ``decode``, state ``write_prompt`` and ``prefill`` from
+    shapes alone (6.41 GB of weights and a 5.5 GB pool of slot states, which
+    this host never makes). Returns (pool shapes, compiled, prefill)."""
+    import importlib
+
+    if "brumby" in _COMPILED:
+        return _COMPILED["brumby"]
+
+    from deeplearning4j_tpu.models import brumby
+    from deeplearning4j_tpu.ops import tuning
+    from deeplearning4j_tpu.serving.engine import (
+        build_prefill, build_state_decode, build_state_write)
+
+    registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(tuning, "current_device_kind", lambda: "tpu_v5_lite")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cfg = brumby.BrumbyConfig(num_hidden_layers=5)
+    geo = brumby.brumby_slot_state(cfg).arrays
+    assert geo == {"S": ((5, 8, 65, 128, 128), "float32"),
+                   "z": ((5, 8, 65, 128), "float32")}
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    pool = {n: sds((BR_SLOTS,) + shape, dt) for n, (shape, dt) in geo.items()}
+    state = {n: sds(shape, dt) for n, (shape, dt) in geo.items()}
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: brumby.init_brumby_params(
+            jax.random.key(0), cfg, bf16)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    slot = lambda dt: sds((BR_SLOTS,), dt)  # noqa: E731
+    programs = brumby.brumby_programs(cfg)
+    compiled = {
+        "decode": build_state_decode(programs.decode_step).lower(
+            params, pool, slot(i32), slot(i32), slot(i32), key, slot(f32),
+            slot(i32), slot(f32)).compile(),
+        "write_prompt": build_state_write().lower(
+            pool, state, sds((), i32)).compile(),
+    }
+    prefill = build_prefill(programs.prefill).lower(
+        params, sds((1, BR_MAX_PROMPT), i32), sds((), i32), key,
+        sds((1,), f32), sds((1,), i32), sds((1,), f32), slot(i32),
+        sds((), i32))
+    _COMPILED["brumby"] = (pool, compiled, prefill)
+    return _COMPILED["brumby"]
+
+
+def test_brumby_serving_programs_update_the_state_pool_in_place(one_chip,
+                                                                monkeypatch):
+    """Brumby's ``decode`` and state ``write_prompt`` at its cell's geometry
+    (five layers, 40 heads over 8, the whole vocabulary, 32 slots of 171.7 MB
+    of float32 state): both alias the whole pool input to output and make
+    nothing pool-sized (a second copy is 5.5 GB: out of memory beside 6.41
+    GB of weights); the retention kernel is on its Pallas path once a layer
+    under its own name over the whole pool; ``prefill`` compiles and fits
+    beside the pool too."""
+    pool, compiled, prefill = _state_programs(one_chip, monkeypatch)
+    pool_bytes = sum(math.prod(a.shape) * 4 for a in pool.values())
+    assert pool_bytes == 32 * 5 * 8 * 65 * 129 * 128 * 4
+    n_state = math.prod(pool["S"].shape)
+    result = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                        r"([\w\-]+)\(")
+    in_place = ("parameter", "dynamic-update-slice", "fusion",
+                "get-tuple-element", "bitcast")
+    for name, c in compiled.items():
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool_bytes, (name, mem)
+        assert mem.temp_size_in_bytes < 0.1 * pool_bytes, (name, mem)
+        for line in c.as_text().splitlines():
+            m = result.match(line)
+            if m and math.prod(int(d) for d in m.group(1).split(",")
+                               if d) == n_state:
+                assert m.group(2) in in_place, (name, line[:200])
+    decode = compiled["decode"].as_text()
+    kernels = re.findall(r"%retention_decode[.\d]* = \((\S+) .*"
+                         r'custom_call_target="tpu_custom_call".*', decode)
+    assert len(kernels) == 5
+    assert all(k.startswith("f32[32,5,8,65,128,128]") for k in kernels)
+    mem = compiled["decode"].memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
+    mem = prefill.compile().memory_analysis()
+    # the pool is not prefill's argument, but it is on the chip meanwhile
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes + pool_bytes) < 15.7e9
+
+
 def test_the_grouped_products_are_the_kernels_at_both_cells(one_chip,
                                                            monkeypatch):
     """The expert layers' grouped products in the compiled ``decode`` and
