@@ -14,7 +14,10 @@ One metric model for the whole framework:
 * :func:`log_event` — JSONL event log, enabled by ``DL4J_TPU_OBS_LOG=path``.
 * :class:`scanned_call` — the one record every scanned trainer keeps of a
   call: span ``fit_scanned`` with its ``fit_scanned_dispatch`` /
-  ``fit_scanned_read`` children, and the step and example counters.
+  ``fit_scanned_read`` children, each with the host's accounting, and the
+  step and example counters.
+* :func:`install_gc_hook` — the collector's passes as the accounting
+  spans' ``gc_ms``, ``host_gc`` spans and ``dl4j_tpu_gc_pause_seconds``.
 * :func:`summary` — the compact snapshot ``tools/obsreport.py`` prints.
 
 This package imports neither jax nor the model runtimes — it is safe to
@@ -53,6 +56,8 @@ from deeplearning4j_tpu.observe.ledger import (
     signature_of,
 )
 
+from deeplearning4j_tpu.observe import host as _host
+from deeplearning4j_tpu.observe.host import install_gc_hook
 from deeplearning4j_tpu.observe.hyper import note_hyper_connection
 from deeplearning4j_tpu.observe.moe import note_moe
 from deeplearning4j_tpu.observe.retention import note_retention
@@ -69,6 +74,7 @@ def reset() -> None:
     reset_default_tracer()
     reset_default_ledger()
     reset_log_state()
+    _host.reset()
 
 
 _SCANNED_CALLS = itertools.count(1)
@@ -94,7 +100,7 @@ class scanned_call:
 
     def __enter__(self) -> "scanned_call":
         self._span = tracer().span(
-            "fit_scanned", category="train", model=self.model,
+            "fit_scanned", category="train", host=True, model=self.model,
             steps=self.steps, call=next(_SCANNED_CALLS))
         self._span.__enter__()
         return self
@@ -110,10 +116,11 @@ class scanned_call:
         return False
 
     def dispatch(self) -> Span:
-        return tracer().span("fit_scanned_dispatch", category="train")
+        return tracer().span("fit_scanned_dispatch", category="train",
+                             host=True)
 
     def read(self) -> Span:
-        return tracer().span("fit_scanned_read", category="train")
+        return tracer().span("fit_scanned_read", category="train", host=True)
 
 
 def _ms(seconds) -> Any:
@@ -300,7 +307,7 @@ __all__ = [
     "CompileEvent", "RecompileLedger", "OBS_LOG_ENV",
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
-    "install_xla_listener", "scanned_call", "note_moe",
+    "install_xla_listener", "install_gc_hook", "scanned_call", "note_moe",
     "note_hyper_connection", "note_retention",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

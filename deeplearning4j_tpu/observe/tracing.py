@@ -27,6 +27,13 @@ same interval is open as a ``jax.profiler.TraceAnnotation`` named
 ``utils/profiling.device_trace`` the program's stages sit on the host track
 beside the device's operations. This module never imports jax: the
 annotation is looked up only once ``jax`` is already in ``sys.modules``.
+
+Host accounting (``span(..., host=True)``, or ``host_usage``/``host_since``
+around an interval recorded after the fact): the spans that own the host's
+time carry what the thread did while they were open, as deltas: ``cpu_ms``
+(on a core; the rest of the wall time the thread was off it: waiting on the
+GIL, a lock or the device, or preempted) and ``gc_ms`` (the collector's
+passes on this thread, timed by ``observe/host.py``'s hook).
 """
 
 from __future__ import annotations
@@ -37,7 +44,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # A reader that finds ``dropped > 0`` holds half a window and reads nothing, so
 # the bound has to hold what a reader's window writes. Sized for a serving
@@ -54,6 +61,36 @@ _IDS = itertools.count(1)
 
 _ANNOTATION = None
 
+# collector seconds of each thread (``seconds``), added by the collector's
+# hook (``observe/host.py``) as each pass ends on that thread
+thread_gc = threading.local()
+
+
+def _nothing_queued() -> None:
+    pass
+
+
+# writes what the collector's hook queued (``observe/host.py`` sets it when
+# the hook is installed). Called only where this thread holds no lock of the
+# observe package: the hook runs wherever an allocation starts a pass, so it
+# takes none, and queues what it saw for this to write
+flush_host: Callable[[], None] = _nothing_queued
+
+
+def host_usage() -> Tuple[float, float]:
+    """This thread's CPU seconds and collector seconds so far."""
+    return time.thread_time(), getattr(thread_gc, "seconds", 0.0)
+
+
+def host_since(start: Tuple[float, float]) -> Dict[str, Any]:
+    """The accounting ``args`` of an interval from ``start`` (a
+    :func:`host_usage` reading) to now. Read ``start`` after the interval's
+    first clock reading and this before its last, so that ``cpu_ms`` lies
+    inside the wall time."""
+    cpu, gc_s = host_usage()
+    return {"cpu_ms": round(1e3 * (cpu - start[0]), 3),
+            "gc_ms": round(1e3 * (gc_s - start[1]), 3)}
+
 
 def _annotation_cls():
     """``jax.profiler.TraceAnnotation`` once jax has been imported by someone
@@ -69,15 +106,16 @@ def _annotation_cls():
 class Span:
     """One open span: the context manager ``SpanTracer.span`` returns. The
     body may add ``args`` known only at the end with :meth:`set`; ``start``
-    is the ``perf_counter`` reading the span opened at."""
+    is the ``perf_counter`` reading the span opened at. With ``host`` the
+    span keeps the host's accounting (the module's docstring)."""
 
     __slots__ = ("_tracer", "name", "category", "args", "id", "parent",
-                 "start", "_annotation")
+                 "start", "_annotation", "_host", "_since")
 
     def __init__(self, tracer: "SpanTracer", name: str, category: str,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], host: bool = False):
         self._tracer, self.name, self.category = tracer, name, category
-        self.args = args
+        self.args, self._host = args, host
 
     def set(self, **args) -> None:
         """Add ``args``. The event of a closed span holds this same dict, so
@@ -97,9 +135,13 @@ class Span:
         else:
             self._annotation = None
         self.start = time.perf_counter()
+        if self._host:
+            self._since = host_usage()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._host:
+            self.args.update(host_since(self._since))
         end = time.perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
@@ -113,6 +155,8 @@ class Span:
             "dur": (end - self.start) * 1e6, "pid": 0,
             "tid": threading.get_ident() % 1_000_000, "args": args,
         })
+        if self._host:
+            flush_host()
         return False
 
 
@@ -153,33 +197,42 @@ class SpanTracer:
                 self.dropped += 1
             self.events.append(ev)
 
-    def span(self, name: str, category: str = "step", **args) -> Span:
+    def span(self, name: str, category: str = "step", *, host: bool = False,
+             **args) -> Span:
         """Record a complete ('X') event around the with-block; ``as`` gives
         the :class:`Span`. Nesting is carried twice: by the ts/dur intervals
         per tid (how the chrome trace viewer reconstructs the stack) and by
-        ``args["id"]``/``args["parent"]`` (how a reader does)."""
-        return Span(self, name, category, args)
+        ``args["id"]``/``args["parent"]`` (how a reader does). ``host``: the
+        span also carries the host's accounting, and its end writes what
+        the collector's hook queued."""
+        return Span(self, name, category, args, host)
 
     def complete(self, name: str, start_us: float, dur_us: float,
                  category: str = "step", parent: Optional[int] = None,
-                 **args) -> int:
+                 tid: Optional[int] = None, **args) -> int:
         """Record an explicit complete event (for externally measured
-        intervals, e.g. the AOT trace/compile split). Returns its id."""
+        intervals, e.g. the AOT trace/compile split) on thread ``tid`` (a
+        ``threading.get_ident()``; this thread where None). Returns its
+        id."""
         args["id"], args["parent"] = next(_IDS), parent
+        if tid is None:
+            tid = threading.get_ident()
         self._append({"name": name, "cat": category, "ph": "X",
                       "ts": start_us, "dur": dur_us, "pid": 0,
-                      "tid": threading.get_ident() % 1_000_000, "args": args})
+                      "tid": tid % 1_000_000, "args": args})
         return args["id"]
 
     def complete_between(self, name: str, perf_start: float, perf_end: float,
                          category: str = "step",
-                         parent: Optional[int] = None, **args) -> int:
+                         parent: Optional[int] = None,
+                         tid: Optional[int] = None, **args) -> int:
         """Record a complete event from two ``time.perf_counter()`` readings
         (same monotonic clock as the tracer — no epoch conversion). An
         after-the-fact span has the ``parent`` it is given, else none."""
         return self.complete(name, (perf_start - self.perf_origin) * 1e6,
                              (perf_end - perf_start) * 1e6,
-                             category=category, parent=parent, **args)
+                             category=category, parent=parent, tid=tid,
+                             **args)
 
     def async_between(self, name: str, perf_start: float, perf_end: float,
                       key: int, category: str = "step", **args) -> int:
@@ -202,6 +255,8 @@ class SpanTracer:
         return args["id"]
 
     def instant(self, name: str, **args) -> None:
+        """A moment: the collector's passes before it are written first."""
+        flush_host()
         self._append({"name": name, "cat": "marker", "ph": "i",
                       "ts": self._us(), "pid": 0,
                       "tid": threading.get_ident() % 1_000_000, "s": "g",
@@ -209,6 +264,7 @@ class SpanTracer:
 
     # -- export --------------------------------------------------------------
     def to_dict(self) -> Dict[str, Any]:
+        flush_host()
         with self._lock:
             events = list(self.events)
         return {"traceEvents": events, "displayTimeUnit": "ms"}

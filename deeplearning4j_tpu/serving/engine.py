@@ -94,6 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu import faults, observe
+from deeplearning4j_tpu.observe.tracing import host_since, host_usage
 from deeplearning4j_tpu.ops.pallas_attention import gather_pages
 from deeplearning4j_tpu.models.served import SlotState
 from deeplearning4j_tpu.serving.cache import PagedKVCache, SlotStatePool
@@ -1138,8 +1139,9 @@ class GenerativeEngine:
         # graftlock: justified(GL012): single-writer — only the (one) worker/inline step thread steps
         self._step_count += 1
         with observe.tracer().span(
-                "serving_step", category="serving", step=self._step_count,
-                pending=len(sched.pending), active=len(sched.slots)) as sp:
+                "serving_step", category="serving", host=True,
+                step=self._step_count, pending=len(sched.pending),
+                active=len(sched.slots)) as sp:
             admitted, produced = self._step(ahead)
             sp.set(admitted=admitted, produced=produced)
         return produced
@@ -1435,22 +1437,21 @@ class GenerativeEngine:
         self._obs["decode_h"].observe(dt)
         self._obs["sampler"][self._sampler[1]].inc()
         self._obs["launches"][int(before is not None)].inc()
-        return self._commit_decode(due, toks, dt) if due is not None else 0
+        return self._commit_decode(due, toks) if due is not None else 0
 
     def _read_decode(self, step: _DecodeStep) -> np.ndarray:
         """The blocking read of a launched decode step: its tokens and the
         model's statistics in ONE transfer. The statistics go to the step's
         own ``serving_decode`` span, open or closed."""
         with observe.tracer().span("serving_decode_read",
-                                   category="serving"):
+                                   category="serving", host=True):
             toks, stats = jax.device_get((step.toks, step.stats))
         if stats is not None:
             self.programs.note_stats(stats, step.span, decode_step=True,
                                      tokens=len(toks))
         return toks
 
-    def _commit_decode(self, step: _DecodeStep, toks: np.ndarray,
-                       step_seconds: float) -> int:
+    def _commit_decode(self, step: _DecodeStep, toks: np.ndarray) -> int:
         """Hand a read step's tokens to their sequences, stamped now: when
         they reached the host is what a caller can see. A sequence that is
         gone, or complete by what landed before (its ``eos`` was read a
@@ -1470,8 +1471,6 @@ class GenerativeEngine:
                 produced += 1
                 self._finish_leaving(st)
             self._obs["generated"].inc(produced)
-            observe.log_event("serving_decode", slots=len(step.slots),
-                              step_seconds=round(step_seconds, 6))
         return produced
 
     def _land(self) -> int:
@@ -1481,10 +1480,7 @@ class GenerativeEngine:
         step, self._flying = self._flying, None
         produced = 0
         if step is not None:
-            t0 = time.perf_counter()
-            toks = self._read_decode(step)
-            produced = self._commit_decode(step, toks,
-                                           time.perf_counter() - t0)
+            produced = self._commit_decode(step, self._read_decode(step))
         self._land_admissions()
         return produced
 
@@ -1498,7 +1494,9 @@ class GenerativeEngine:
         while self._landing:
             adm = self._landing.pop(0)
             t0 = time.perf_counter()
+            since = host_usage()
             tok, stats = jax.device_get((adm.tok, adm.stats))
+            used = host_since(since)
             now = time.perf_counter()
             # never opened: it collects the args of a span whose extent is
             # known only now
@@ -1512,7 +1510,7 @@ class GenerativeEngine:
             tracer.complete_between("serving_prefill_launch", *adm.launch,
                                     category="serving", parent=pid)
             tracer.complete_between("serving_prefill_read", t0, now,
-                                    category="serving", parent=pid)
+                                    category="serving", parent=pid, **used)
             sched.on_first_token(adm.st, int(tok), now)
             self._obs["generated"].inc()
             self._obs["ttft_h"].observe(now - adm.st.submit_t)

@@ -14,7 +14,7 @@ import pytest
 from deeplearning4j_tpu import faults, observe
 from deeplearning4j_tpu.models.gpt import GptConfig, GptModel
 from deeplearning4j_tpu.models.longcat import LongcatModel
-from deeplearning4j_tpu.observe import tracing
+from deeplearning4j_tpu.observe import host, tracing
 from deeplearning4j_tpu.serving import GenerativeEngine
 from tests.test_longcat import bench_cfg, program_cfg, ref
 
@@ -452,6 +452,9 @@ def test_the_ring_holds_a_window_of_the_fastest_predicted_cell():
     requests = len(events("serving_request", phases=("b",)))
     assert steps >= 200 and requests == 60 + SLOTS
     assert tr.dropped == 0
+    # with the collector's hook in, whose passes of generations 1 and 2 are
+    # spans of this ring too
+    assert tracing.flush_host is host.flush
     per_request = 4 + 5  # two async pairs; admit, prefill and its two parts, a key
     per_step = (len(tr.events) - per_request * requests) / steps
     assert 8 <= per_step <= 13, per_step

@@ -161,6 +161,21 @@ class TestSpanIds:
 
 
 EPS = 1.0  # microseconds, for the float arithmetic behind the timestamps
+ACCOUNTING = ("cpu_ms", "gc_ms")
+
+
+def assert_accounted(events, owners):
+    """The spans named in ``owners`` carry the host's accounting, inside
+    their wall time (``cpu_ms`` is rounded to the microsecond); the others
+    carry none of it."""
+    for e in events:
+        args = e["args"]
+        if e["name"] not in owners:
+            assert not set(ACCOUNTING) & set(args), e["name"]
+            continue
+        assert set(ACCOUNTING) <= set(args), e["name"]
+        assert 0 <= args["cpu_ms"] <= e["dur"] / 1e3 + 1e-3, e
+        assert 0 <= args["gc_ms"] <= e["dur"] / 1e3 + 1e-3, e
 
 STAGES = ("serving_schedule", "serving_admit", "serving_next_key",
           "serving_prefill", "serving_prefill_launch", "serving_prefill_read",
@@ -209,6 +224,14 @@ class TestServingStageSpans:
         assert sum(s["args"]["admitted"] for s in steps) == len(PROMPTS)
         assert sum(s["args"]["produced"] for s in steps) == sum(
             len(r.tokens) - 1 for r in served[2])
+
+    def test_the_spans_that_own_the_host_keep_its_accounting(self, served):
+        owners = ("serving_step", "serving_decode_read",
+                  "serving_prefill_read")
+        assert all(spans(name) for name in owners)
+        assert_accounted(
+            [e for e in spans() if e["ph"] == "X"
+             and e["name"] not in ("host_gc", "xla_compile")], owners)
 
     def test_old_spans_keep_their_args(self, served):
         assert all(1 <= e["args"]["slots"] <= 2
@@ -381,6 +404,17 @@ class TestScannedTrainerSpans:
         model.fit_mlm_scanned(bert_batch(), 3)
         calls = [e["args"]["call"] for e in spans("fit_scanned")]
         assert calls[1] == calls[0] + 1
+
+    def test_a_scanned_calls_spans_keep_the_hosts_accounting(self):
+        tiny_bert().fit_mlm_scanned(bert_batch(), 2)
+        owners = ("fit_scanned", "fit_scanned_dispatch", "fit_scanned_read")
+        found = [e for e in spans() if e["name"] in owners]
+        assert sorted(e["name"] for e in found) == sorted(owners)
+        assert_accounted(found, owners)
+        call = next(e for e in found if e["name"] == "fit_scanned")
+        # the call's CPU time holds its two stages'
+        assert call["args"]["cpu_ms"] >= sum(
+            e["args"]["cpu_ms"] for e in found if e is not call) - 2e-3
 
     def test_bert_per_batch_loop_counts_and_writes_no_spans(self):
         tiny_bert().fit_mlm([bert_batch(), bert_batch()], epochs=2)
