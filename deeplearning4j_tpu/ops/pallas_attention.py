@@ -50,6 +50,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 # pltpu ships with jax's pallas package and is needed even in interpret mode
@@ -640,8 +641,8 @@ def flash_mha(q, k, v, *, num_heads: int, causal: bool = False,
 # so a page is a ``(page_size, heads * head_dim)`` matrix that fills the
 # TPU's (8, 128) float32 / (16, 128) bfloat16 tiles with no padding, the
 # device keeps the pool row-major, and every program updates it in place.
-# The op takes the WHOLE pool and a static ``layer``: slicing a layer out
-# first would copy it.
+# The op takes the WHOLE pool and a ``layer``: slicing a layer out first
+# would copy it.
 #
 # Two implementations of one contract, selected through the registry
 # platform table exactly like flash attention above:
@@ -649,13 +650,14 @@ def flash_mha(q, k, v, *, num_heads: int, causal: bool = False,
 #     out of the pool and run masked attention; runs anywhere, and is what
 #     narrow models (heads * head_dim not a multiple of 128) take on a TPU
 #     too, at the cost of materializing the gathered (S, T_max, H, D) keys.
-#   * `_paged_decode_call` — Pallas: grid (slot, group of pages) with the page
-#     walk innermost; the page table rides scalar-prefetch
-#     (PrefetchScalarGridSpec) and the layer is a constant of the index_map,
-#     so each grid step DMAs its pages' K and V straight from where they lie
-#     in the pool — neither a gathered copy nor a layer's slice ever exists —
-#     and the walk stops at the sequence's length. Online-softmax running
-#     state lives in VMEM scratch across the page walk of one slot (the
+#   * `_paged_decode_call` — Pallas: grid (slot,), the pool left in HBM, the
+#     page table, lengths and layer in scalar prefetch; each slot's pages
+#     come by the kernel's own DMAs, K and V of a page in one copy, straight
+#     from where they lie in the pool (neither a gathered copy nor a layer's
+#     slice ever exists) through a ring of VMEM buffers that runs on from one
+#     slot into the next (`_PageStream`, shared with the latent kernel
+#     below), and the walk stops at the sequence's length. Online-softmax
+#     running state lives in VMEM scratch across the walk of one slot (the
 #     FlashAttention-2 recurrence, a group of pages at a time).
 
 
@@ -697,74 +699,182 @@ def paged_decode_attention_xla(q, kv_pages, page_table, seq_lens, *,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-# Pages one grid step of the paged kernel attends to. A grid step has a cost
-# whatever it does, and one page of 16 positions gives the MXU a sixteenth of
-# a tile to do; 8 pages of 16 make the scores one full 128-lane tile. On the
-# v5e at GPT-2 small's widths (32 slots x 65 pages of 16) the whole decode
-# program took 11.8, 9.4, 8.3, 7.8 and 8.0 ms at 1, 2, 4, 8 and 16 pages a
-# step (PERF.md, PR 26).
-_PAGES_PER_STEP = 8
+class _PageStream:
+    """The groups of every slot's pages as ONE stream through a ring of VMEM
+    buffers, for a decode kernel whose grid is ``(slots,)`` walked in order
+    and whose pool is left in HBM.
+
+    A slot's pages come in groups of ``span // page``; group ``c`` of the
+    stream lands in buffer ``c % depth``, which has its own DMA semaphore.
+    While group ``c`` is consumed, group ``c + depth - 1`` is in flight,
+    the next slot's first once a slot's last is reached. The producer's
+    place in the stream (slot, group) and the counts of groups started and
+    consumed live in SMEM across the grid's steps (``cur_ref``, 4 int32). A
+    group's pages past its slot's last re-read the last, so that a group is
+    always whole: its copies are issued in straight-line code beside the
+    products they overlap, and ONE wait takes the whole buffer. A slot
+    takes ``ceil(seq_len / span)`` groups and an empty slot one.
+    ``copy(page, buf, k)`` is the async copy of pool page ``page`` into
+    place ``k`` of buffer ``buf``."""
+
+    def __init__(self, pt_ref, sl_ref, cur_ref, ring, sems, copy, *,
+                 page: int, span: int):
+        self.pt_ref, self.sl_ref, self.cur_ref = pt_ref, sl_ref, cur_ref
+        self.ring, self.sems, self.copy = ring, sems, copy
+        self.page, self.span = page, span
+        self.depth, self.n_slots = ring.shape[0], pl.num_programs(0)
+
+    # every count here is non-negative: lax.div and lax.rem are the floor
+    # division and modulo, in one operation each where // and % take ten
+
+    def groups_of(self, s):
+        return jnp.maximum(
+            jax.lax.div(self.sl_ref[s] + self.span - 1, self.span), 1)
+
+    def produce(self, rolled: bool = False):
+        cur_ref, page, depth = self.cur_ref, self.page, self.depth
+        group = self.span // page
+        ps, pg, started = cur_ref[0], cur_ref[1], cur_ref[2]
+        # past the stream's end (the last depth - 1 groups), the last slot
+        # again: drained by close()
+        s = jnp.minimum(ps, self.n_slots - 1)
+        last_page = jax.lax.div(jnp.maximum(self.sl_ref[s] - 1, 0), page)
+        buf, first = jax.lax.rem(started, depth), pg * group
+
+        def start(k, c=None):
+            self.copy(self.pt_ref[s, jnp.minimum(first + k, last_page)],
+                      buf, k).start()
+            return c
+
+        if rolled:
+            jax.lax.fori_loop(0, group, start, 0)
+        else:
+            # straight-line copies beside the product they overlap (issued
+            # from a rolled loop, 0.42 against 0.35 ms a call at 16 pages a
+            # group: docs/KERNELS.md)
+            for k in range(group):
+                start(k)
+        last = pg + 1 >= self.groups_of(s)
+        cur_ref[0] = jnp.where(last, ps + 1, ps)
+        cur_ref[1] = jnp.where(last, 0, pg + 1)
+        cur_ref[2] = started + 1
+
+    def consume(self):
+        """Wait for the stream's next group; its buffer's index."""
+        buf = jax.lax.rem(self.cur_ref[3], self.depth)
+        # the group's copies signal its semaphore by their bytes: one wait
+        # for a whole buffer's
+        pltpu.make_async_copy(self.ring.at[buf], self.ring.at[buf],
+                              self.sems.at[buf]).wait()
+        self.cur_ref[3] = self.cur_ref[3] + 1
+        return buf
+
+    def open(self):
+        """At the grid's first step: the stream's first depth - 1 groups,
+        their copies issued from a rolled loop (once a call)."""
+        @pl.when(pl.program_id(0) == 0)
+        def _first():
+            for i in range(4):
+                self.cur_ref[i] = 0
+            jax.lax.fori_loop(0, self.depth - 1,
+                              lambda i, c: (self.produce(rolled=True), c)[1],
+                              0)
+
+    def close(self):
+        """At the grid's last step: wait for what ran past the end."""
+        @pl.when(pl.program_id(0) == self.n_slots - 1)
+        def _drain():
+            jax.lax.fori_loop(0, self.depth - 1,
+                              lambda i, c: (self.consume(), c)[1], 0)
 
 
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, *refs, page: int,
-                         scale: float, head_dim: int, group: int):
-    """One (slot, group of pages) grid step over merged-head pages.
+# The paged kernel's ring: a slot's pages come in groups of
+# _PAGED_GROUP_PAGES, K and V of a group in one buffer of VMEM with its own
+# DMA semaphore, _PAGED_RING_GROUPS buffers in all (_PageStream). A float32
+# page of GPT-2 small is 48 KB a side (16 rows of 768). Timed on the v5e at
+# that cell's geometry (32 slots of 218-948 positions, an empty and a fresh
+# slot among them, 12 heads, page 16; docs/KERNELS.md has the sweep), ms a
+# call by (groups, pages a group): (2, 4) 0.222, (3, 4) 0.169, (4, 4) 0.165,
+# (6, 4) 0.166, (2, 8) 0.176, (3, 8) 0.172, (4, 8) 0.173, (2, 16) 0.188,
+# (3, 16) 0.190, (2, 32) 0.227; the BlockSpec walk this replaced (8 inputs a
+# grid step of (slot, group)) 0.269 (PERF.md section 6). The copies alone take
+# as long as the whole wherever three groups or more are in flight, at
+# 700-720 GB/s, and a group's pages past its slot's last are re-read: small
+# groups re-read least, and deep rings keep them streaming.
+_PAGED_RING_GROUPS = 4
+_PAGED_GROUP_PAGES = 4
 
-    Row ``h`` of the block-diagonal query holds head ``h``'s query in its
-    own ``head_dim`` columns and zeros elsewhere, so ONE (rows, H*D) x
-    (H*D, positions) matmul gives every head's scores and ONE (rows,
-    positions) x (positions, H*D) matmul every head's values; the diagonal
-    blocks of the accumulator are the output. The zeros cost operations the
-    MXU has to spare (decode is bound by the K/V stream) and save the
-    per-head lane slices of an unaligned ``head_dim``.
 
-    A group wholly past ``seq_len`` is skipped (its probabilities would all
-    be exactly 0), and the index maps re-point its pages at the sequence's
-    last page, which the pipeline then does not fetch again: the walk over
-    the page table costs DMA and arithmetic only as far as the sequence
-    goes. Within the last group, positions past ``seq_len`` are masked."""
-    kv_refs, (o_ref, acc_ref, mx_ref, l_ref) = refs[:group], refs[group:]
-    s_idx, j = pl.program_id(0), pl.program_id(1)
+def paged_decode_groups(seq_lens, page: int) -> int:
+    """Groups of pages the paged kernel walks for these sequence lengths:
+    ``ceil(seq_len / (group * page))`` summed over them (host arithmetic on
+    lengths the caller holds; what a call's cost follows)."""
+    span = _PAGED_GROUP_PAGES * page
+    return int(((np.asarray(seq_lens, np.int64) + span - 1) // span).sum())
+
+
+def _paged_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, kv_hbm, o_ref,
+                         ring, sems, qd_ref, acc_ref, mx_ref, l_ref, cur_ref,
+                         *, page: int, scale: float, head_dim: int):
+    """Grid step ``s``: slot ``s``'s heads against its K/V pages, a group of
+    pages at a time, the pages streamed from the pool where it lies by
+    :class:`_PageStream` (the walk is a rolled loop whose trip count comes
+    from ``seq_lens``: the traced body is the same at any page count).
+
+    Row ``h`` of the block-diagonal query (made once a slot, in ``qd_ref``)
+    holds head ``h``'s query in its own ``head_dim`` columns and zeros
+    elsewhere, so ONE (rows, H*D) x (H*D, positions) product gives every
+    head's scores and ONE (rows, positions) x (positions, H*D) product every
+    head's values; the diagonal blocks of the accumulator are the output.
+    The zeros cost operations the MXU has to spare (decode is bound by the
+    K/V stream) and save the per-head lane slices of an unaligned
+    ``head_dim``. Within a slot's last group, positions past ``seq_len``
+    are masked; a slot of length 0 gives zeros."""
+    s_idx = pl.program_id(0)
     rows, width = acc_ref.shape      # heads rounded up to 8, heads * head_dim
-    span = group * page
-    seq_len = sl_ref[s_idx]
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        mx_ref[:] = jnp.full_like(mx_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    span = ring.shape[2]
+    layer = layer_ref[0]
+    stream = _PageStream(
+        pt_ref, sl_ref, cur_ref, ring, sems,
+        lambda p, buf, k: pltpu.make_async_copy(
+            kv_hbm.at[layer, :, p], ring.at[buf, :, pl.ds(k * page, page)],
+            sems.at[buf]),
+        page=page, span=span)
+    stream.open()
 
     first = head_dim * jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
     own = (col >= first) & (col < first + head_dim)
+    # selected in f32: a bf16 select would need the int32 mask in bf16's
+    # (16, 128) tiling, a relayout Mosaic refuses
+    qd_ref[...] = jnp.where(own, q_ref[...].astype(jnp.float32),
+                            0.0).astype(qd_ref.dtype)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    mx_ref[...] = jnp.full_like(mx_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    seq_len = sl_ref[s_idx]
 
-    @pl.when(j * span < seq_len)
-    def _attend():
-        # selected in f32: a bf16 select would need the int32 mask in bf16's
-        # (16, 128) tiling, a relayout Mosaic refuses
-        q = jnp.where(own, q_ref[:].astype(jnp.float32), 0.0).astype(
-            q_ref.dtype)             # (rows, H*D)
-        kblk = jnp.concatenate([r[0] for r in kv_refs], axis=0)
-        vblk = jnp.concatenate([r[1] for r in kv_refs], axis=0)
-
-        s = _mm_nt(q, kblk) * scale  # f32 (rows, span)
-        pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    def attend(g, c):
+        stream.produce()                # into the buffer group c - 1 left
+        buf = stream.consume()
+        s = _mm_nt(qd_ref[...], ring[buf, 0]) * scale      # f32 (rows, span)
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         s = jnp.where(pos < seq_len, s, -1e30)
-
         m_prev = mx_ref[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + _mm_nn(p, vblk)
+        acc_ref[...] = acc_ref[...] * alpha + _mm_nn(p, ring[buf, 1])
         mx_ref[:, :1] = m_new
+        return c
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        out = jnp.where(own, acc_ref[:] / l, 0.0)
-        o_ref[:] = out.sum(axis=0, keepdims=True).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, stream.groups_of(s_idx), attend, 0)
+    stream.close()
+
+    out = jnp.where(own, acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30), 0.0)
+    out = out.sum(axis=0, keepdims=True)
+    o_ref[...] = jnp.where(seq_len > 0, out, 0.0).astype(o_ref.dtype)
 
 
 def _paged_decode_call(q, kv_pages, page_table, seq_lens, *, layer: int = 0,
@@ -772,48 +882,41 @@ def _paged_decode_call(q, kv_pages, page_table, seq_lens, *, layer: int = 0,
                        interpret: Optional[bool] = None):
     """Pallas paged decode. Same contract as paged_decode_attention_xla,
     except that a slot of length 0 (inactive: nobody reads it) gives zeros
-    where the gather path gives the mean of its masked values."""
+    where the gather path gives the mean of its masked values. ``layer``
+    rides scalar prefetch beside the page table, so every layer's call is
+    one and the same kernel."""
     s_n, h, d = q.shape
     page, width = kv_pages.shape[3], kv_pages.shape[4]
-    max_pages = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    interpret = pallas_interpret(interpret)
     rows = -(-h // 8) * 8
-    group = min(_PAGES_PER_STEP, max_pages)
+    span = _PAGED_GROUP_PAGES * page
     kernel = functools.partial(_paged_decode_kernel, page=page, scale=scale,
-                               head_dim=d, group=group)
-    layer = int(layer)
-
-    def page_block(i):
-        """K and V of the group's ``i``-th page of one layer, where they
-        lie in the pool; past the sequence's end, its last page again."""
-        def index(s, j, pt, sl):
-            last = jnp.maximum(sl[s] - 1, 0) // page
-            return (layer, 0, pt[s, jnp.minimum(j * group + i, last)], 0, 0)
-
-        return pl.BlockSpec((None, 2, None, page, width), index)
-
+                               head_dim=d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_n, -(-max_pages // group)),
-        in_specs=[pl.BlockSpec((None, 1, width),
-                               lambda s, j, pt, sl: (s, 0, 0))]
-        + [page_block(i) for i in range(group)],
-        out_specs=pl.BlockSpec((None, 1, width),
-                               lambda s, j, pt, sl: (s, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(s_n,),
+        in_specs=[pl.BlockSpec((None, 1, width), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, 1, width), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((_PAGED_RING_GROUPS, 2, span, width), kv_pages.dtype),
+            pltpu.SemaphoreType.DMA((_PAGED_RING_GROUPS,)),
+            pltpu.VMEM((rows, width), q.dtype),
             pltpu.VMEM((rows, width), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, 1, width), q.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(interpret),
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q.reshape(s_n, 1, width), *([kv_pages] * group))
+      jnp.full((1,), layer, jnp.int32), q.reshape(s_n, 1, width), kv_pages)
     return out.reshape(s_n, h, d)
 
 
@@ -842,19 +945,27 @@ def _paged_usable(q, kv_pages, page_table, seq_lens, **kw):
 def _check_paged_decode_attention():
     """Validation case (ops.validation ratchet): XLA gather path vs a
     straight numpy oracle, and the Pallas interpret kernel vs both, on the
-    second layer of a two-layer pool."""
-    import numpy as np
-
+    second layer of a two-layer pool. The first slot's walk goes once round
+    the kernel's ring and on, into the next slot, which is empty (the
+    kernel's zeros; the gather path's output there is nobody's); one slot
+    ends on a group's last position and one fills its page table, which
+    ends inside a group."""
     r = np.random.RandomState(7)
-    s_n, h, d, page, n_pages, max_pages, layer = 3, 4, 32, 8, 10, 3, 1
+    h, d, page, layer = 4, 32, 8, 1
+    span = _PAGED_GROUP_PAGES * page
+    ring = _PAGED_RING_GROUPS * span
+    max_pages = (ring + span + page) // page
+    assert (max_pages * page) % span
+    sl = np.array([ring + span - 5, 0, 2 * span, 5, max_pages * page],
+                  np.int32)
+    s_n, n_pages = len(sl), 2 * max_pages
     q = r.randn(s_n, h, d).astype(np.float32)
     kv = r.randn(2, 2, n_pages, page, h * d).astype(np.float32)
     pt = np.stack([r.choice(n_pages, max_pages, replace=False)
                    for _ in range(s_n)]).astype(np.int32)
-    sl = np.array([5, 17, 24], np.int32)
     scale = 1.0 / math.sqrt(d)
     want = np.zeros_like(q)
-    for i in range(s_n):
+    for i in np.flatnonzero(sl):
         gk = kv[layer, 0, pt[i]].reshape(-1, h, d)[:sl[i]]
         gv = kv[layer, 1, pt[i]].reshape(-1, h, d)[:sl[i]]
         for hh in range(h):
@@ -863,8 +974,9 @@ def _check_paged_decode_attention():
             p = p / p.sum()
             want[i, hh] = p @ gv[:, hh]
     args = (jnp.asarray(q), jnp.asarray(kv), jnp.asarray(pt), jnp.asarray(sl))
-    got = paged_decode_attention_xla(*args, layer=layer)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    got = np.asarray(paged_decode_attention_xla(*args, layer=layer))
+    live = sl > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-4, atol=1e-5)
     assert _paged_usable(*args)
     got_pl = _paged_decode_call(*args, layer=layer)
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
@@ -949,64 +1061,23 @@ def _latent_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     (group rows, R) product, the online softmax in float32 scratch.
 
     The pages come by the kernel's own DMAs from the pool where it lies
-    (``kv_hbm``, left in HBM) into a ring of ``depth`` group buffers. The
-    groups of ALL slots form one stream taken in order, so while group ``c``
-    is scored group ``c + depth - 1`` is in flight, the next slot's first
-    once a slot's last is reached. The producer's place in the stream
-    (slot, group) and the counts of groups started and consumed live in SMEM
-    across the grid's steps (``cur_ref``), which the grid therefore walks in
-    order. A group's pages past its slot's last re-read the last, so that
-    a group is always whole: its copies are issued in straight-line code
-    beside the product they overlap, and ONE wait takes the whole buffer. An empty slot still takes one group, scored
+    (``kv_hbm``, left in HBM) through :class:`_PageStream`'s ring, so while
+    one group is scored the next is in flight, the next slot's first once a
+    slot's last is reached. An empty slot still takes one group, scored
     and then dropped (its output is zeros). The walk over a slot's groups
     is a rolled loop whose trip count comes from ``seq_lens``: the traced
     body is the same at any page count."""
-    s_idx, n_slots = pl.program_id(0), pl.num_programs(0)
-    depth, span = ring.shape[0], ring.shape[1]
-    group = span // page
+    s_idx = pl.program_id(0)
+    span = ring.shape[1]
     rows = acc_ref.shape[0]
     layer = layer_ref[0]
-
-    def groups_of(s):
-        return jnp.maximum((sl_ref[s] + span - 1) // span, 1)
-
-    def produce():
-        ps, pg, started = cur_ref[0], cur_ref[1], cur_ref[2]
-        # past the stream's end (the last depth - 1 groups), the last slot
-        # again: drained below
-        s = jnp.minimum(ps, n_slots - 1)
-        last_page = jnp.maximum(sl_ref[s] - 1, 0) // page
-        buf, first = started % depth, pg * group
-
-        def start(k, c):
-            pltpu.make_async_copy(
-                kv_hbm.at[layer, 0,
-                          pt_ref[s, jnp.minimum(first + k, last_page)]],
-                ring.at[buf, pl.ds(k * page, page)], sems.at[buf]).start()
-            return c
-
-        # traced once, lowered unrolled: straight-line copies beside the
-        # product they overlap (issued from a rolled loop, 0.42 against
-        # 0.35 ms a call at 16 pages a group: docs/KERNELS.md)
-        jax.lax.fori_loop(0, group, start, 0, unroll=True)
-        last = pg + 1 >= groups_of(s)
-        cur_ref[0] = jnp.where(last, ps + 1, ps)
-        cur_ref[1] = jnp.where(last, 0, pg + 1)
-        cur_ref[2] = started + 1
-
-    def consume():
-        buf = cur_ref[3] % depth
-        # the group's copies signal its semaphore by their bytes: one wait
-        # for a whole buffer's
-        pltpu.make_async_copy(ring.at[buf], ring.at[buf], sems.at[buf]).wait()
-        cur_ref[3] = cur_ref[3] + 1
-        return buf
-
-    @pl.when(s_idx == 0)
-    def _first():
-        for i in range(4):
-            cur_ref[i] = 0
-        jax.lax.fori_loop(0, depth - 1, lambda i, c: (produce(), c)[1], 0)
+    stream = _PageStream(
+        pt_ref, sl_ref, cur_ref, ring, sems,
+        lambda p, buf, k: pltpu.make_async_copy(
+            kv_hbm.at[layer, 0, p], ring.at[buf, pl.ds(k * page, page)],
+            sems.at[buf]),
+        page=page, span=span)
+    stream.open()
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     mx_ref[...] = jnp.full_like(mx_ref, -1e30)
@@ -1014,8 +1085,8 @@ def _latent_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     seq_len = sl_ref[s_idx]
 
     def attend(g, c):
-        produce()                       # into the buffer group c - 1 left
-        blk = ring[consume()]                               # (span, W)
+        stream.produce()                # into the buffer group c - 1 left
+        blk = ring[stream.consume()]                        # (span, W)
         s = _mm_nt(q_ref[...], blk) * scale                 # f32 (H, span)
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         s = jnp.where(pos < seq_len, s, -1e30)
@@ -1029,11 +1100,8 @@ def _latent_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, kv_hbm, o_ref,
         mx_ref[:, :1] = m_new
         return c
 
-    jax.lax.fori_loop(0, groups_of(s_idx), attend, 0)
-
-    @pl.when(s_idx == n_slots - 1)
-    def _drain():
-        jax.lax.fori_loop(0, depth - 1, lambda i, c: (consume(), c)[1], 0)
+    jax.lax.fori_loop(0, stream.groups_of(s_idx), attend, 0)
+    stream.close()
 
     out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
     o_ref[...] = jnp.where(seq_len > 0, out, 0.0).astype(o_ref.dtype)
@@ -1101,8 +1169,6 @@ def _check_latent_decode_attention():
     """Validation case: the generic path against a numpy oracle and the
     Pallas kernel (interpreted) against both, on the second sub-layer; the
     last slot's walk goes once round the kernel's ring and on."""
-    import numpy as np
-
     rs = np.random.RandomState(11)
     s_n, h, r, dr, page, n_pages, max_pages, layer = 3, 8, 128, 32, 8, 96, 80, 1
     width = 256

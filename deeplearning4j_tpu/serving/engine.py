@@ -95,7 +95,8 @@ import numpy as np
 
 from deeplearning4j_tpu import faults, observe
 from deeplearning4j_tpu.observe.tracing import host_since, host_usage
-from deeplearning4j_tpu.ops.pallas_attention import gather_pages
+from deeplearning4j_tpu.ops.pallas_attention import (
+    gather_pages, paged_decode_groups)
 from deeplearning4j_tpu.models.served import SlotState
 from deeplearning4j_tpu.serving.cache import PagedKVCache, SlotStatePool
 from deeplearning4j_tpu.serving.prefix import PrefixMatch, RadixPrefixCache
@@ -430,6 +431,7 @@ class GenerativeEngine:
             "launches": [m.counter(
                 "dl4j_tpu_serving_decode_launches_total", ahead=str(a))
                 for a in (0, 1)],
+            "paged_groups": m.counter("dl4j_tpu_paged_decode_groups_total"),
             # written ONLY by stop(): the gauge is process-global, and a
             # constructor write here would clobber a previous engine's
             # hung-stop indication while that engine is still wedged
@@ -1413,12 +1415,19 @@ class GenerativeEngine:
                 signature=observe.signature_of(
                     page_table=getattr(cache, "page_table", None),
                     seq_lens=cache.seq_lens, tokens=self._toks, active=act))
+            attn = {}
+            if getattr(cache, "sides", 0) == 2:
+                # K/V rows: the groups of pages the paged kernel walks, over
+                # the lengths this step's token makes
+                attn["attn_groups"] = paged_decode_groups(
+                    cache.seq_lens[active] + 1, cache.page_size)
+                self._obs["paged_groups"].inc(attn["attn_groups"])
         # None in a synchronous iteration: it reads what it launches
         before = self._flying
         t0 = time.perf_counter()
         with tracer.span("serving_decode", category="serving",
                          slots=len(active), sampler=self._sampler[1],
-                         ahead=int(before is not None)) as sp:
+                         ahead=int(before is not None), **attn) as sp:
             with tracer.span("serving_decode_launch", category="serving"):
                 cache.kv, self._toks, _logits, stats = self._decode_fn(
                     self.model.params, cache.kv, *args, key, *sampling)

@@ -340,7 +340,7 @@ WIDE_MODEL = GptModel(WIDE_CFG, seed=1)
 class TestPagedDecodeEquivalence:
     # (heads, head_dim, page, pages a slot, kernel takes it): the merged
     # width has to be a multiple of 128 lanes and the page of 8 sublanes. 19
-    # pages a slot is three grid steps of the kernel's 8 pages, the last one
+    # pages a slot is three of the kernel's groups of 8 pages, the last one
     # short, with sequences that end inside the first, second and third.
     @pytest.mark.parametrize("h,d,page,max_pages,taken", [
         (4, 32, 8, 4, True), (4, 64, 8, 19, True), (12, 64, 16, 19, True),

@@ -259,6 +259,46 @@ def test_spans_show_the_launch_before_the_read_of_the_step_before():
     assert ttft.count == len(PROMPTS)
 
 
+def test_a_decode_step_counts_the_groups_the_paged_kernel_walks(
+        name, monkeypatch):
+    """``serving_decode``'s ``attn_groups`` and the counter
+    ``dl4j_tpu_paged_decode_groups_total``: the groups of pages the paged
+    kernel walks in a step, ``ceil(length / (group * page))`` over the
+    active slots' lengths with the step's token, where the pool holds K/V
+    rows. A latent pool's steps carry neither."""
+    from deeplearning4j_tpu.ops import pallas_attention
+
+    # a group of one page, so that these short sequences cross groups
+    monkeypatch.setattr(pallas_attention, "_PAGED_GROUP_PAGES", 1)
+    eng = make_engine(name)
+    page = eng.cache.page_size
+    want = []
+    step_decode = eng._step_decode
+
+    def spy(active, ahead):
+        want.append(sum(-(-(int(eng.cache.seq_lens[s]) + 1) // page)
+                        for s in active))
+        return step_decode(active, ahead)
+
+    eng._step_decode = spy
+    futs = [eng.submit(p, max_new_tokens=10, eos_token=-1)
+            for p in PROMPTS[:3]]
+    while eng.scheduler.has_work():
+        eng.step()
+    assert all(f.result(timeout=0).finish_reason == "length" for f in futs)
+    keep_programs(name, eng)
+    got = [e["args"].get("attn_groups")
+           for e in sorted(events("serving_decode"), key=lambda e: e["ts"])]
+    counted = observe.metrics().counter(
+        "dl4j_tpu_paged_decode_groups_total").value
+    assert len(got) == len(want) > 5
+    if name == "gpt":
+        assert got == want and len(set(want)) > 2
+        assert counted == sum(want)
+    else:
+        assert got == [None] * len(want) and counted == 0
+
+
 def test_inline_steps_and_a_speculating_engine_never_run_ahead():
     from deeplearning4j_tpu.models.gpt import draft_config_for
 

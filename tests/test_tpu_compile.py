@@ -351,6 +351,36 @@ def test_the_latent_kernel_lowers_the_same_at_any_page_count(one_chip):
     assert counts[0] == counts[1] < 1806, counts
 
 
+def test_the_paged_kernel_lowers_the_same_at_any_page_count(one_chip,
+                                                           monkeypatch):
+    """The paged kernel's page walk is rolled: its Mosaic body has as many
+    operations at 16 pages a sequence as at 65, at the serving cell's widths
+    (32 slots, 12 heads of 64, float32, page 16), and no more than the
+    BlockSpec walk it replaced had (632: eight inputs a grid step of (slot,
+    group); docs/KERNELS.md). GPT-2's ``decode`` at the cell's geometry holds
+    one kernel body for its twelve calls, since the layer rides scalar
+    prefetch; its calls are the program's own un-named Mosaic calls
+    (``%decode.N``), which ``paged_attn_roofline.serve`` reads."""
+    from deeplearning4j_tpu.ops.pallas_attention import _paged_decode_call
+
+    kernel = functools.partial(_paged_decode_call, layer=11, interpret=False)
+    counts = []
+    for max_pages in (16, PAGES_PER_SEQ):
+        text = _compiled_text(
+            one_chip, kernel, ((SLOTS, 12, 64), jnp.float32),
+            ((12, 2, N_PAGES + 1, PAGE, 768), jnp.float32),
+            ((SLOTS, max_pages), jnp.int32), ((SLOTS,), jnp.int32))
+        bodies = re.findall(r'"custom_call_config":\{"body":"([^"]+)"', text)
+        assert len(bodies) == 1
+        counts.append(_mosaic_ops(bodies[0])[0])
+    assert counts[0] == counts[1] <= 632, counts
+    _pool, compiled, _prefill = _pool_programs(one_chip, monkeypatch,
+                                               jnp.float32)
+    bodies = _mosaic_bodies(compiled["decode"].as_text(), "decode")
+    assert len(bodies) == 12
+    assert len({_mosaic_ops(b)[1] for b in bodies}) == 1
+
+
 # Brumby's cell (benchmarks/traffic/reason-closed-32.json): five layers at
 # the published widths, the whole vocabulary, 32 slots, prompts bucketed to
 # 512.
