@@ -6,7 +6,9 @@ scheduler and sampler. ``cache_rows()`` says what the model keeps between
 steps, in one of two geometries: rows a TOKEN in pages (:class:`CacheRows`:
 attention over a context) or a fixed-size state a SLOT (:class:`SlotState`:
 a recurrence). The engine builds its pool, its write and its decode program
-from the geometry it is given."""
+from the geometry it is given. Its programs say how many tokens a step
+yields a sequence: one, or a BLOCK (``ServingPrograms.block``: a model that
+generates by diffusion over blocks)."""
 
 from __future__ import annotations
 
@@ -55,6 +57,26 @@ class ServingPrograms(NamedTuple):
       pool of states; a slot that is not ``active`` keeps its state to the
       last bit.
 
+    With :class:`CacheRows` and ``block`` = B > 1 (generation by diffusion
+    over blocks of ``B`` positions: a step yields a block a sequence; greedy,
+    and the model's ``cfg`` names its ``mask_token_id``, which no prompt may
+    hold):
+
+    * ``prefill(params, ids (1, T), prompt_len)`` -> ``(rows (layers, sides,
+      T, width), stats)``: the forward over the prompt's whole blocks,
+      ``prompt_len`` a multiple of ``B`` (the engine gives ``(L // B) * B``);
+      no logits: the first tokens come from the first block.
+    * ``decode_step(params, kv_pages, block_init (S, B), starts (S,),
+      page_table, write_page (S, B), write_offset (S, B), active (S,) bool)``
+      -> ``(kv_pages, tokens (S, B), unmask_pass (S, B), passes, stats)``:
+      one block for every active slot, the mask token where ``block_init``
+      is masked and the prompt's tail where a first block holds one (fixed:
+      its ``unmask_pass`` is -1); ``starts`` is the block's first position
+      and the rows cached before it. ``passes`` is how many forward passes
+      the program ran, the last over the clean blocks, whose rows it writes
+      at ``write_page``/``write_offset``; no pass that still holds a mask
+      writes the pool.
+
     Either way:
 
     * ``prefill_suffix(params, ids, prefix_kv, prefix_len, suffix_len)`` ->
@@ -76,10 +98,15 @@ class ServingPrograms(NamedTuple):
     and two scalars of its hyper-connected residual (``"hc_residual"``,
     ``"hc_clamped"``) for ``observe.note_hyper_connection``. ``BrumbyModel``:
     a dict with one part, ``"retention"``: three scalars of its states for
-    ``observe.note_retention``."""
+    ``observe.note_retention``. ``SdarModel``: ``LongcatModel``'s integer
+    array, summed over a block step's passes.
+
+    ``block``: the tokens a step yields a sequence, 1 for a model that
+    decodes a token at a time."""
 
     prefill: Callable
     decode_step: Callable
     prefill_suffix: Optional[Callable] = None
     verify: Optional[Callable] = None
     note_stats: Optional[Callable] = None
+    block: int = 1

@@ -58,6 +58,7 @@ from deeplearning4j_tpu.observe.ledger import (
 
 from deeplearning4j_tpu.observe import host as _host
 from deeplearning4j_tpu.observe.host import install_gc_hook
+from deeplearning4j_tpu.observe.diffusion import note_diffusion
 from deeplearning4j_tpu.observe.hyper import note_hyper_connection
 from deeplearning4j_tpu.observe.moe import note_moe
 from deeplearning4j_tpu.observe.retention import note_retention
@@ -308,6 +309,6 @@ __all__ = [
     "metrics", "tracer", "ledger", "default_registry", "default_tracer",
     "default_ledger", "log_event", "note_jit_signature", "signature_of",
     "install_xla_listener", "install_gc_hook", "scanned_call", "note_moe",
-    "note_hyper_connection", "note_retention",
+    "note_hyper_connection", "note_retention", "note_diffusion",
     "summary", "dispatch_summary", "reset", "reset_log_state",
 ]

@@ -1197,6 +1197,238 @@ def _check_latent_decode_attention():
     np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# Block decode attention (generation by diffusion over blocks, grouped heads)
+# ---------------------------------------------------------------------------
+#
+# A block model's step runs B query positions a slot at once, the block
+# being denoised: each attends to the slot's cached K/V rows before the
+# block, in the two-sided paged pool (a row is Hkv * D values, heads merged),
+# and, bidirectionally, to the block's own B current rows, which are not in
+# the pool (a pass that still holds a mask never writes it). Query head
+# ``a`` reads key/value head ``a // (Hq / Hkv)``. Two implementations of one
+# contract: the generic gather and a Pallas kernel that walks each slot's
+# pages through the page stream the paged and latent kernels share.
+
+
+def block_decode_attention_xla(q, k_blk, v_blk, kv_pages, page_table,
+                               seq_lens, *, layer: int = 0,
+                               scale: Optional[float] = None):
+    """Generic gather path: q:[S,B,Hq,D], k_blk, v_blk:[S,B,Hkv,D] (the
+    block's own rows), kv_pages:[L,2,P,page,Hkv*D] (the whole pool),
+    page_table:[S,max_pages], seq_lens:[S] (the cached rows before the
+    block) -> [S,B,Hq,D]. Scores and softmax in float32."""
+    s_n, b, hq, d = q.shape
+    hkv = k_blk.shape[2]
+    page, max_pages = kv_pages.shape[3], page_table.shape[1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    t = max_pages * page
+    with jax.named_scope("block_decode_attention"):
+        kp, vp = (gather_pages(kv_pages, layer, side, page_table).reshape(
+            s_n, t, hkv, d).astype(jnp.float32) for side in (0, 1))
+        qg = q.astype(jnp.float32).reshape(s_n, b, hkv, hq // hkv, d)
+        pooled = jnp.einsum("sbcgd,stcd->scbgt", qg, kp) * scale
+        live = jnp.arange(t)[None, :] < seq_lens[:, None]
+        pooled = jnp.where(live[:, None, None, None, :], pooled, -1e30)
+        own = jnp.einsum("sbcgd,sucd->scbgu", qg,
+                         k_blk.astype(jnp.float32)) * scale
+        p = jax.nn.softmax(jnp.concatenate([pooled, own], -1), axis=-1)
+        out = (jnp.einsum("scbgt,stcd->sbcgd", p[..., :t], vp)
+               + jnp.einsum("scbgu,sucd->sbcgd", p[..., t:],
+                            v_blk.astype(jnp.float32)))
+        return out.reshape(s_n, b, hq, d).astype(q.dtype)
+
+
+# The block kernel's ring: a slot's pages come in groups of
+# _BLOCK_GROUP_PAGES, K and V of a group in one buffer with its own DMA
+# semaphore, _BLOCK_RING_GROUPS buffers in all (_PageStream). A bfloat16 page
+# of SDAR-30B-A3B is 16 KB a side (16 rows of 4 x 128). Untimed apart from
+# the cell it serves (docs/KERNELS.md § Block decode): eight pages a group
+# keep a slot's re-read tail under a tenth of its ~700 positions, and three
+# groups keep two in flight while one is scored.
+_BLOCK_RING_GROUPS = 3
+_BLOCK_GROUP_PAGES = 8
+# the block's own rows, padded to a whole bfloat16 tile of sublanes
+_BLOCK_ROWS = 16
+
+
+def _block_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, kb_ref, kv_hbm,
+                         o_ref, ring, sems, acc_ref, mx_ref, l_ref, cur_ref,
+                         *, page: int, scale: float, heads_kv: int,
+                         block: int):
+    """Grid step ``s``: slot ``s``'s query rows against the block's own
+    rows and then its cached pages, a group at a time, in one online
+    softmax. The query rows come ordered (key/value head, position, group
+    head), so key/value head ``c`` owns ``rows_kv = B * Hq / Hkv`` rows and
+    its keys and values are lanes ``c * D .. (c + 1) * D`` of a pool row: a
+    (rows_kv, D) x (D, span) product for the scores and a (rows_kv, span) x
+    (span, D) product for the values, a head at a time. The block's own
+    rows come first: every row's running maximum starts finite, and a slot
+    with nothing cached (its one group of the stream masked whole) gives
+    the block's attention alone. Pages are streamed from the pool where it
+    lies (:class:`_PageStream`; the walk is a rolled loop whose trip count
+    comes from ``seq_lens``)."""
+    s_idx = pl.program_id(0)
+    span = ring.shape[2]
+    d = q_ref.shape[-1]
+    rows_kv = q_ref.shape[0] // heads_kv
+    layer = layer_ref[0]
+    stream = _PageStream(
+        pt_ref, sl_ref, cur_ref, ring, sems,
+        lambda p, buf, k: pltpu.make_async_copy(
+            kv_hbm.at[layer, :, p], ring.at[buf, :, pl.ds(k * page, page)],
+            sems.at[buf]),
+        page=page, span=span)
+    stream.open()
+    seq_len = sl_ref[s_idx]
+    own = jax.lax.broadcasted_iota(jnp.int32, (rows_kv, kb_ref.shape[1]), 1)
+    for c in range(heads_kv):
+        rows, lanes = pl.ds(c * rows_kv, rows_kv), pl.ds(c * d, d)
+        s = _mm_nt(q_ref[rows, :], kb_ref[0, :, lanes]) * scale
+        s = jnp.where(own < block, s, -1e30)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        mx_ref[rows, :1] = m
+        l_ref[rows, :1] = p.sum(axis=-1, keepdims=True)
+        acc_ref[rows, :] = _mm_nn(p.astype(kb_ref.dtype), kb_ref[1, :, lanes])
+
+    def attend(g, carry):
+        stream.produce()                # into the buffer group g - 1 left
+        buf = stream.consume()
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (rows_kv, span),
+                                                  1)
+        live = pos < seq_len
+        for c in range(heads_kv):
+            rows, lanes = pl.ds(c * rows_kv, rows_kv), pl.ds(c * d, d)
+            s = _mm_nt(q_ref[rows, :], ring[buf, 0, :, lanes]) * scale
+            s = jnp.where(live, s, -1e30)
+            m_prev = mx_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows, :1] = (l_ref[rows, :1] * alpha
+                               + p.sum(axis=-1, keepdims=True))
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + _mm_nn(
+                p.astype(ring.dtype), ring[buf, 1, :, lanes])
+            mx_ref[rows, :1] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, stream.groups_of(s_idx), attend, 0)
+    stream.close()
+    o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def _block_decode_call(q, k_blk, v_blk, kv_pages, page_table, seq_lens, *,
+                       layer: int = 0, scale: Optional[float] = None,
+                       interpret: Optional[bool] = None):
+    """Pallas block decode. Same contract as block_decode_attention_xla.
+    ``layer`` rides scalar prefetch beside the page table, so every layer's
+    call is one and the same kernel."""
+    s_n, b, hq, d = q.shape
+    hkv = k_blk.shape[2]
+    page, width = kv_pages.shape[3], kv_pages.shape[4]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    rows = hq * b
+    span = _BLOCK_GROUP_PAGES * page
+    # query rows (key/value head, position, group head); the block's own
+    # rows (S, 2, _BLOCK_ROWS, width) in the pool's type
+    q_rows = q.reshape(s_n, b, hkv, hq // hkv, d).transpose(
+        0, 2, 1, 3, 4).reshape(s_n, rows, d).astype(kv_pages.dtype)
+    kb = jnp.stack([k_blk.reshape(s_n, b, width),
+                    v_blk.reshape(s_n, b, width)], axis=1)
+    kb = jnp.pad(kb.astype(kv_pages.dtype),
+                 ((0, 0), (0, 0), (0, _BLOCK_ROWS - b), (0, 0)))
+    kernel = functools.partial(_block_decode_kernel, page=page, scale=scale,
+                               heads_kv=hkv, block=b)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_n,),
+        in_specs=[pl.BlockSpec((None, rows, d), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec((None, 2, _BLOCK_ROWS, width),
+                               lambda s, *_: (s, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, rows, d), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((_BLOCK_RING_GROUPS, 2, span, width), kv_pages.dtype),
+            pltpu.SemaphoreType.DMA((_BLOCK_RING_GROUPS,)),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.SMEM((4,), jnp.int32),
+        ],
+    )
+    with jax.named_scope("block_decode_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, rows, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=pallas_interpret(interpret),
+            name="block_decode_attention",
+        )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+          jnp.full((1,), layer, jnp.int32), q_rows, kb, kv_pages)
+    return out.reshape(s_n, hkv, b, hq // hkv, d).transpose(
+        0, 2, 1, 3, 4).reshape(s_n, b, hq, d)
+
+
+def _block_usable(q, k_blk, v_blk, kv_pages, page_table, seq_lens, **kw):
+    """The Pallas block path takes whole-tile pages (row a multiple of 128
+    lanes, page of 8 sublanes), head widths of whole lanes (a key/value
+    head's lanes are a lane-aligned slice of a row), a block that fits the
+    padded rows and a key/value head's query rows in whole tiles of the
+    pool's type; the tiny test models take the generic path."""
+    if getattr(q, "ndim", 0) != 4 or getattr(kv_pages, "ndim", 0) != 5:
+        return False
+    if getattr(page_table, "ndim", 0) != 2 or kv_pages.shape[1] != 2:
+        return False
+    s_n, b, hq, d = q.shape
+    hkv = k_blk.shape[2]
+    sublanes = 8 * 4 // jnp.dtype(kv_pages.dtype).itemsize
+    return (hq % hkv == 0 and hkv * d == kv_pages.shape[4]
+            and d % 128 == 0 and kv_pages.shape[3] % 8 == 0
+            and b <= _BLOCK_ROWS and (b * hq // hkv) % sublanes == 0)
+
+
+def _check_block_decode_attention():
+    """Validation case: the generic path against a numpy oracle and the
+    Pallas kernel (interpreted) against both, on the second layer, at
+    grouped heads (4 query heads over 2): one slot with nothing cached, one
+    whose last page is partial, one whose walk goes once round the ring
+    and on into the next slot."""
+    rs = np.random.RandomState(13)
+    s_n, b, hq, hkv, d, page, layer = 3, 4, 4, 2, 8, 8, 1
+    grp = hq // hkv
+    span = _BLOCK_GROUP_PAGES * page
+    max_pages = _BLOCK_RING_GROUPS * _BLOCK_GROUP_PAGES + 3
+    n_pages = 2 * max_pages
+    sl = np.array([0, 3 * page + 5, _BLOCK_RING_GROUPS * span + 2 * page],
+                  np.int32)
+    assert sl.max() <= max_pages * page
+    q = rs.randn(s_n, b, hq, d).astype(np.float32)
+    kb = rs.randn(s_n, b, hkv, d).astype(np.float32)
+    vb = rs.randn(s_n, b, hkv, d).astype(np.float32)
+    kv = rs.randn(2, 2, n_pages, page, hkv * d).astype(np.float32)
+    pt = np.stack([rs.choice(n_pages, max_pages, replace=False)
+                   for _ in range(s_n)]).astype(np.int32)
+    scale = 1.0 / math.sqrt(d)
+    want = np.zeros_like(q)
+    for i in range(s_n):
+        keys = kv[layer, 0, pt[i]].reshape(-1, hkv, d)[:sl[i]]
+        vals = kv[layer, 1, pt[i]].reshape(-1, hkv, d)[:sl[i]]
+        keys = np.concatenate([keys, kb[i]])
+        vals = np.concatenate([vals, vb[i]])
+        for a in range(hq):
+            sc = keys[:, a // grp] @ q[i, :, a].T * scale      # (T, B)
+            p = np.exp(sc - sc.max(axis=0))
+            want[i, :, a] = (p / p.sum(axis=0)).T @ vals[:, a // grp]
+    args = tuple(jnp.asarray(a) for a in (q, kb, vb, kv, pt, sl))
+    got = block_decode_attention_xla(*args, layer=layer)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    got_pl = _block_decode_call(*args, layer=layer)
+    np.testing.assert_allclose(np.asarray(got_pl), want, rtol=1e-4, atol=1e-5)
+
+
 def register_platform_attention() -> None:
     """Install flash attention as the TPU platform override for the generic
     dot_product_attention op, and register the paged decode-attention op
@@ -1229,6 +1461,20 @@ def register_platform_attention() -> None:
                               _latent_decode_call, _latent_usable)
         _validation.add_case("latent_decode_attention",
                              _check_latent_decode_attention)
+
+    if "block_decode_attention" not in reg:
+        reg.register(
+            "block_decode_attention", block_decode_attention_xla,
+            doc="block-step attention of a block diffusion model: B query "
+                "positions a slot over the two-sided paged pool and the "
+                "block's own rows, grouped heads (q:[S,B,Hq,D], k_blk, "
+                "v_blk:[S,B,Hkv,D], kv_pages:[L,2,P,page,Hkv*D], "
+                "page_table:[S,max_pages], seq_lens:[S], layer=, scale= "
+                "-> [S,B,Hq,D])")
+        reg.register_platform("block_decode_attention", "tpu",
+                              _block_decode_call, _block_usable)
+        _validation.add_case("block_decode_attention",
+                             _check_block_decode_attention)
 
     def flash_dpa(q, k, v, mask=None, *, scaled: bool = True,
                   causal: bool = False,

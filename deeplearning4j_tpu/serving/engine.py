@@ -60,6 +60,18 @@ refcount-safe. Slots with ``temperature > 0`` (or
 shape depends only on ``(max_slots, spec_k, page geometry)`` — the
 ledger stays at one ``first_compile`` per function, zero ``new_shape``.
 
+A model whose programs yield a BLOCK a step (``ServingPrograms.block`` > 1:
+generation by diffusion over blocks, ``models/sdar.py``) takes the
+same loop with the block model's programs (``models/served.py``): its
+``prefill`` writes the prompt's whole blocks and yields no token; its
+``decode`` denoises one block for every slot in one program and stores the
+clean block's rows. The bank holds each slot's next block as it starts (the
+mask token, or a first block's fixed prompt tail) instead of a token; a
+step reserves pages to the block's end, advances a slot's cached length by
+the block and commits up to the block's tokens a slot, stamped together,
+cutting at an ``eos`` or at ``max_new_tokens``. Nothing of it needs a
+token's value, so the worker runs it ahead as it runs the one-token step.
+
 Observability (docs/OBSERVABILITY.md catalog additions): admitted/evicted/
 generated-token counters, slot-occupancy gauge, decode-step latency
 histogram, TTFT + inter-token histograms, ``serving_prefill``/
@@ -132,6 +144,12 @@ class _DecodeStep(NamedTuple):
     toks: Any              # the bank's token vector after the step, device
     stats: Any
     span: Any              # the step's ``serving_decode`` span
+    # a block step's (``_step_block``): the pass that unmasked each token and
+    # the passes run, on the device; a slot's (fixed positions, tokens it
+    # yields), aligned with ``slots``
+    when: Any = None
+    passes: Any = None
+    yields: Optional[list] = None
 
 
 def build_write(page: int, trash: int):
@@ -200,6 +218,28 @@ def build_decode(decode_step, page: int, trash: int):
             write_page, write_off)
         toks = sample_tokens(logits, key, temp, top_k, top_p)
         return kv_pages, jnp.where(on, toks, tokens), logits, stats
+
+    return decode
+
+
+def build_block_decode(decode_step, page: int, trash: int):
+    """The jitted ``decode`` of a block model (``ServingPrograms.block`` > 1): one
+    block for every slot against the donated pool (the model's
+    ``decode_step``), its rows written where the slot's pages hold the
+    block's positions (the trash page for a slot that sits the step out). A
+    function of configuration and page geometry alone, like
+    :func:`build_decode`; greedy, so no key and no sampler."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode(params, kv_pages, page_table, seq_lens, block_init, active):
+        s_n, b = block_init.shape
+        on = active > 0
+        pos = seq_lens[:, None] + jnp.arange(b)[None, :]
+        row = jnp.clip(pos // page, 0, page_table.shape[1] - 1)
+        write_page = jnp.where(
+            on[:, None], page_table[jnp.arange(s_n)[:, None], row], trash)
+        return decode_step(params, kv_pages, block_init, seq_lens,
+                           page_table, write_page, pos % page, on)
 
     return decode
 
@@ -292,6 +332,13 @@ class GenerativeEngine:
             # the slot bank by default.
             num_pages = max_slots * max_pages_per_seq + max(0, prefix_pages)
         rows = model.cache_rows()
+        # tokens a step yields a sequence: 1, or a block model's block
+        self.block = int(self.programs.block)
+        if self.block > 1 and (prefix_pages or spec_k):
+            raise ValueError(
+                f"{type(model).__name__} yields a block of {self.block} "
+                f"tokens a step: no prefix cache and no speculation for it "
+                f"(docs/SERVING.md)")
         if isinstance(rows, SlotState):
             # a state a slot: no page to hand out, every array in the dtype
             # the model names; the context limit is the paged arithmetic
@@ -305,10 +352,11 @@ class GenerativeEngine:
                 num_pages=num_pages, max_slots=max_slots,
                 max_pages_per_seq=max_pages_per_seq,
                 dtype=jax.tree.leaves(model.params)[0].dtype)
-        if self.max_prompt + 1 > self.cache.max_context():
+        if self._first_need(self.max_prompt) > self.cache.max_context():
             raise ValueError(
-                f"max_prompt={max_prompt} + 1 exceeds per-slot context "
-                f"{self.cache.max_context()} "
+                f"max_prompt={max_prompt} + "
+                f"{'1' if self.block == 1 else 'a block'} exceeds per-slot "
+                f"context {self.cache.max_context()} "
                 f"(page_size*max_pages_per_seq)")
         self.scheduler = SlotScheduler(max_slots)
         # ---------------------------------------- radix prefix cache (2a)
@@ -376,6 +424,11 @@ class GenerativeEngine:
         # admission's first token set by its prefill); a host array only
         # until the first launch
         self._toks: Any = np.zeros((max_slots,), np.int32)
+        # a block model's bank: every slot's next block as it starts, the
+        # mask token where masked (a first block's prompt tail is fixed)
+        self._block_init = (np.full((max_slots, self.block),
+                                    cfg.mask_token_id, np.int32)
+                            if self.block > 1 else None)
         # launched and not read yet (docs/SERVING.md § The loop): the decode
         # step the worker keeps in flight, and this iteration's admissions
         self._flying: Optional[_DecodeStep] = None
@@ -486,6 +539,8 @@ class GenerativeEngine:
 
     # ---------------------------------------------------------- compiled fns
     def _build_prefill(self):
+        if self.block > 1:
+            return jax.jit(self.programs.prefill)
         return build_prefill(self.programs.prefill)
 
     def _build_write(self):
@@ -540,6 +595,10 @@ class GenerativeEngine:
         return suffix_prefill
 
     def _build_decode(self):
+        if self.block > 1:
+            return build_block_decode(self.programs.decode_step,
+                                      self.cache.page_size,
+                                      self.cache.trash_page)
         if isinstance(self.cache, SlotStatePool):
             return build_state_decode(self.programs.decode_step)
         return build_decode(self.programs.decode_step, self.cache.page_size,
@@ -615,6 +674,20 @@ class GenerativeEngine:
             raise ValueError(
                 f"prompt token ids must be in [0, {self.cfg.vocab_size}), "
                 f"got range [{lo}, {hi}]")
+        if self.block > 1:
+            if req.temperature > 0.0:
+                raise ValueError(
+                    f"{type(self.model).__name__} denoises by the static "
+                    f"low-confidence schedule, which is greedy: sampled "
+                    f"requests (temperature {req.temperature}) are not "
+                    f"served")
+            if np.any(req.prompt == self.cfg.mask_token_id):
+                # a masked position of the first block is what the program
+                # fills: a prompt that holds the mask token would be
+                # rewritten
+                raise ValueError(
+                    f"prompt holds the mask token "
+                    f"{self.cfg.mask_token_id}")
 
     def submit_request(self, req: GenerationRequest
                        ) -> "Future[GenerationResult]":
@@ -877,6 +950,8 @@ class GenerativeEngine:
         self._flying = None
         self._landing.clear()
         self._toks = np.zeros((self.cache.max_slots,), np.int32)
+        if self._block_init is not None:
+            self._block_init[:] = self.cfg.mask_token_id
         leaving, sched.leaving = sched.leaving, []
         for st in reversed(leaving):
             self._requeue_or_fail(st)
@@ -1045,16 +1120,21 @@ class GenerativeEngine:
         else:
             self.cache.check_invariants()
         sched = self.scheduler
-        flying = ([st for _slot, st in self._flying.slots]
-                  if self._flying is not None else [])
-        waited = flying + [adm.st for adm in self._landing]
+        # (sequence, the tokens a launch waiting to be read yields it)
+        waited = []
+        if self._flying is not None:
+            ys = self._flying.yields or [(0, 1)] * len(self._flying.slots)
+            waited = [(st, n) for (_slot, st), (_f, n)
+                      in zip(self._flying.slots, ys)]
+        waited += [(adm.st, int(adm.tok is not None))
+                   for adm in self._landing]
         for st in list(sched.slots.values()) + sched.leaving:
-            n = sum(w is st for w in waited)
-            assert st.unlanded == n <= 1, (
+            mine = [n for w, n in waited if w is st and n]
+            assert st.unlanded == sum(mine) and len(mine) <= 1, (
                 f"request {st.request.request_id}: {st.unlanded} tokens "
-                f"counted unlanded, {n} launches waiting to be read")
+                f"counted unlanded, {len(mine)} launches waiting to be read")
         for st in sched.leaving:
-            assert st.unlanded == 1 and len(st.tokens) + 1 >= \
+            assert st.unlanded >= 1 and len(st.tokens) + st.unlanded >= \
                 st.request.max_new_tokens, (
                     f"request {st.request.request_id} left its slot with "
                     f"{len(st.tokens)} tokens and {st.unlanded} in flight")
@@ -1065,6 +1145,13 @@ class GenerativeEngine:
             self.spec.check_invariants(self._spec_slots, self.cache.seq_lens)
 
     # ------------------------------------------------------------ scheduling
+    def _first_need(self, prompt_len: int) -> int:
+        """Positions an admission needs room for: the prompt and the first
+        token, which the same iteration's decode caches; a block model's
+        prompt's whole blocks and its first block, to the block's end."""
+        b = self.block
+        return prompt_len + 1 if b == 1 else (prompt_len // b + 1) * b
+
     def _free_slot(self, slot: int, completed: bool) -> None:
         """Give back what ``slot`` holds beside its scheduler state: its
         pages and its draft row."""
@@ -1185,8 +1272,9 @@ class GenerativeEngine:
                 self._finish_unslotted(req, fut, "deadline", t_sub)
 
             # 2. capacity: every surviving slot needs room for one more token
+            #    (a block model's: for the block, to its end)
             for slot in sched.active_slots():
-                need = int(cache.seq_lens[slot]) + 1
+                need = int(cache.seq_lens[slot]) + self.block
                 if need > self.cfg.max_position:
                     self._retire_or_hold(slot, "overflow", hold)
                     continue
@@ -1222,7 +1310,7 @@ class GenerativeEngine:
                 # the bill (the CoW tail still costs a fresh page), and the
                 # tree's unpinned pages count as reclaimable supply.
                 match = self._match_prefix(req)
-                need_new = cache.pages_for(p_len + 1) - (
+                need_new = cache.pages_for(self._first_need(p_len)) - (
                     match.matched // cache.page_size if match else 0)
                 if need_new > cache.free_pages:
                     # only now pay the O(tree) reclaimable walk: tree pages
@@ -1282,9 +1370,12 @@ class GenerativeEngine:
                     with sched._plock:
                         sched.pending.appendleft(item)
                     raise
-                cache.seq_lens[slot] = p_len
+                # a block model's prefill caches the prompt's whole blocks
+                # and launches no token
+                cache.seq_lens[slot] = p_len // self.block * self.block
                 st = sched.admit(slot, req, fut, t_sub,
-                                 prefix_hit_tokens=hit_tokens)
+                                 prefix_hit_tokens=hit_tokens,
+                                 unlanded=int(self.block == 1))
                 self._landing.append(_Admission(st, adm.id, *launched))
                 if not ahead:
                     self._land_admissions()
@@ -1362,7 +1453,9 @@ class GenerativeEngine:
         faults.maybe_sleep("slow_decode", 0.05)
 
         produced = 0
-        if plain:
+        if plain and self.block > 1:
+            produced += self._step_block(plain, ahead)
+        elif plain:
             produced += self._step_decode(plain, ahead)
         if spec_now:
             produced += self._step_speculative(spec_now)
@@ -1482,13 +1575,111 @@ class GenerativeEngine:
             self._obs["generated"].inc(produced)
         return produced
 
+    def _step_block(self, active: List[int], ahead: bool) -> int:
+        """A block model's iteration over ``active``: launch one block for
+        each (the bank's ``_block_init``: the mask token, or a first block's
+        fixed prompt tail), then read and commit the step that is due, as
+        :meth:`_step_decode` does. A slot's cached length advances by the
+        block at the launch, and it counts the tokens its block yields as
+        unlanded until they land."""
+        cache, sched = self.cache, self.scheduler
+        tracer = observe.tracer()
+        b, m_id = self.block, self.cfg.mask_token_id
+        if self._decode_fn is None:
+            self._decode_fn = self._build_decode()
+        with tracer.span("serving_decode_upload", category="serving"):
+            act = np.zeros((cache.max_slots,), np.int32)
+            act[active] = 1
+            init = self._block_init.copy()
+            args = (*cache.decode_args(), self._resident("block_init", init),
+                    self._resident("active", act))
+            observe.note_jit_signature(
+                self._decode_fn, graph="serving", key="decode",
+                signature=observe.signature_of(
+                    page_table=cache.page_table, seq_lens=cache.seq_lens,
+                    block_init=init, active=act))
+        before = self._flying
+        t0 = time.perf_counter()
+        with tracer.span("serving_decode", category="serving",
+                         slots=len(active),
+                         ahead=int(before is not None)) as sp:
+            with tracer.span("serving_decode_launch", category="serving"):
+                cache.kv, toks, when, passes, stats = self._decode_fn(
+                    self.model.params, cache.kv, *args)
+            fixed = (init[active] != m_id).sum(axis=1).tolist()
+            step = _DecodeStep(
+                [(slot, sched.slots[slot]) for slot in active], toks, stats,
+                sp, when=when, passes=passes,
+                yields=[(f, b - f) for f in fixed])
+            for (slot, st), (_f, n) in zip(step.slots, step.yields):
+                cache.seq_lens[slot] += b
+                st.unlanded += n
+            # a first block's prompt tail went with this launch
+            self._block_init[active] = m_id
+            self._flying = step if ahead else None
+            due = before if ahead else step
+            read = self._read_block(due) if due is not None else None
+        self._obs["decode_h"].observe(time.perf_counter() - t0)
+        self._obs["launches"][int(before is not None)].inc()
+        return self._commit_block(due, *read) if due is not None else 0
+
+    def _read_block(self, step: _DecodeStep) -> tuple:
+        """The blocking read of a launched block step: its tokens, the pass
+        that unmasked each, the passes run and the model's statistics in ONE
+        transfer; the statistics and the step's diffusion arguments go to
+        its own ``serving_decode`` span, open or closed."""
+        with observe.tracer().span("serving_decode_read",
+                                   category="serving", host=True):
+            toks, when, passes, stats = jax.device_get(
+                (step.toks, step.when, step.passes, step.stats))
+        if stats is not None:
+            self.programs.note_stats(stats, step.span, decode_step=True,
+                                     tokens=toks.size)
+        masked = sum(n for _f, n in step.yields)
+        observe.note_diffusion(passes, masked, masked, len(step.slots),
+                               step.span)
+        return toks, when
+
+    def _commit_block(self, step: _DecodeStep, toks: np.ndarray,
+                      when: np.ndarray) -> int:
+        """Hand a read block step's tokens to their sequences, stamped
+        together now: a slot's masked positions in order, cut after an
+        ``eos`` and at ``max_new_tokens``. What is cut is dropped (its rows
+        lie past the length the result keeps), as is the whole block of a
+        sequence that is gone or complete by what landed before."""
+        sched = self.scheduler
+        with observe.tracer().span("serving_commit", category="serving"):
+            now = time.perf_counter()
+            produced = 0
+            for (slot, st), (fixed, n) in zip(step.slots, step.yields):
+                if not sched.holds(slot, st) or sched.finish_reason(st):
+                    st.unlanded -= n
+                    continue
+                keep = [int(t) for t in toks[slot, fixed:]]
+                keep = keep[:st.request.max_new_tokens - len(st.tokens)]
+                if st.request.eos_token in keep:
+                    keep = keep[:keep.index(st.request.eos_token) + 1]
+                first = st.ttft_s is None
+                gaps = sched.on_block_tokens(
+                    st, keep, [int(p) for p in when[slot, fixed:]], now, n)
+                if first and keep:
+                    self._obs["ttft_h"].observe(now - st.submit_t)
+                for gap in gaps:
+                    self._obs["itl_h"].observe(gap)
+                produced += len(keep)
+                self._finish_leaving(st)
+            self._obs["generated"].inc(produced)
+        return produced
+
     def _land(self) -> int:
         """Read and commit everything launched and not read yet: the decode
         step in flight, then the admissions, in launch order. Returns the
         decode tokens committed."""
         step, self._flying = self._flying, None
         produced = 0
-        if step is not None:
+        if step is not None and step.yields is not None:
+            produced = self._commit_block(step, *self._read_block(step))
+        elif step is not None:
             produced = self._commit_decode(step, self._read_decode(step))
         self._land_admissions()
         return produced
@@ -1520,6 +1711,9 @@ class GenerativeEngine:
                                     category="serving", parent=pid)
             tracer.complete_between("serving_prefill_read", t0, now,
                                     category="serving", parent=pid, **used)
+            if tok is None:
+                # a block model's admission launched no token
+                continue
             sched.on_first_token(adm.st, int(tok), now)
             self._obs["generated"].inc()
             self._obs["ttft_h"].observe(now - adm.st.submit_t)
@@ -1630,6 +1824,8 @@ class GenerativeEngine:
         match = self._slot_match.pop(slot, None)
         if match is not None:
             return self._prefill_suffix_into(slot, req, match)
+        if self.block > 1:
+            return self._prefill_block_into(slot, req)
         cache = self.cache
         p_len = int(req.prompt.size)
         ids = np.zeros((1, self.max_prompt), np.int32)
@@ -1659,6 +1855,38 @@ class GenerativeEngine:
                                   *cache.write_args(slot, p_len))
         return (tok, stats, (t0, time.perf_counter()),
                 dict(prompt_len=p_len, request=req.request_id))
+
+    def _prefill_block_into(self, slot: int, req: GenerationRequest) -> tuple:
+        """A block model's admission: launch the forward over the prompt's
+        whole blocks and the write of their rows into the slot's pages, and
+        set the slot's first block in the bank (the prompt's tail, fixed,
+        then the mask token). No token is launched: the first block's
+        tokens come from the next decode step."""
+        cache, b = self.cache, self.block
+        p_len = int(req.prompt.size)
+        whole = p_len // b * b
+        ids = np.zeros((1, self.max_prompt), np.int32)
+        ids[0, :p_len] = req.prompt
+        if self._prefill_fn is None:
+            self._prefill_fn = self._build_prefill()
+        if self._write_fn is None:
+            self._write_fn = self._build_write()
+        observe.note_jit_signature(
+            self._prefill_fn, graph="serving", key="prefill",
+            signature=observe.signature_of(ids=ids))
+        observe.note_jit_signature(
+            self._write_fn, graph="serving", key="write_prompt",
+            signature=observe.signature_of(ids=ids))
+        t0 = time.perf_counter()
+        rows, stats = self._prefill_fn(self.model.params, ids,
+                                       np.int32(whole))
+        cache.kv = self._write_fn(cache.kv, rows,
+                                  *cache.write_args(slot, whole))
+        self._block_init[slot] = self.cfg.mask_token_id
+        self._block_init[slot, :p_len - whole] = req.prompt[whole:]
+        return (None, stats, (t0, time.perf_counter()),
+                dict(prompt_len=p_len, request=req.request_id, dd_passes=1,
+                     dd_masked=0, dd_tokens=0))
 
     def _prefill_suffix_into(self, slot: int, req: GenerationRequest,
                              match: PrefixMatch) -> tuple:

@@ -173,6 +173,10 @@ class GenerationResult:
     spec_proposed_tokens: int = 0
     spec_accepted_tokens: int = 0
     spec_disabled: bool = False
+    # a block model's (generation by diffusion over blocks): the denoising
+    # pass of its block at which each token was unmasked; None for a model
+    # that decodes a token at a time
+    unmask_pass: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass(eq=False)
@@ -188,9 +192,10 @@ class _Slot:
     prefix_hit_tokens: int = 0
     spec_proposed_tokens: int = 0
     spec_accepted_tokens: int = 0
-    # tokens launched for this sequence (its prefill's, a decode step's)
-    # that the host has not read yet
+    # tokens launched for this sequence (its prefill's, a decode step's,
+    # a block step's whole block) that the host has not read yet
     unlanded: int = 0
+    unmask_pass: Optional[List[int]] = None
 
 
 class SlotScheduler:
@@ -289,16 +294,18 @@ class SlotScheduler:
               future: "Future[GenerationResult]", submit_t: float,
               first_token: Optional[int] = None,
               now: Optional[float] = None,
-              prefix_hit_tokens: int = 0) -> _Slot:
+              prefix_hit_tokens: int = 0, unlanded: int = 1) -> _Slot:
         """Install a request whose prefill was launched into ``slot``. Its
         first sampled token is in flight (``unlanded``) until
-        :meth:`on_first_token` lands it; given here, it lands at once.
+        :meth:`on_first_token` lands it; given here, it lands at once. A
+        block model's prefill launches no token (``unlanded=0``).
         ``prefix_hit_tokens`` records how much of the prompt the radix
         prefix cache served — it rides into the GenerationResult so
         callers and the replay bench can account hits per request."""
         st = _Slot(request=request, future=future, submit_t=submit_t,
                    prompt_len=int(request.prompt.size),
-                   prefix_hit_tokens=int(prefix_hit_tokens), unlanded=1)
+                   prefix_hit_tokens=int(prefix_hit_tokens),
+                   unlanded=int(unlanded))
         self.slots[slot] = st
         if first_token is not None:
             self.on_first_token(st, first_token, now)
@@ -318,6 +325,33 @@ class SlotScheduler:
         if st.last_token_t is not None:
             st.intertoken_s.append(now - st.last_token_t)
         st.last_token_t = now
+
+    def on_block_tokens(self, st: _Slot, tokens: List[int],
+                        passes: List[int], now: float,
+                        launched: int) -> List[float]:
+        """A block step's tokens for ``st`` reached the host together:
+        ``launched`` were counted unlanded, ``tokens`` are what the commit
+        keeps of them (cut at an ``eos`` or at ``max_new_tokens``),
+        ``passes`` the pass that unmasked each. They are stamped together:
+        the first is the first token (TTFT) or follows the last landed one
+        by the step's gap, the rest follow it by 0. Returns the gaps it
+        appended."""
+        st.unlanded -= int(launched)
+        if not tokens:
+            return []
+        if st.unmask_pass is None:
+            st.unmask_pass = []
+        gaps = [0.0] * len(tokens)
+        if st.ttft_s is None:
+            st.ttft_s = now - st.submit_t
+            gaps = gaps[1:]
+        else:
+            gaps[0] = now - st.last_token_t
+        st.tokens.extend(int(t) for t in tokens)
+        st.unmask_pass.extend(int(p) for p in passes[:len(tokens)])
+        st.intertoken_s.extend(gaps)
+        st.last_token_t = now
+        return gaps
 
     def on_spec_tokens(self, slot: int, tokens: List[int], now: float,
                        proposed: int, accepted: int) -> Optional[float]:
@@ -384,6 +418,8 @@ class SlotScheduler:
         toks = st.tokens
         if reason == "eos" and toks and toks[-1] == st.request.eos_token:
             toks = toks[:-1]
+        passes = (None if st.unmask_pass is None
+                  else np.asarray(st.unmask_pass[:len(toks)], np.int32))
         result = GenerationResult(
             tokens=np.asarray(toks, np.int32), finish_reason=reason,
             prompt_len=st.prompt_len, ttft_s=st.ttft_s,
@@ -392,7 +428,7 @@ class SlotScheduler:
             prefix_hit_tokens=st.prefix_hit_tokens,
             spec_proposed_tokens=st.spec_proposed_tokens,
             spec_accepted_tokens=st.spec_accepted_tokens,
-            spec_disabled=st.request.spec_disabled)
+            spec_disabled=st.request.spec_disabled, unmask_pass=passes)
         note_terminal(st.request, st.submit_t, reason, len(toks))
         if not st.future.done():
             # graftlife: justified(GR003): retire() only forms the result —

@@ -477,6 +477,66 @@ def test_brumby_serving_programs_update_the_state_pool_in_place(one_chip,
             + mem.output_size_in_bytes + pool_bytes) < 15.7e9
 
 
+def test_sdar_block_programs_update_the_pool_in_place(one_chip, monkeypatch):
+    """SDAR's block ``decode`` and ``write_prompt`` at its cell's geometry
+    (six expert layers of all 128 experts, the whole vocabulary, 128 slots
+    of 96 pages, blocks of 4): every pass of the rolled loop reads the pool
+    where it lies and the clean block's rows are scattered into it in
+    place, twelve scatters; the block kernel is on its Pallas path once a
+    layer under its own name, one body for the six calls; the grouped
+    products take all 512 x 8 rows a pass; the program fits the chip beside
+    8.72 GB of weights (its temporaries are one pass's float32 logits, 0.31
+    GB)."""
+    import importlib
+
+    from deeplearning4j_tpu.models import sdar
+    from deeplearning4j_tpu.ops import tuning
+    from deeplearning4j_tpu.serving.engine import build_block_decode, \
+        build_write
+
+    registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(tuning, "current_device_kind", lambda: "tpu_v5_lite")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cfg = sdar.SdarConfig(num_hidden_layers=6)
+    rows = sdar.sdar_cache_rows(cfg)
+    assert rows == (6, 2, 512)
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    pool = sds((6, 2, LC_N_PAGES + 1, PAGE, 512), bf16)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: sdar.init_sdar_params(jax.random.key(0), cfg,
+                                                     bf16)))
+    compiled = {
+        "decode": build_block_decode(
+            sdar.sdar_programs(cfg).decode_step, PAGE, LC_N_PAGES).lower(
+            params, pool, sds((LC_SLOTS, LC_PAGES_PER_SEQ), i32),
+            sds((LC_SLOTS,), i32), sds((LC_SLOTS, 4), i32),
+            sds((LC_SLOTS,), i32)).compile(),
+        "write_prompt": build_write(PAGE, LC_N_PAGES).lower(
+            pool, sds((6, 2, LC_MAX_PROMPT, 512), bf16),
+            sds((LC_PAGES_PER_SEQ,), i32), sds((), i32)).compile(),
+    }
+    _assert_pool_in_place(pool, compiled, LC_N_PAGES, temp_share=0.15)
+    decode = compiled["decode"].as_text()
+    assert len(re.findall(r"= bf16\[6,2,12289,16,512\]\S* scatter\(",
+                          decode)) == 12
+    kernels = re.findall(r"%block_decode_attention[.\d]* = (\S+) .*"
+                         r'custom_call_target="tpu_custom_call"', decode)
+    assert len(kernels) == 6
+    assert all(k.startswith("bf16[128,128,128]") for k in kernels), kernels
+    bodies = set(_mosaic_bodies(decode, "block_decode_attention"))
+    assert len({_mosaic_ops(b)[1] for b in bodies}) == 1
+    for kernel, dt in (("grouped_gate_up", "bf16"), ("grouped_down", "f32")):
+        got = re.findall(rf"%{kernel}[.\d]* = {dt}\[(\d+),\d+\]", decode)
+        assert got == ["4096"] * 6, (kernel, got)
+    mem = compiled["decode"].memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.7e9
+
+
 def test_the_grouped_products_are_the_kernels_at_both_cells(one_chip,
                                                            monkeypatch):
     """The expert layers' grouped products in the compiled ``decode`` and
