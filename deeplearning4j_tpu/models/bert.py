@@ -162,6 +162,10 @@ def bert_encoder(params, ids, segments, mask, cfg: BertConfig, *,
             if train and cfg.dropout > 0 and rngs[2 * i + 1] is not None:
                 keep = jax.random.bernoulli(rngs[2 * i + 1], 1 - cfg.dropout,
                                             hdn.shape)
+                # kept in memory (a byte an element): left free, XLA copies
+                # the threefry draw into each of its three consumers (the
+                # W2 product, W2's gradient, the hidden gradient)
+                keep = jax.lax.optimization_barrier(keep)
                 hdn = jnp.where(keep, hdn / (1 - cfg.dropout), 0.0)
             x = _layer_norm(x + hdn @ f["W2"] + f["b2"], f["ln_gamma"],
                             f["ln_beta"], cfg.layer_norm_eps)

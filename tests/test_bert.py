@@ -133,6 +133,83 @@ class TestBertModel:
         assert np.isfinite(hist).all()
         assert hist[-1] < hist[0], hist
 
+    def test_kept_hidden_mask_gives_the_inline_draws_loss_and_grads(self):
+        """The feed-forward's keep mask is drawn once and stored; the step
+        must see the very bits of the plain inline draw (same key chain,
+        same shape, same scaling), so loss and every gradient agree."""
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.models.bert import _attention, _layer_norm
+        from deeplearning4j_tpu.nn import dtype as DT
+
+        cfg = BertConfig.tiny(dropout=0.1)
+        params = init_bert_params(jax.random.key(3), cfg)
+        rs = np.random.RandomState(3)
+        ids = rs.randint(5, cfg.vocab_size, (2, 16)).astype(np.int32)
+        segments = np.zeros_like(ids)
+        mask = np.ones_like(ids)
+        labels = rs.randint(5, cfg.vocab_size, ids.shape).astype(np.int32)
+        weight = (rs.rand(*ids.shape) < 0.3).astype(np.float32)
+        rng = jax.random.key(11)
+
+        def nll(logits):
+            logp = jax.nn.log_softmax(logits)
+            picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)
+            return -jnp.sum(picked[..., 0] * weight) / np.sum(weight)
+
+        def kept(p):
+            return nll(mlm_logits(p, ids, segments, mask, cfg, train=True,
+                                  rng=rng))
+
+        def inline(p):
+            eps, emb = cfg.layer_norm_eps, p["embeddings"]
+            with DT.precision_scope("float32"):
+                x = (emb["word"][ids] + emb["position"][jnp.arange(16)][None]
+                     + emb["token_type"][segments])
+                x = _layer_norm(x, emb["ln_gamma"], emb["ln_beta"], eps)
+                rngs = jax.random.split(rng, cfg.layers * 2)
+                for i, blk in enumerate(p["encoder"]):
+                    a = _attention(blk["attn"], x, mask, cfg, train=True,
+                                   rng=rngs[2 * i])
+                    x = _layer_norm(x + a, blk["attn"]["ln_gamma"],
+                                    blk["attn"]["ln_beta"], eps)
+                    f = blk["ffn"]
+                    hdn = jax.nn.gelu(x @ f["W1"] + f["b1"])
+                    keep = jax.random.bernoulli(rngs[2 * i + 1],
+                                                1 - cfg.dropout, hdn.shape)
+                    hdn = jnp.where(keep, hdn / (1 - cfg.dropout), 0.0)
+                    x = _layer_norm(x + hdn @ f["W2"] + f["b2"],
+                                    f["ln_gamma"], f["ln_beta"], eps)
+                m = p["mlm"]
+                h = _layer_norm(jax.nn.gelu(x @ m["W"] + m["b"]),
+                                m["ln_gamma"], m["ln_beta"], eps)
+                return nll(h @ emb["word"].T + m["bias"])
+
+        loss, grads = jax.jit(jax.value_and_grad(kept))(params)
+        want, want_grads = jax.jit(jax.value_and_grad(inline))(params)
+        assert jnp.dtype(loss.dtype) == jnp.float32
+        np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want_grads)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=1e-6,
+                                       err_msg=jax.tree_util.keystr(path))
+        # and dropout does act: another key draws other masks
+        assert float(nll(mlm_logits(params, ids, segments, mask, cfg,
+                                    train=True, rng=jax.random.key(12)))) \
+            != float(loss)
+
+    @pytest.mark.parametrize("train,dropout", [(False, 0.1), (True, 0.0)])
+    def test_no_mask_is_drawn_or_kept_without_dropout(self, train, dropout):
+        """``predict`` and a dropout-free step trace no draw and no barrier."""
+        cfg = BertConfig.tiny(dropout=dropout)
+        params = init_bert_params(jax.random.key(0), cfg)
+        ids = np.zeros((2, 8), np.int32)
+        jaxpr = str(jax.make_jaxpr(
+            lambda p: mlm_logits(p, ids, ids, np.ones_like(ids), cfg,
+                                 train=train, rng=jax.random.key(1)))(params))
+        assert "optimization_barrier" not in jaxpr
+        assert "random_bits" not in jaxpr and "threefry" not in jaxpr
+
     def test_param_count_base_is_bertbase_sized(self):
         cfg = BertConfig.base()
         params = init_bert_params(jax.random.key(0), cfg)

@@ -620,6 +620,44 @@ def test_the_samplers_sorts_stand_inside_a_conditional(one_chip, monkeypatch,
         assert any(" conditional(" in ln for ln in always), name
 
 
+def test_the_mlm_step_draws_each_hidden_mask_once(one_chip, monkeypatch):
+    """BERT's training step makes the bits of each layer's feed-forward
+    keep mask in one place. Left to itself XLA copies the threefry draw
+    into the three fusions that read the mask (the W2 product, W2's
+    gradient and the hidden gradient): at BERT-base on a v5e that was some
+    23 ms of a 159 ms step (PERF.md section 5). The intermediate width
+    (256) is no other dimension of the program."""
+    import importlib
+
+    from deeplearning4j_tpu.models.bert import BertConfig, BertModel
+    from deeplearning4j_tpu.ops import tuning
+
+    registry = importlib.import_module("deeplearning4j_tpu.ops.registry")
+    monkeypatch.setattr(registry, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(tuning, "current_device_kind", lambda: "tpu_v5_lite")
+
+    cfg = BertConfig.tiny(layers=2, intermediate=256)
+    model = BertModel(cfg, dtype=jnp.bfloat16)
+    n, t = 4, 128
+    sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    key = sds(jax.eval_shape(lambda: jax.random.key(0)))
+    tokens = sds(jax.ShapeDtypeStruct((n, t), jnp.int32))
+    text = model._mlm_step().lower(
+        jax.tree.map(sds, model.params), jax.tree.map(sds, model.opt_state),
+        sds(jax.ShapeDtypeStruct((), jnp.int32)), key, tokens, tokens,
+        tokens, tokens, sds(jax.ShapeDtypeStruct((n, t), jnp.float32)),
+    ).compile().as_text()
+    _assert_mosaic(text)  # the flash kernel and the fused updater
+    # each draw ends in the compare of its uniforms with the keep rate
+    # (two layers' draws may share one fusion, so the compares are counted)
+    draw = re.compile(r"= pred\[%d,%d,%d\]\S* compare\(.*"
+                      r'op_name="[^"]*_bernoulli\)+/lt"'
+                      % (n, t, cfg.intermediate))
+    draws = [ln for ln in text.splitlines() if draw.search(ln)]
+    assert len(draws) == cfg.layers, [ln[:120] for ln in draws]
+
+
 @pytest.mark.parametrize("bh,t,causal,rate", [
     (8, 4096, True, 0.0),      # the flash_min_t crossover shape
     (192, 512, False, 0.1),    # BERT-base b16 x 12 heads, in-kernel dropout
